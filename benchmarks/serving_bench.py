@@ -329,8 +329,11 @@ def run_paged_attn(smoke: bool = False):
         jnp.arange(1, table_pages + 1, dtype=jnp.int32)[None],
         (n_slots, table_pages))
     toks = jnp.ones((n_slots, 1), jnp.int32)
+    # "pallas" rows run the platform's fused lowering ("auto"): the
+    # Mosaic kernel on TPU, its blocked XLA lowering elsewhere
+    fused = {"xla": "xla", "pallas": "auto"}
     steps = {b: jax.jit(make_paged_decode_step(
-        dataclasses.replace(cfg, attention_backend=b)))
+        dataclasses.replace(cfg, attention_backend=fused[b])))
         for b in ("xla", "pallas")}
 
     def step_ms(backend, ln):
@@ -364,7 +367,7 @@ def run_paged_attn(smoke: bool = False):
     n_pages = N_SLOTS * (MAX_PROMPT + 16 + 8) // PAGE_SIZE + 1
 
     def replay(backend):
-        c = dataclasses.replace(cfg, attention_backend=backend)
+        c = dataclasses.replace(cfg, attention_backend=fused[backend])
         eng, rids, _ = _run_continuous(params, c, trace, n_pages,
                                        timed=False)
         res = eng.results()
@@ -788,7 +791,7 @@ def run_kv_quant(smoke: bool = False):
 
     def engine_run(kvd):
         c = dataclasses.replace(cfg, kv_cache_dtype=kvd,
-                                attention_backend="pallas")
+                                attention_backend="auto")
         tel = ServeTelemetry(trace=True)
         eng = Engine(params, c, n_slots=n_slots, page_size=ps,
                      n_pages=npages(kvd), max_seq_pages=seq_pages,
@@ -841,7 +844,7 @@ def run_kv_quant(smoke: bool = False):
 
     def make_prefill_argmax(kvd):
         c = dataclasses.replace(cfg, kv_cache_dtype=kvd,
-                                attention_backend="pallas")
+                                attention_backend="auto")
         fn = jax.jit(make_paged_prefill(c))
         pages = jnp.arange(1, P + 1, dtype=jnp.int32)[None]
 

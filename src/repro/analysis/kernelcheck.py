@@ -257,7 +257,7 @@ def check_encoded_maps(x_map: Optional[Callable] = None,
     shapes = {
         "x": (x_map, (bm, bk), (mp, kp)),
         "w": (w_map, (U, bk, bn), (U, kp, np_)),
-        "bias": (b_map, (bn,), (np_,)),
+        "bias": (b_map, (1, bn), (1, np_)),
         "out": (o_map, (bm, bn), (mp, np_)),
     }
     out: List[Finding] = []

@@ -101,11 +101,14 @@ class ModelConfig:
     param_dtype: str = "float32"
     compute_dtype: str = "float32"
     attn_chunk: int = 1024
-    flash_attention: bool = False  # Pallas flash kernel (TPU; interpret on CPU)
+    # Pallas flash kernel for full-sequence attention: '' = off; 'pallas'
+    # = Mosaic (TPU only); 'pallas_interpret' = interpret mode (tests)
+    flash_attention: str = ""
     # paged decode attention (DESIGN.md §8): 'xla' = gathered-view
     # reference; 'pallas' = fused flash-decoding kernel over the page
-    # table (Mosaic on TPU, the blocked XLA lowering elsewhere);
-    # 'pallas_interpret' / 'blocked' force those lowerings (tests)
+    # table, compiled by Mosaic (TPU only — raises elsewhere);
+    # 'pallas_interpret' = that kernel in interpret mode (tests);
+    # 'blocked' = its XLA lowering; 'auto' = pallas on TPU, else blocked
     attention_backend: str = "xla"
     # max query tokens per slot routed through the fused paged kernel:
     # 1 = decode only (default); the speculative-decoding verify step

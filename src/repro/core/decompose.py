@@ -159,8 +159,12 @@ class BitplaneProgram:
         k = w_codes.shape[0]
         S_bil, S_a, S_b, c0 = self.scatter_coeffs(s)
         Gv = self.planes(w_codes, "b").astype(jnp.float32)     # (V, k, n)
-        Wt = jnp.einsum("uv,vkn->ukn", S_bil, Gv) + S_a[:, None, None]
-        bias = jnp.einsum("v,vn->n", S_b, Gv.sum(axis=1)) + c0 * k
+        # full f32: a TPU's default f32 matmul would round S to bf16
+        hi = jax.lax.Precision.HIGHEST
+        Wt = jnp.einsum("uv,vkn->ukn", S_bil, Gv, precision=hi) \
+            + S_a[:, None, None]
+        bias = jnp.einsum("v,vn->n", S_b, Gv.sum(axis=1), precision=hi) \
+            + c0 * k
         return Wt, bias
 
     def apply(self, x_codes: jnp.ndarray, w_codes: jnp.ndarray,

@@ -38,8 +38,10 @@ def w_block_map(i, j, kk):
 
 
 def bias_block_map(i, j, kk):
-    """bias (n,): column block j (added once on the last K step)."""
-    return (j,)
+    """bias (1, n): column block j (added once on the last K step).  A 2-D
+    row, not a 1-D vector: Mosaic tiles a 1-D block differently from the
+    XLA layout of a 1-D operand and refuses the call."""
+    return (0, j)
 
 
 def out_block_map(i, j, kk):
@@ -69,7 +71,7 @@ def _kernel(x_ref, w_ref, b_ref, o_ref, *, mono_bits, n_k_blocks):
 
     @pl.when(k == n_k_blocks - 1)
     def _bias():
-        o_ref[...] += b_ref[...].astype(jnp.float32)[None, :]
+        o_ref[...] += b_ref[...]
 
 
 @functools.partial(jax.jit,
@@ -91,15 +93,16 @@ def encoded_matmul_pallas(x_codes: jnp.ndarray, wt: jnp.ndarray,
     grid = (m // bm, n // bn, k // bk)
     kernel = functools.partial(_kernel, mono_bits=mono_bits,
                                n_k_blocks=grid[2])
+    bias2d = bias.astype(jnp.float32).reshape(1, n)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), x_block_map),
             pl.BlockSpec((u, bk, bn), w_block_map),
-            pl.BlockSpec((bn,), bias_block_map),
+            pl.BlockSpec((1, bn), bias_block_map),
         ],
         out_specs=pl.BlockSpec((bm, bn), out_block_map),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x_codes, wt.astype(jnp.bfloat16), bias.astype(jnp.float32))
+    )(x_codes, wt.astype(jnp.bfloat16), bias2d)

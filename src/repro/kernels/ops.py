@@ -1,7 +1,10 @@
 """Jitted public wrappers for the Pallas kernels (padding + dispatch).
 
-On CPU (this container) the Pallas paths run in interpret mode; on TPU they
-compile to Mosaic.  ``backend='xla'`` uses the single-GEMM einsum fold.
+Backends: ``pallas`` compiles the kernel to Mosaic and needs a TPU (it
+raises anywhere else — there is no silent fallback); ``pallas_interpret``
+runs the same kernel in interpret mode (tests, CPU debugging);
+``backend='xla'`` uses the single-GEMM einsum fold; ``auto`` picks
+``pallas`` on TPU and the XLA lowering elsewhere.
 
 ``_pad_to`` is the one shared pad-to-block helper for both the encoded and
 the flash wrappers.  Under an active mesh (parallel/sharding.set_mesh) the
@@ -19,9 +22,27 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import AXIS_MODEL, get_mesh, shard_map_norep
+from repro.parallel.sharding import AXIS_MODEL, get_mesh
 from .encoded_matmul import encoded_matmul_pallas
 from .ref import planes_ref
+
+
+def interpret_mode(backend: str) -> bool:
+    """``interpret=`` flag for a Pallas backend name.  ``pallas`` is the
+    compiled Mosaic kernel and raises off the TPU; only
+    ``pallas_interpret`` interprets."""
+    if backend == "pallas_interpret":
+        return True
+    if backend != "pallas":
+        raise ValueError(f"unknown Pallas backend {backend!r}; expected "
+                         "'pallas' or 'pallas_interpret'")
+    platform = jax.default_backend()
+    if platform != "tpu":
+        raise RuntimeError(
+            f"backend 'pallas' compiles the Mosaic kernel and needs a TPU, "
+            f"but JAX runs on {platform!r}; pass 'pallas_interpret' to run "
+            "the kernel in interpret mode")
+    return False
 
 
 def _pad_to(x: jnp.ndarray, mult: int, axis: int) -> jnp.ndarray:
@@ -96,17 +117,18 @@ def _pallas_sharded(x_codes, wt, bias, mono, role, mesh, bm, bn, bk,
     if role == "column":
         def col(xl, wl, bl):
             return _pallas_padded(xl, wl, bl, mono, bm, bn, bk, interpret)
-        return shard_map_norep(col, mesh,
-                               (P(), P(None, None, ax), P(ax)),
-                               P(None, ax))(x_codes, wt, bias)
+        return jax.shard_map(col, mesh=mesh,
+                             in_specs=(P(), P(None, None, ax), P(ax)),
+                             out_specs=P(None, ax),
+                             check_vma=False)(x_codes, wt, bias)
 
     def row(xl, wl, bl):
         zero = jnp.zeros_like(bl)
         part = _pallas_padded(xl, wl, zero, mono, bm, bn, bk, interpret)
         return jax.lax.psum(part, ax) + bl
-    return shard_map_norep(row, mesh,
-                           (P(None, ax), P(None, ax, None), P()),
-                           P())(x_codes, wt, bias)
+    return jax.shard_map(row, mesh=mesh,
+                         in_specs=(P(None, ax), P(None, ax, None), P()),
+                         out_specs=P(), check_vma=False)(x_codes, wt, bias)
 
 
 def encoded_matmul(x_codes: jnp.ndarray, wt: jnp.ndarray, bias: jnp.ndarray,
@@ -138,7 +160,7 @@ def encoded_matmul(x_codes: jnp.ndarray, wt: jnp.ndarray, bias: jnp.ndarray,
         A = planes_ref(x_codes, _pad3(mono)).astype(jnp.bfloat16)
         return jnp.einsum("umk,ukn->mn", A, wt.astype(jnp.bfloat16),
                           preferred_element_type=jnp.float32) + bias
-    interpret = backend == "pallas_interpret" or jax.default_backend() != "tpu"
+    interpret = interpret_mode(backend)
     mesh = get_mesh()
     if mesh is not None and AXIS_MODEL in mesh.axis_names:
         tp = mesh.shape[AXIS_MODEL]
@@ -150,7 +172,8 @@ def encoded_matmul(x_codes: jnp.ndarray, wt: jnp.ndarray, bias: jnp.ndarray,
 
 
 def flash_mha(q, k, v, *, scale: float, causal: bool = True, window=None,
-              cap=None, bq: int = 128, bk: int = 128, backend: str = "auto"):
+              cap=None, bq: int = 128, bk: int = 128,
+              backend: str = "pallas"):
     """4-D GQA wrapper for the flash kernel: q (B,Sq,Hq,D), k/v (B,Sk,Hkv,D).
 
     KV heads are repeated to q heads (uniform grouping), (B,H) flattened to
@@ -172,8 +195,7 @@ def flash_mha(q, k, v, *, scale: float, causal: bool = True, window=None,
     vf = _pad_to(vf, bk, 1)
     if (-Sk) % bk and not causal:
         raise ValueError("non-causal padding needs an explicit kv mask")
-    interpret = backend == "pallas_interpret" or \
-        (backend == "auto" and jax.default_backend() != "tpu")
+    interpret = interpret_mode(backend)
     out = flash_attention(qf, kf, vf, scale=scale, causal=causal,
                           window=window, cap=cap, bq=bq, bk=bk,
                           interpret=interpret)

@@ -29,10 +29,10 @@ is cheap.  This kernel instead walks each sequence's page chain directly:
 
 Backends (``paged_attn(..., backend=...)``):
 
-  * ``pallas``           — the Pallas kernel (Mosaic on TPU, interpret
-                           elsewhere; interpret is a correctness path, not
-                           a fast one — parity tests use it);
-  * ``pallas_interpret`` — force interpret mode (debug/tests);
+  * ``pallas``           — the Pallas kernel compiled by Mosaic; TPU only
+                           (raises on any other platform);
+  * ``pallas_interpret`` — the same kernel in interpret mode, a
+                           correctness path, not a fast one (tests);
   * ``blocked``          — the kernel's XLA reference lowering: the same
                            page-block online-softmax recurrence as a
                            ``fori_loop`` bounded by ``max(lens)``, so
@@ -59,21 +59,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import AXIS_MODEL, get_mesh, shard_map_norep
+from repro.parallel.sharding import AXIS_MODEL, get_mesh
 from repro.quant.kvcache import kv_mode_of, unpack_int4
+from .ops import interpret_mode
 
 NEG_INF = -2.0e38                    # finite f32 sentinel (matches mha)
+# f32 contractions at full f32 precision in every lowering, so the kernel,
+# its blocked lowering and the gather reference agree on the chip too (a
+# TPU's default f32 matmul rounds operands to bf16)
+ATTN_PRECISION = jax.lax.Precision.HIGHEST
 _NO_WINDOW = np.int32(2 ** 30)       # "no sliding window" resolves to huge
 
 
 def _dequant_block(x, scale, mode):
     """Per-page-block dequant shared by both lowerings (DESIGN.md §11):
-    pool bytes ``x (..., H, Dp)`` + scale rows ``(..., H)`` → f32
-    ``(..., H, D)``.  ``mode == 'bf16'`` is the dense passthrough."""
+    pool bytes ``x (..., Dp)`` times f32 scales ``(..., 1)`` (one per
+    token and head, broadcast over the head dim) → f32 ``(..., D)``.
+    ``mode == 'bf16'`` is the dense passthrough."""
     if mode == "int8":
-        return x.astype(jnp.float32) * scale.astype(jnp.float32)[..., None]
+        return x.astype(jnp.float32) * scale
     if mode == "int4":
-        return unpack_int4(x) * scale.astype(jnp.float32)[..., None]
+        return unpack_int4(x) * scale
     return x.astype(jnp.float32)
 
 
@@ -120,7 +126,7 @@ def paged_scale_block_map(b, p, pages_s, lens_s, win_s, *, Sq, ps):
 
 
 def paged_q_block_map(b, p, *_):
-    """q / output block index: row b, whole (Sq, Hq, D) block."""
+    """Folded q / output block index: row b, whole (Hkv, Sq·G, D) block."""
     return (b, 0, 0, 0)
 
 
@@ -129,7 +135,7 @@ def paged_q_block_map(b, p, *_):
 # ---------------------------------------------------------------------------
 
 def _decode_kernel(pages_s, lens_s, win_s, q_ref, k_ref, v_ref, *rest,
-                   ps, n_pb, scale, cap, G, Sq, mode="bf16"):
+                   ps, n_pb, cap, G, Sq, mode="bf16"):
     if mode == "bf16":
         sk_ref = sv_ref = None
         o_ref, m_ref, l_ref, acc_ref = rest
@@ -149,50 +155,61 @@ def _decode_kernel(pages_s, lens_s, win_s, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(p < nb)
     def _block():
-        q = q_ref[0]                                 # (Sq, Hq, D)
-        # in-loop dequant (DESIGN.md §11): quantized pools stream their
-        # narrow bytes HBM→VMEM and widen to f32 here, per page block —
-        # the dense-width K/V view never exists anywhere
-        k = _dequant_block(k_ref[0], None if sk_ref is None else sk_ref[0],
-                           mode)                     # (ps, Hkv, D) f32
-        v = _dequant_block(v_ref[0], None if sv_ref is None else sv_ref[0],
-                           mode)
-        hkv = k.shape[1]
-        D = q.shape[-1]
         f32 = jnp.float32
-        # dense-op-order numerics: scale in storage dtype, contract in f32.
-        # The Sq query tokens fold into the group axis — row r = s·G + g of
-        # the (Hkv, Sq·G) layout is query s, group g — so the online-softmax
-        # recurrence is shape-identical to the Sq == 1 kernel.
-        qg = (q * jnp.asarray(scale, q.dtype)
-              ).reshape(Sq, hkv, G, D).transpose(1, 0, 2, 3)
-        qg = qg.reshape(hkv, Sq * G, D).astype(f32)
-        kt = k.transpose(1, 0, 2)                    # (Hkv, ps, D)
-        s = jax.lax.dot_general(qg, kt, (((2,), (2,)), ((0,), (0,))),
-                                preferred_element_type=f32)  # (Hkv, Sq·G, ps)
-        s = _softcap(s, cap)
-        t = p * ps + jax.lax.broadcasted_iota(jnp.int32, (Sq * G, ps), 1)
-        rq = jax.lax.broadcasted_iota(jnp.int32, (Sq * G, ps), 0) // G
-        d = (ln + rq) - t                            # q_pos(=ln+s) - k_pos
+        hkv, R = q_ref.shape[1], q_ref.shape[2]      # R = Sq·G query rows
+        # row r = s·G + g is query s of group g; query s sits at ln + s
+        t = p * ps + jax.lax.broadcasted_iota(jnp.int32, (R, ps), 1)
+        rq = jax.lax.broadcasted_iota(jnp.int32, (R, ps), 0)
+        if G > 1:
+            rq = rq // G
+        d = (ln + rq) - t                            # q_pos - k_pos
         ok = (d >= 0) & (d < win_s[0])
-        s = jnp.where(ok[None], s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(-1))
-        alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.exp(s - m_new[..., None])
-        l_ref[...] = l_ref[...] * alpha + pexp.sum(-1)
-        vt = v.transpose(1, 0, 2)                    # (Hkv, ps, D)
-        pv = jax.lax.dot_general(pexp, vt, (((2,), (1,)), ((0,), (0,))),
-                                 preferred_element_type=f32)  # (Hkv, Sq·G, D)
-        acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
-        m_ref[...] = m_new
+        # one kv head at a time: (ps, D) reads straight from the page
+        # block, 2-D contractions only — no in-kernel reshape/transpose
+        for h in range(hkv):
+            # in-loop dequant (DESIGN.md §11): quantized pools stream
+            # their narrow bytes HBM→VMEM and widen to f32 here
+            k = _dequant_block(
+                k_ref[0, :, h, :],
+                None if sk_ref is None else sk_ref[0][:, h:h + 1], mode)
+            v = _dequant_block(
+                v_ref[0, :, h, :],
+                None if sv_ref is None else sv_ref[0][:, h:h + 1], mode)
+            q = q_ref[0, h].astype(f32)                       # (R, D)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    precision=ATTN_PRECISION,
+                                    preferred_element_type=f32)  # (R, ps)
+            s = jnp.where(ok, _softcap(s, cap), NEG_INF)
+            m_prev = m_ref[h]                                 # (R, 1)
+            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            pexp = jnp.exp(s - m_new)
+            l_ref[h] = l_ref[h] * alpha + pexp.sum(-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+                pexp, v, precision=ATTN_PRECISION, preferred_element_type=f32)
+            m_ref[h] = m_new
 
     @pl.when(p == n_pb - 1)
     def _finalize():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
-        hkv, _, D = acc_ref.shape
-        out = out.reshape(hkv, Sq, G, D).transpose(1, 0, 2, 3)
-        o_ref[0] = out.reshape(Sq, hkv * G, D).astype(o_ref.dtype)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = out.astype(o_ref.dtype)
+
+
+def fold_q(q, scale: float, G: int):
+    """(B, Sq, Hq, D) → (B, Hkv, Sq·G, D), scaled in the storage dtype
+    (dense-op-order numerics): row r = s·G + g of kv head h is query s of
+    q head h·G + g.  Done outside the kernel — Mosaic does not lower the
+    reshapes this needs."""
+    B, S, Hq, D = q.shape
+    qg = (q * jnp.asarray(scale, q.dtype)).reshape(B, S, Hq // G, G, D)
+    return qg.transpose(0, 2, 1, 3, 4).reshape(B, Hq // G, S * G, D)
+
+
+def unfold_q(out, S: int, G: int):
+    """Inverse of ``fold_q``'s layout: (B, Hkv, Sq·G, D) → (B, Sq, Hq, D)."""
+    B, hkv, _, D = out.shape
+    out = out.reshape(B, hkv, S, G, D).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, S, hkv * G, D)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "cap", "G",
@@ -213,19 +230,20 @@ def paged_attn_pallas(q, pool_k, pool_v, pages, lens, window, *,
     Dp = pool_k.shape[3]
     mode = kv_mode_of(pool_k)        # static: dtype is a trace constant
     n_pb = pages.shape[1]
+    R = S * G
     win = jnp.asarray(window, jnp.int32).reshape(1)
 
     page_idx = functools.partial(paged_kv_block_map, Sq=S, ps=ps)
     page_idx3 = functools.partial(paged_scale_block_map, Sq=S, ps=ps)
 
-    kern = functools.partial(_decode_kernel, ps=ps, n_pb=n_pb, scale=scale,
-                             cap=cap, G=G, Sq=S, mode=mode)
+    kern = functools.partial(_decode_kernel, ps=ps, n_pb=n_pb, cap=cap, G=G,
+                             Sq=S, mode=mode)
     in_specs = [
-        pl.BlockSpec((1, S, Hq, D), paged_q_block_map),
+        pl.BlockSpec((1, Hkv, R, D), paged_q_block_map),
         pl.BlockSpec((1, ps, Hkv, Dp), page_idx),
         pl.BlockSpec((1, ps, Hkv, Dp), page_idx),
     ]
-    operands = [q, pool_k, pool_v]
+    operands = [fold_q(q, scale, G), pool_k, pool_v]
     if mode != "bf16":
         in_specs += [pl.BlockSpec((1, ps, Hkv), page_idx3),
                      pl.BlockSpec((1, ps, Hkv), page_idx3)]
@@ -234,19 +252,20 @@ def paged_attn_pallas(q, pool_k, pool_v, pages, lens, window, *,
         num_scalar_prefetch=3,
         grid=(B, n_pb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, S, Hq, D), paged_q_block_map),
+        out_specs=pl.BlockSpec((1, Hkv, R, D), paged_q_block_map),
         scratch_shapes=[
-            pltpu.VMEM((Hkv, S * G), jnp.float32),
-            pltpu.VMEM((Hkv, S * G), jnp.float32),
-            pltpu.VMEM((Hkv, S * G, D), jnp.float32),
+            pltpu.VMEM((Hkv, R, 1), jnp.float32),
+            pltpu.VMEM((Hkv, R, 1), jnp.float32),
+            pltpu.VMEM((Hkv, R, D), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), q.dtype),
         interpret=interpret,
     )(pages, lens, win, *operands)
+    return unfold_q(out, S, G)
 
 
 # ---------------------------------------------------------------------------
@@ -269,9 +288,7 @@ def _paged_attn_blocked(q, pool_k, pool_v, pages, lens, window, *,
     f32 = jnp.float32
     # fold the Sq query tokens into the group axis (row r = s·G + g), same
     # layout as the Pallas kernel
-    qg = (q * jnp.asarray(scale, q.dtype)
-          ).reshape(B, S, Hkv, G, D).transpose(0, 2, 1, 3, 4)
-    qg = qg.reshape(B, Hkv, S * G, D).astype(f32)
+    qg = fold_q(q, scale, G).astype(f32)
     win = jnp.asarray(window, jnp.int32)
     bp = max(1, bk // ps)                            # pages per K block
     blk = bp * ps                                    # tokens per K block
@@ -287,13 +304,15 @@ def _paged_attn_blocked(q, pool_k, pool_v, pages, lens, window, *,
         pid = jax.lax.dynamic_slice_in_dim(pages, j * bp, bp, 1)  # (B, bp)
         # gather narrow pool bytes, then widen per block — the same
         # in-loop dequant as the Pallas kernel (DESIGN.md §11)
-        skb = None if scale_k is None else jnp.take(scale_k, pid, axis=0)
-        svb = None if scale_v is None else jnp.take(scale_v, pid, axis=0)
+        skb = None if scale_k is None else \
+            jnp.take(scale_k, pid, axis=0)[..., None]
+        svb = None if scale_v is None else \
+            jnp.take(scale_v, pid, axis=0)[..., None]
         kb = _dequant_block(jnp.take(pool_k, pid, axis=0), skb, mode)
         vb = _dequant_block(jnp.take(pool_v, pid, axis=0), svb, mode)
         kb = kb.reshape(B, blk, Hkv, D)              # (B, bp, ps, H, D) →
         vb = vb.reshape(B, blk, Hkv, D)
-        s = jnp.einsum("bhgd,bphd->bhgp", qg, kb,
+        s = jnp.einsum("bhgd,bphd->bhgp", qg, kb, precision=ATTN_PRECISION,
                        preferred_element_type=f32)
         s = _softcap(s, cap)
         # q_pos(=lens+s) - k_pos, per (query-row, key) pair: (B, S·G, blk)
@@ -306,7 +325,8 @@ def _paged_attn_blocked(q, pool_k, pool_v, pages, lens, window, *,
         pexp = jnp.exp(s - m_new[..., None])
         l = l * alpha + pexp.sum(-1)
         acc = acc * alpha[..., None] + jnp.einsum(
-            "bhgp,bphd->bhgd", pexp, vb, preferred_element_type=f32)
+            "bhgp,bphd->bhgd", pexp, vb, precision=ATTN_PRECISION,
+            preferred_element_type=f32)
         return m_new, l, acc
 
     init = (jnp.full((B, Hkv, S * G), NEG_INF, f32),
@@ -314,8 +334,7 @@ def _paged_attn_blocked(q, pool_k, pool_v, pages, lens, window, *,
             jnp.zeros((B, Hkv, S * G, D), f32))
     m, l, acc = jax.lax.fori_loop(0, nb, body, init)
     out = acc / jnp.maximum(l, 1e-30)[..., None]
-    out = out.reshape(B, Hkv, S, G, D).transpose(0, 2, 1, 3, 4)
-    return out.reshape(B, S, Hq, D).astype(q.dtype)
+    return unfold_q(out, S, G).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -328,8 +347,7 @@ def _local(q, pool_k, pool_v, pages, lens, win, *, scale, cap, G, backend,
         return _paged_attn_blocked(q, pool_k, pool_v, pages, lens, win,
                                    scale=scale, cap=cap, G=G,
                                    scale_k=scale_k, scale_v=scale_v)
-    interpret = (backend == "pallas_interpret"
-                 or jax.default_backend() != "tpu")
+    interpret = interpret_mode(backend)
     return paged_attn_pallas(q, pool_k, pool_v, pages, lens, win,
                              scale=scale, cap=cap, G=G, interpret=interpret,
                              scale_k=scale_k, scale_v=scale_v)
@@ -402,7 +420,8 @@ def paged_attn(q, pool_k, pool_v, pages, lens, *, scale: float,
                 return _local(ql, kl, vl, pg, ln, w, scale_k=sk,
                               scale_v=sv, **kw)
 
-            return shard_map_norep(shard, mesh, tuple(specs),
-                                   P(None, None, ax, None))(*args)
+            return jax.shard_map(shard, mesh=mesh, in_specs=tuple(specs),
+                                 out_specs=P(None, None, ax, None),
+                                 check_vma=False)(*args)
     return _local(q, pool_k, pool_v, pages, lens, win, scale_k=scale_k,
                   scale_v=scale_v, **kw)

@@ -40,6 +40,26 @@ def set_cpu_cores(n: int) -> None:
         f"--xla_force_host_platform_device_count={n}"
 
 
+# fixed, git-ignored home of the persistent compile cache inside the
+# checkout: the cache key includes the path, so it must not move
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  ``JAX_COMPILATION_CACHE_DIR``, when set, is left to JAX
+    (nothing is set in code); otherwise the cache goes to the fixed
+    in-checkout ``.jax_cache``.  Call before the first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.normpath(DEFAULT_COMPILE_CACHE)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def set_debug_nan(flag: bool) -> None:
     """Raise on the first NaN any computation produces (re-runs the
     offending op un-jitted to localize it).  Debug-only: disables some

@@ -101,13 +101,14 @@ def main():
                          "top-1 logit agreement online every N engine "
                          "steps and publish it as a gauge (0 = off)")
     ap.add_argument("--paged-attn", default="xla",
-                    choices=["xla", "pallas"],
+                    choices=["xla", "auto", "pallas"],
                     help="paged decode attention (DESIGN.md §8): 'xla' = "
                          "gathered-page-view reference; 'pallas' = fused "
                          "flash-decoding kernel reading K/V page-by-page "
                          "through the page table with per-row lens "
-                         "early-exit (Mosaic on TPU, the blocked XLA "
-                         "lowering of the same algorithm elsewhere)")
+                         "early-exit, compiled by Mosaic (TPU only); "
+                         "'auto' = that kernel on TPU, its blocked XLA "
+                         "lowering elsewhere")
     ap.add_argument("--kv-dtype", default="bf16",
                     choices=["bf16", "int8", "int4"],
                     help="paged KV-cache storage (DESIGN.md §11): 'bf16' "
@@ -160,6 +161,8 @@ def main():
                          "REPRO_SANITIZE=1 does the same)")
     args = ap.parse_args()
 
+    from repro.launch.env import use_compile_cache
+    use_compile_cache()
     if args.debug_nan:
         from repro.launch.env import set_debug_nan
         set_debug_nan(True)
@@ -190,12 +193,10 @@ def main():
     if args.reduced:
         cfg = cfg.reduced()
     elif args.mac == "encoded" and jax.default_backend() == "cpu":
-        # folded bitplane weights are U× the dense weight bytes — a
-        # production-sized config would not fit host memory, so the CPU
-        # (interpret/XLA) path always serves the reduced shape
-        print(f"[encoded-serving] CPU backend: using {args.arch}.reduced() "
-              "(pass --reduced to silence)")
-        cfg = cfg.reduced()
+        # folded bitplane weights are U× the dense weight bytes: at its
+        # published size the model does not fit a CPU host's memory
+        ap.error(f"--mac encoded serves {args.arch} at its published size "
+                 "only on an accelerator; pass --reduced on the CPU")
     if args.mac == "int8":
         cfg = dataclasses.replace(cfg, mac=MacConfig(mode="int8"))
     if args.paged_attn != "xla":

@@ -51,8 +51,9 @@ def main():
     if args.devices:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices}")
+    from repro.launch.env import set_debug_nan, use_compile_cache
+    use_compile_cache()
     if args.debug_nan:
-        from repro.launch.env import set_debug_nan
         set_debug_nan(True)
     # comm/compute overlap (latency-hiding scheduler) — harmless on CPU
     os.environ.setdefault(
@@ -69,6 +70,7 @@ def main():
     from repro.data.pipeline import DataPipeline
     from repro.ckpt import (save_checkpoint, restore_checkpoint,
                             async_save_checkpoint, latest_step)
+    from repro.launch.mesh import make_test_mesh
     from repro.parallel.sharding import set_mesh, param_specs, batch_spec
     from repro.parallel.statesharding import opt_state_specs
 
@@ -91,8 +93,7 @@ def main():
             if n_dev % m == 0 and cfg.d_ff % m == 0:
                 model_ax = m
                 break
-        mesh = jax.make_mesh((n_dev // model_ax, model_ax),
-                             ("data", "model"))
+        mesh = make_test_mesh(n_dev // model_ax, model_ax)
         print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))}")
 
     ds = SyntheticLMDataset(cfg.vocab_size, args.seq, seed=17)

@@ -113,7 +113,8 @@ def attn_apply(p: dict, x: jnp.ndarray, cfg, *, layer_window=None,
             from repro.kernels.ops import flash_mha
             return flash_mha(q, k, v, scale=scale, causal=True,
                              window=window if isinstance(window, int)
-                             else None, cap=cfg.attn_softcap)
+                             else None, cap=cfg.attn_softcap,
+                             backend=cfg.flash_attention)
         k_valid = None if pad is None else positions >= 0   # left-pad keys
         return mha(q, k, v, kv_map, scale=scale, q_pos=positions,
                    k_pos=positions, window=window, cap=cfg.attn_softcap,
@@ -161,11 +162,9 @@ def attn_apply(p: dict, x: jnp.ndarray, cfg, *, layer_window=None,
                  and gqa_group(kv_map, cfg.n_heads_p, cfg.n_kv_p)
                  is not None)
         if fused:
-            backend = ("auto" if cfg.attention_backend == "pallas"
-                       else cfg.attention_backend)
             out = paged_attn(q, pk, pv, pages, lens, scale=scale,
                              window=window, cap=cfg.attn_softcap,
-                             kv_of_q=kv_map, backend=backend,
+                             kv_of_q=kv_map, backend=cfg.attention_backend,
                              scale_k=sk, scale_v=sv)
         else:
             if quant:

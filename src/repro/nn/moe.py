@@ -21,7 +21,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.sharding import (get_mesh, shard_map, AXIS_BATCH,
+from repro.parallel.sharding import (get_mesh, AXIS_BATCH,
                                      AXIS_MODEL)
 from jax.sharding import PartitionSpec as P
 from .common import linear, linear_init, mlp_init, mlp_apply, act_fn
@@ -146,7 +146,7 @@ def moe_apply(p: dict, x: jnp.ndarray, cfg) -> tuple:
         fn = functools.partial(dispatch_compute,
                                n_experts_total=cfg.n_experts, capacity=cap,
                                act=cfg.act, axis_name=AXIS_MODEL)
-        out = shard_map(
+        out = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(data_axes, None), P(data_axes, None),
                       P(data_axes, None), P(AXIS_MODEL, None, None),

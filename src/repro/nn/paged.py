@@ -35,7 +35,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.paged_attention import gqa_group
+from repro.kernels.paged_attention import ATTN_PRECISION, gqa_group
 from repro.quant.kvcache import dequantize_kv, kv_mode_of, quantize_kv
 from .common import softcap
 from .attention_mha import NEG_INF
@@ -106,7 +106,9 @@ def paged_attn_decode(q, k, v, kv_of_q: np.ndarray, *, scale: float,
     so queries see every already-cached token plus the in-chunk prefix.
     Mirrors the dense ``mha`` op order — grouped (kv-head, group) layout,
     f32 accumulation, identical einsum strings — so paged greedy decode
-    stays token-identical to the dense-cache path.  Fully-masked rows
+    stays token-identical to the dense-cache path.  Contractions run at
+    full f32 precision, as the fused kernel's do, so the two paths agree
+    on a TPU as well.  Fully-masked rows
     (idle slots, lens == 0) stay finite because NEG_INF is a finite f32
     sentinel.
     """
@@ -124,7 +126,7 @@ def paged_attn_decode(q, k, v, kv_of_q: np.ndarray, *, scale: float,
 
     qg = (q * jnp.asarray(scale, q.dtype)).reshape(B, Sq, He, G, D)
     lg = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(f32), k.astype(f32),
-                    preferred_element_type=f32)
+                    precision=ATTN_PRECISION, preferred_element_type=f32)
     lg = softcap(lg, cap)
     d = q_pos[:, :, None] - k_pos[None, None, :]          # (B, Sq, Sk)
     ok = (d >= 0) & k_valid[:, None, :]
@@ -133,5 +135,5 @@ def paged_attn_decode(q, k, v, kv_of_q: np.ndarray, *, scale: float,
     lg = jnp.where(ok[:, None, None], lg, NEG_INF)
     p = jax.nn.softmax(lg, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(f32),
-                     preferred_element_type=f32)
+                     precision=ATTN_PRECISION, preferred_element_type=f32)
     return out.reshape(B, Sq, Hq, -1).astype(q.dtype)

@@ -8,36 +8,26 @@ jitted steps (per-op device time), complementing the host-side
 ``time_device`` attribution the telemetry layer records per engine step
 (DESIGN.md §9).
 
-Profiler availability varies by platform/backend, so failures to start
-degrade to a warning instead of killing the serving run.
+A trace that was asked for and cannot start or stop is an error: the
+exception propagates, so a profiled run never exits 0 without its trace.
 """
 from __future__ import annotations
 
 import contextlib
-import warnings
 from typing import Optional
 
 
 @contextlib.contextmanager
 def profiler_trace(profile_dir: Optional[str] = None):
     """Context manager: jax profiler trace into ``profile_dir`` (no-op
-    when None/empty).  Yields True iff the profiler actually started."""
+    when None/empty).  Yields True iff the profiler started; start and
+    stop failures raise."""
     if not profile_dir:
         yield False
         return
     import jax
-    started = False
+    jax.profiler.start_trace(profile_dir)
     try:
-        jax.profiler.start_trace(profile_dir)
-        started = True
-    except Exception as e:                      # pragma: no cover - platform
-        warnings.warn(f"jax.profiler.start_trace failed ({e}); "
-                      "serving continues unprofiled")
-    try:
-        yield started
+        yield True
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception as e:              # pragma: no cover - platform
-                warnings.warn(f"jax.profiler.stop_trace failed ({e})")
+        jax.profiler.stop_trace()

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.sharding import shard_map, pvary
-
 
 def pipeline_apply(stage_fn, mesh: Mesh, axis: str, params_stacked, x_mb):
     """Run x through S pipeline stages.
@@ -38,8 +36,8 @@ def pipeline_apply(stage_fn, mesh: Mesh, axis: str, params_stacked, x_mb):
         M = x_all.shape[0]
         n_ticks = M + S - 1
         # carries become stage-varying inside the loop — mark them upfront
-        carry_in = pvary(jnp.zeros_like(x_all[0]), (axis,))
-        outs = pvary(jnp.zeros_like(x_all), (axis,))
+        carry_in = jax.lax.pvary(jnp.zeros_like(x_all[0]), (axis,))
+        outs = jax.lax.pvary(jnp.zeros_like(x_all), (axis,))
 
         def tick(t, state):
             carry_in, outs = state
@@ -67,6 +65,5 @@ def pipeline_apply(stage_fn, mesh: Mesh, axis: str, params_stacked, x_mb):
         return outs
 
     pspec = jax.tree_util.tree_map(lambda _: P(axis), params_stacked)
-    return shard_map(body, mesh=mesh,
-                         in_specs=(pspec, P()),
+    return jax.shard_map(body, mesh=mesh, in_specs=(pspec, P()),
                          out_specs=P())(params_stacked, x_mb)

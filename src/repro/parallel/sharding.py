@@ -24,28 +24,6 @@ AXIS_POD = "pod"
 AXIS_BATCH = (AXIS_POD, AXIS_DATA)     # logical batch = pod × data
 AXIS_EXPERT = AXIS_MODEL               # experts sharded over the model axis
 
-# jax.shard_map graduated from jax.experimental in newer releases; alias
-# whichever this installation provides so call sites stay uniform.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:                                   # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map
-
-# lax.pvary marks values as axis-varying under newer shard_map semantics;
-# pre-0.5 shard_map treats everything as varying, so identity is correct.
-pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
-
-def shard_map_norep(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking off (Pallas calls have no rep
-    rule); newer releases renamed/dropped ``check_rep``, so fall back."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
-
 _ctx = threading.local()
 
 

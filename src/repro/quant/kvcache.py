@@ -85,8 +85,9 @@ def pack_int4(q: jnp.ndarray) -> jnp.ndarray:
 def unpack_int4(b: jnp.ndarray) -> jnp.ndarray:
     """Inverse of ``pack_int4`` → f32 levels in [-7, 7] (zero bytes —
     never written — decode to -8, masked/zero-scaled upstream)."""
-    lo = (b & 0xF).astype(jnp.float32) - 8.0
-    hi = (b >> 4).astype(jnp.float32) - 8.0
+    w = b.astype(jnp.int32)          # Mosaic has no uint8 → f32 cast
+    lo = (w & 0xF).astype(jnp.float32) - 8.0
+    hi = (w >> 4).astype(jnp.float32) - 8.0
     return jnp.concatenate([lo, hi], axis=-1)
 
 

@@ -22,7 +22,7 @@ from repro.models.lm import mtp_logits
 from repro.optim import make_optimizer, warmup_cosine, clip_by_global_norm
 from repro.optim.compression import init_error_buffers, ef_compress_tree, \
     decompress_int8
-from repro.parallel.sharding import get_mesh, shard_map, AXIS_BATCH
+from repro.parallel.sharding import get_mesh, AXIS_BATCH
 from jax.sharding import PartitionSpec as P
 from .losses import lm_loss
 
@@ -58,8 +58,7 @@ def _compressed_allreduce(grads, err, mesh):
         return g2, e2
 
     spec = jax.tree_util.tree_map(lambda _: P(), grads)
-    return shard_map(f, mesh=mesh,
-                         in_specs=(spec, spec),
+    return jax.shard_map(f, mesh=mesh, in_specs=(spec, spec),
                          out_specs=(spec, spec))(grads, err)
 
 

@@ -10,6 +10,7 @@ sharding cannot change the stored bytes).  Each check prints 'OK <name>'.
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import dataclasses
 
@@ -43,7 +44,7 @@ def main():
     mesh = make_test_mesh(1, 2)
     for kv_dtype in ("int8", "int4"):
         ref = serve(None, "xla", kv_dtype)
-        out = serve(mesh, "pallas", kv_dtype)
+        out = serve(mesh, "pallas_interpret", kv_dtype)
         assert out == ref, (kv_dtype, out, ref)
         print(f"OK kv_quant_mesh_{kv_dtype}_token_identical")
         out_b = serve(mesh, "blocked", kv_dtype)

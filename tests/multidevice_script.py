@@ -2,6 +2,7 @@
 Each check prints 'OK <name>' — the wrapper asserts all are present."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import dataclasses
 import numpy as np
@@ -14,7 +15,7 @@ from repro.models import init_model, apply_model, init_cache
 from repro.train import make_train_step, init_train_state
 from repro.data.synthetic import SyntheticLMDataset
 from repro.parallel.sharding import set_mesh, param_specs, batch_spec
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_mesh, make_test_mesh
 from repro.parallel.statesharding import opt_state_specs, cache_specs
 
 assert jax.device_count() == 8, jax.device_count()
@@ -127,7 +128,7 @@ print("OK compressed_allreduce")
 # ------------------------------------------------------------------- pipeline
 from repro.parallel.pipeline import pipeline_apply
 S_stages, M, mb, dd = 4, 8, 2, 16
-mesh_p = jax.make_mesh((4,), ("stage",))
+mesh_p = make_mesh((4,), ("stage",))
 rng = np.random.default_rng(3)
 Ws = jnp.asarray(rng.normal(size=(S_stages, dd, dd)) / np.sqrt(dd),
                  jnp.float32)

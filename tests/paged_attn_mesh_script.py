@@ -1,13 +1,15 @@
 """Runs under 2 fake CPU devices (subprocess; see test_paged_attention.py).
 
 The fused paged-attention decode path must compose with tensor-parallel
-serving: a model=2 mesh engine with ``attention_backend='pallas'`` (the
-kernel runs shard-local over kv-head-sharded pools via shard_map) serves
+serving: a model=2 mesh engine with the fused backend (``auto`` — the
+platform's lowering — and the Pallas kernel in interpret mode, each
+shard-local over kv-head-sharded pools via shard_map) serves
 greedy-token-identically to the single-device gather-path engine.  Each
 check prints 'OK <name>'.
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import dataclasses
 
@@ -39,7 +41,7 @@ def main():
 
     ref = serve(None, "xla")
     mesh = make_test_mesh(1, 2)
-    out = serve(mesh, "pallas")
+    out = serve(mesh, "auto")
     assert out == ref, (out, ref)
     print("OK paged_attn_mesh_token_identical")
     out_i = serve(mesh, "pallas_interpret")

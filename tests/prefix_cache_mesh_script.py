@@ -8,6 +8,7 @@ cache-disabled engine, and still reports prefix hits.  Each check prints
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np
 import jax

@@ -9,6 +9,7 @@ kernel.  Each check prints 'OK <name>'.
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import dataclasses
 import tempfile

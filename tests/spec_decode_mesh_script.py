@@ -8,6 +8,7 @@ prints 'OK <name>'.
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import dataclasses
 
@@ -43,7 +44,7 @@ def main():
     assert out == ref, (out, ref)
     assert st["spec_acceptance_rate"] > 0, st
     print("OK spec_decode_mesh_xla_token_identical")
-    out_p, st_p = serve(mesh, "pallas", 4)
+    out_p, st_p = serve(mesh, "pallas_interpret", 4)
     assert out_p == ref, (out_p, ref)
     assert st_p["spec_acceptance_rate"] > 0, st_p
     print("OK spec_decode_mesh_pallas_token_identical")

@@ -83,7 +83,8 @@ def test_elastic_restore_across_meshes(tmp_path):
     from jax.sharding import NamedSharding, PartitionSpec as P
     t = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}
     save_checkpoint(str(tmp_path), 0, t)
-    mesh = jax.make_mesh((1,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("model",))
     sh = {"w": NamedSharding(mesh, P("model", None))}
     r = restore_checkpoint(str(tmp_path), 0, t, shardings=sh)
     np.testing.assert_array_equal(np.asarray(r["w"]), np.asarray(t["w"]))

@@ -60,8 +60,8 @@ def test_flash_bf16_padding():
 
 
 def test_flash_model_path_matches_default():
-    """cfg.flash_attention=True routes training attention through the
-    Pallas kernel (interpret on CPU) with identical outputs."""
+    """cfg.flash_attention routes training attention through the Pallas
+    kernel (here in interpret mode) with identical outputs."""
     import dataclasses
     from repro.configs import get_config
     from repro.models import init_model, apply_model
@@ -71,7 +71,7 @@ def test_flash_model_path_matches_default():
     toks = jnp.asarray(np.random.default_rng(0).integers(
         0, cfg.vocab_size, (1, 64)), jnp.int32)
     base, _, _ = apply_model(params, cfg, toks)
-    cfg_f = dataclasses.replace(cfg, flash_attention=True)
+    cfg_f = dataclasses.replace(cfg, flash_attention="pallas_interpret")
     flash, _, _ = apply_model(params, cfg_f, toks)
     np.testing.assert_allclose(np.asarray(flash), np.asarray(base),
                                rtol=2e-4, atol=2e-4)

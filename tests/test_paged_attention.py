@@ -178,8 +178,26 @@ def test_engine_token_identical_across_backends(qwen):
                for n in (5, 12, 9)]
     kw = dict(n_slots=2, page_size=4, n_pages=64)
     ref = _serve(params, cfg, prompts, "xla", **kw)
-    for backend in ("pallas", "pallas_interpret", "blocked"):
+    for backend in ("auto", "pallas_interpret", "blocked"):
         assert _serve(params, cfg, prompts, backend, **kw) == ref, backend
+
+
+@pytest.mark.skipif(jax.default_backend() == "tpu",
+                    reason="'pallas' is the Mosaic kernel on a TPU")
+def test_pallas_backend_refuses_non_tpu(qwen):
+    """'pallas' names the Mosaic kernel: off a TPU it raises instead of
+    quietly interpreting — at the op and through the engine."""
+    cfg, params = qwen
+    rng = np.random.default_rng(0)
+    q, pool_k, pool_v, pages, kv_map = _pool_case(rng, 2, 4, 2, 8, 4, 4)
+    lens = jnp.asarray([3, 5], jnp.int32)
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        paged_attn(q, pool_k, pool_v, pages, lens, scale=1.0,
+                   kv_of_q=kv_map, backend="pallas")
+    prompt = rng.integers(0, cfg.vocab_size, 5).astype(np.int32)
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        _serve(params, cfg, [prompt], "pallas", n_slots=1, page_size=4,
+               n_pages=16)
 
 
 def test_engine_chunked_prefill_offsets_token_identical(qwen):
@@ -193,7 +211,7 @@ def test_engine_chunked_prefill_offsets_token_identical(qwen):
     kw = dict(n_slots=2, page_size=4, n_pages=64, prefill_chunk=8,
               prefix_cache=True)
     ref = _serve(params, cfg, prompts, "xla", **kw)
-    assert _serve(params, cfg, prompts, "pallas", **kw) == ref
+    assert _serve(params, cfg, prompts, "pallas_interpret", **kw) == ref
 
 
 def test_engine_sliding_window_softcap_token_identical():
@@ -206,7 +224,7 @@ def test_engine_sliding_window_softcap_token_identical():
     prompts = [rng.integers(0, cfg.vocab_size, 40).astype(np.int32)]
     kw = dict(n_slots=1, page_size=8, n_pages=16)
     ref = _serve(params, cfg, prompts, "xla", **kw)
-    for backend in ("pallas", "pallas_interpret"):
+    for backend in ("auto", "pallas_interpret"):
         assert _serve(params, cfg, prompts, backend, **kw) == ref, backend
     dense = np.asarray(generate(params, cfg, jnp.asarray(prompts[0])[None],
                                 max_new=6))[0]

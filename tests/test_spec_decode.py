@@ -214,7 +214,7 @@ def test_spec_chunked_prefill_prefix_cache_identity(qwen):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("backend", ["xla", "blocked", "pallas"])
+@pytest.mark.parametrize("backend", ["xla", "blocked", "pallas_interpret"])
 @pytest.mark.parametrize("k", [1, 2, 4, 8])
 def test_spec_backend_k_sweep(qwen, backend, k):
     cfg, params = qwen
