@@ -474,8 +474,9 @@ def run_telemetry(smoke: bool = False, trace_out=None, metrics_out=None):
     valid = all(e["dur"] >= 0 and e["ts"] >= 0
                 and {"name", "ph", "ts", "dur", "pid", "tid"} <= set(e)
                 for e in spans)
-    need = {"step", "prefill_chunk", "decode_step", "evict", "request",
-            "queued", "prefill", "decode", "admit", "first_token"}
+    need = {"serve.step", "serve.prefill_chunk", "serve.decode", "evict",
+            "request", "queued", "prefill", "decode", "admit",
+            "first_token"}
     valid &= need <= names
     # reconciliation: per request, phase spans telescope to the latency
     phases = {}
@@ -652,7 +653,7 @@ def run_kv_quant(smoke: bool = False):
        admits ~1 request at a time, the quantized pools run all slots
        concurrently, so the capacity win converts into batched decode
        throughput (int8 must reach ≥1.3x bf16).  Decode time comes from
-       the telemetry tracer's ``decode_step`` spans, so prefill (equal
+       the telemetry tracer's ``serve.decode`` spans, so prefill (equal
        work in every arm) does not dilute the ratio.
     3. **agreement** — per-position top-1 argmax agreement of a paged
        prefill over the quantized pool vs the dense pool (int8 ≥0.99 on
@@ -801,7 +802,7 @@ def run_kv_quant(smoke: bool = False):
         eng.run()
         wall = time.perf_counter() - t0
         dec_us = sum(e["dur"] for e in tel.tracer.chrome_events()
-                     if e.get("ph") == "X" and e["name"] == "decode_step")
+                     if e.get("ph") == "X" and e["name"] == "serve.decode")
         st = eng.stats()
         res = eng.results()
         return {

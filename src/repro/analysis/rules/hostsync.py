@@ -20,8 +20,8 @@ Reachability is a static call-graph closure with two root classes:
     the local scan targets the quiet case: host ops sitting directly in
     the step function's own module.
 
-Intentional syncs (the engine's step boundaries, opt-in ``--time-device``
-blocks) carry ``# analysis: allow(host-sync): <reason>`` annotations.
+Intentional syncs (the engine's step boundaries) carry
+``# analysis: allow(host-sync): <reason>`` annotations.
 """
 from __future__ import annotations
 

@@ -105,4 +105,5 @@ def encoded_matmul_pallas(x_codes: jnp.ndarray, wt: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), out_block_map),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="encoded_matmul_pallas",    # the device op's name in a trace
     )(x_codes, wt.astype(jnp.bfloat16), bias2d)

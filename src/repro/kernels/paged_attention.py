@@ -264,6 +264,7 @@ def paged_attn_pallas(q, pool_k, pool_v, pages, lens, window, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), q.dtype),
         interpret=interpret,
+        name="paged_attn_pallas",        # the device op's name in a trace
     )(pages, lens, win, *operands)
     return unfold_q(out, S, G)
 

@@ -90,12 +90,8 @@ def main():
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="wrap the serving run in a jax.profiler trace "
                          "(TensorBoard/XPlane dump with per-op device "
-                         "time)")
-    ap.add_argument("--time-device", action="store_true",
-                    help="device-time attribution: block_until_ready "
-                         "around every jitted prefill/decode call so "
-                         "device step time separates from host scheduler "
-                         "time (adds a sync per step)")
+                         "time and the engine's serve.* host spans on "
+                         "the same clock)")
     ap.add_argument("--drift-every", type=int, default=0, metavar="N",
                     help="with --mac encoded: sample dense-vs-encoded "
                          "top-1 logit agreement online every N engine "
@@ -259,9 +255,8 @@ def main():
         if args.drift_every > 0:
             drift = DriftMonitor(params_ref, cfg_ref,
                                  every=args.drift_every)
-        tel = ServeTelemetry(trace=bool(args.trace_out),
-                             time_device=args.time_device,
-                             drift=drift, profile_dir=args.profile_dir)
+        tel = ServeTelemetry(trace=bool(args.trace_out), drift=drift,
+                             profile_dir=args.profile_dir)
         engine = Engine(params, cfg, n_slots=args.slots,
                         page_size=args.page_size, n_pages=args.n_pages,
                         reserve=args.reserve, mesh=mesh,
@@ -305,9 +300,6 @@ def main():
             print(f"  ttft_p50={st['ttft_p50_s']:.3f}s "
                   f"tpot_p50={st.get('tpot_p50_s', float('nan')):.4f}s "
                   f"step_p50={st['step_ms_p50']:.2f}ms")
-        if args.time_device and "device_decode_ms_p50" in st:
-            print(f"  device: decode_p50={st['device_decode_ms_p50']:.2f}ms "
-                  f"prefill_p50={st.get('device_prefill_ms_p50', 0.0):.2f}ms")
         if drift is not None and drift.last is not None:
             print(f"  drift: top1_agreement={drift.last:.4f} "
                   f"abs_logit_delta={drift.last_delta:.4f}")

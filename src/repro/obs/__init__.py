@@ -9,7 +9,7 @@ standalone.
 from .stats import percentile, percentiles
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       DEFAULT_BUCKETS)
-from .trace import Tracer, NULL_SPAN
+from .trace import Tracer
 from .jitcount import CompileTracker
 from .drift import DriftMonitor, logit_agreement
 from .profile import profiler_trace

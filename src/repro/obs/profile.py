@@ -4,9 +4,9 @@
 ``jax.profiler.start_trace``/``stop_trace`` pair when a directory is
 given (``launch/serve.py --profile-dir``), and is a no-op otherwise.
 The resulting TensorBoard/XPlane dump attributes time *inside* the
-jitted steps (per-op device time), complementing the host-side
-``time_device`` attribution the telemetry layer records per engine step
-(DESIGN.md §9).
+jitted steps (per-op device time) and holds the engine's ``serve.*``
+host spans on the same clock, so a gap in the device's work can be put
+down to the host work around it (DESIGN.md §9).
 
 A trace that was asked for and cannot start or stop is an error: the
 exception propagates, so a profiled run never exits 0 without its trace.

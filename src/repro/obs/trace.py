@@ -1,21 +1,27 @@
-"""Request-lifecycle event tracer: Chrome trace-event JSON + JSONL.
+"""Engine span API with two sinks: the profiler's trace and a Chrome
+trace-event JSON + JSONL.
 
-Records timestamped spans and instants for the serving engine
-(DESIGN.md §9) and exports them in the Chrome trace-event format, so a
-serving run can be opened directly in Perfetto (https://ui.perfetto.dev)
-or ``chrome://tracing``: one track per request plus engine/device tracks,
-spans for queue wait / prefill chunks / decode steps, instants for
-evictions, stalls, and COW copies.
+``Tracer.span(name, ...)`` always enters a ``jax.profiler.TraceAnnotation``
+of that name: while a profiler trace runs (``--profile-dir``, or the
+benchmark's traced window) the span lands in it on the same clock as the
+device planes, so host time can be set against device time; with no trace
+running the annotation records nothing.  When the tracer is enabled
+(``--trace-out``) the same span is also recorded as a Chrome *complete*
+event, so a serving run can be opened directly in Perfetto
+(https://ui.perfetto.dev) or ``chrome://tracing``: one track per request
+plus the engine track, spans for queue wait / prefill chunks / decode
+steps, instants for evictions, stalls, and COW copies.
 
-Overhead contract: a disabled tracer is near-free.  ``span()`` returns
-one shared no-op context-manager singleton (no per-call allocation) and
-``complete``/``instant`` return before touching the event list — hot
-call sites additionally guard on ``tracer.enabled`` so even the
-timestamp reads and args dicts are skipped (asserted by the
-disabled-fast-path test).
+Overhead contract: a disabled tracer costs one ``TraceAnnotation`` per
+span (about half a microsecond on a host CPU with no profiler running)
+and records nothing; ``complete``/``instant`` return before touching the
+event list.  Those two stay Chrome-only: they serve events that cannot be
+context managers (request lifecycle phases written after the fact, and
+instants), and hot call sites guard them on ``tracer.enabled`` so even
+the timestamp reads and args dicts are skipped.
 
-All spans are emitted as *complete* events (``ph: "X"`` — one record
-carrying both start and duration), so begin/end matching holds by
+All Chrome spans are emitted as *complete* events (``ph: "X"`` — one
+record carrying both start and duration), so begin/end matching holds by
 construction; timestamps are microseconds relative to the tracer's
 creation on one monotonic clock (``time.perf_counter``).
 """
@@ -25,44 +31,44 @@ import json
 import time
 from typing import Dict, List, Optional
 
-
-class _NullSpan:
-    """Shared no-op context manager for the disabled fast path."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-NULL_SPAN = _NullSpan()
+from jax.profiler import TraceAnnotation
 
 
 class _Span:
-    __slots__ = ("tr", "name", "tid", "cat", "args", "t0")
+    """An enabled tracer's span: the profiler annotation plus a Chrome
+    complete event on exit."""
+    __slots__ = ("tr", "name", "tid", "cat", "args", "t0", "ann")
 
     def __init__(self, tr, name, tid, cat, args):
         self.tr, self.name, self.tid = tr, name, tid
         self.cat, self.args = cat, args
+        self.ann = TraceAnnotation(name, **(args or {}))
 
     def __enter__(self):
+        self.ann.__enter__()
         self.t0 = self.tr.now()
         return self
+
+    def set_metadata(self, **args) -> None:
+        """Args known only at the span's end, in both sinks (the name
+        matches ``TraceAnnotation.set_metadata``, which a disabled
+        tracer's span is)."""
+        self.ann.set_metadata(**args)
+        self.args = {**(self.args or {}), **args}
 
     def __exit__(self, *exc):
         self.tr.complete(self.name, self.t0, self.tr.now(),
                          tid=self.tid, cat=self.cat, args=self.args)
+        self.ann.__exit__(*exc)
         return False
 
 
 class Tracer:
     """Append-only trace-event recorder on one monotonic clock.
 
-    Track layout (``tid``): 0 = engine loop, 1 = device time, and one
-    track per request via ``repro.serve.telemetry.req_tid``.  ``pid`` is
-    always 0 (single process).
+    Track layout (``tid``): 0 = engine loop, and one track per request
+    via ``repro.serve.telemetry.req_tid``.  ``pid`` is always 0 (single
+    process).
     """
 
     def __init__(self, enabled: bool = True):
@@ -90,10 +96,12 @@ class Tracer:
 
     def span(self, name: str, tid: int = 0, cat: str = "",
              args: Optional[dict] = None):
-        """Context manager measuring a span; no-op singleton when
-        disabled (zero allocation per call)."""
+        """Context manager measuring a span: a ``TraceAnnotation`` named
+        ``name`` with ``args`` as its metadata (keep them few and
+        scalar), plus a Chrome complete event when enabled.  Either kind
+        takes ``set_metadata(**args)`` for args known only at the end."""
         if not self.enabled:
-            return NULL_SPAN
+            return TraceAnnotation(name, **(args or {}))
         return _Span(self, name, tid, cat, args)
 
     def complete(self, name: str, t_start_s: float, t_end_s: float,

@@ -9,4 +9,4 @@ from .encoded import (prepare_encoded_serving, prepare_drafter,
                       search_family_encodings, fold_linear_params)
 from .spec import (greedy_accept, rejection_sample, make_spec_draft,
                    make_spec_verify)
-from .telemetry import ServeTelemetry, req_tid, TID_ENGINE, TID_DEVICE
+from .telemetry import ServeTelemetry, req_tid, TID_ENGINE

@@ -48,7 +48,7 @@ from .paged_cache import PagedKVCache, _copy_page_jit, pages_for
 from .scheduler import (Scheduler, Request, QUEUED, PREFILLING, DECODING,
                         FINISHED)
 from .spec import greedy_accept, make_spec_draft, make_spec_verify
-from .telemetry import ServeTelemetry, TID_DEVICE, TID_ENGINE, req_tid
+from .telemetry import ServeTelemetry, TID_ENGINE, req_tid
 
 
 def _shard_params(params, mesh):
@@ -375,12 +375,6 @@ class Engine:
         self._h_step = reg.histogram("step_ms", "engine step wall ms",
                                      buckets=(1, 2, 5, 10, 25, 50, 100,
                                               250, 500, 1000))
-        self._h_dev_decode = reg.histogram(
-            "device_decode_ms", "blocked decode-step device ms",
-            buckets=(0.5, 1, 2, 5, 10, 25, 50, 100, 250, 500))
-        self._h_dev_prefill = reg.histogram(
-            "device_prefill_ms", "blocked prefill-chunk device ms",
-            buckets=(0.5, 1, 2, 5, 10, 25, 50, 100, 250, 500))
         self._g_pages_free = reg.gauge("pages_free",
                                        "strictly free pool pages")
         self._g_pages_cached = reg.gauge(
@@ -413,12 +407,6 @@ class Engine:
         self._g_spec_rate = reg.gauge(
             "spec_acceptance_rate",
             "accepted / considered draft tokens, cumulative")
-        self._h_dev_draft = reg.histogram(
-            "device_draft_ms", "blocked draft-k device ms",
-            buckets=(0.5, 1, 2, 5, 10, 25, 50, 100, 250, 500))
-        self._h_dev_verify = reg.histogram(
-            "device_verify_ms", "blocked verify-step device ms",
-            buckets=(0.5, 1, 2, 5, 10, 25, 50, 100, 250, 500))
 
     @property
     def metrics(self) -> dict:
@@ -511,25 +499,26 @@ class Engine:
     # ---- one scheduler tick ------------------------------------------------
 
     def step(self) -> None:
-        """One scheduler tick, instrumented: the step itself is a span on
-        the engine track, per-step wall time lands in the ``step_ms``
+        """One scheduler tick, instrumented: the tick is the ``serve.step``
+        span, with a child span at each layer boundary inside it
+        (DESIGN.md §9); per-step wall time lands in the ``step_ms``
         histogram, and the allocator/queue gauges refresh after the
-        work."""
+        work (``serve.gauges``)."""
         tr = self.tel.tracer
-        t0 = time.perf_counter()
-        try:
-            self._step_impl()
-        finally:
-            t1 = time.perf_counter()
-            self._h_step.observe((t1 - t0) * 1e3, mac=self._mac)
-            if tr.enabled:
-                tr.complete("step", t0, t1, tid=TID_ENGINE, cat="engine",
-                            args={"step": self._steps})
-            self._update_gauges()
-            if self.kv.ledger is not None:
-                # sanitizer: per-step page conservation + shadow/real
-                # cross-check (DESIGN.md §12)
-                self.kv.ledger.verify()
+        with tr.span("serve.step", args={"step": self._steps + 1}) as span:
+            t0 = time.perf_counter()
+            try:
+                n_chunks, n_active = self._step_impl()
+                span.set_metadata(n_active=n_active, n_chunks=n_chunks)
+            finally:
+                self._h_step.observe((time.perf_counter() - t0) * 1e3,
+                                     mac=self._mac)
+                with tr.span("serve.gauges"):
+                    self._update_gauges()
+                    if self.kv.ledger is not None:
+                        # sanitizer: per-step page conservation +
+                        # shadow/real cross-check (DESIGN.md §12)
+                        self.kv.ledger.verify()
 
     def _update_gauges(self) -> None:
         """Pool / queue / prefix gauges (free–held–cached page split,
@@ -542,7 +531,8 @@ class Engine:
         if self.sched.prefix is not None:
             self._g_hit_win.set(self.sched.prefix.windowed_hit_rate)
 
-    def _step_impl(self) -> None:
+    def _step_impl(self) -> tuple:
+        """The tick's work; returns (prefill chunks run, rows decoded)."""
         self._c_steps.inc()
         self.clock += 1
         if self.tel.drift is not None and not self.spec_k:
@@ -566,9 +556,10 @@ class Engine:
                 chunked.add(req.rid)
                 self._prefill_chunk(req)
         if self.spec_k:
-            self._spec_round(chunked)
-            return
-        active = self._runnable()
+            return len(chunked), self._spec_round(chunked)
+        tr = self.tel.tracer
+        with tr.span("serve.grow"):
+            active = self._runnable()
         # occupancy counts every slot that did work this step: decoding
         # slots plus slots that ran a prefill chunk (a request that
         # finished its prefill and decodes in the same step counts once)
@@ -576,58 +567,46 @@ class Engine:
         self._c_occ.inc(len(worked) / self.kv.n_slots)
         if not active:
             if chunked or not self.sched.queue:
-                return                     # prefill progress / fully idle
+                return len(chunked), 0     # prefill progress / fully idle
             raise RuntimeError(
                 "page pool too small for the queued request "
                 f"(need {self.sched._pages_needed(self.sched.queue[0])}"
                 f" pages, {self.kv.alloc.n_free} free)")
-        tokens = np.zeros((self.kv.n_slots, 1), np.int32)
-        # refresh lens for every slotted request (stalled ones included, so
-        # their dummy write this step lands past their pages → scratch;
-        # mid-prefill slots' dummy write lands at their cursor and is
-        # overwritten by their next chunk before it is ever read)
-        for r in self.sched.slots:
-            if r is not None:
-                self.kv.set_len(r.slot, r.n_cached)
-        for req in active:
-            tokens[req.slot, 0] = req.out[-1]
-        tr = self.tel.tracer
-        t_d0 = time.perf_counter()
-        with self._mesh_ctx():
-            toks, self.kv.layers = self._step(
-                self.params, self.kv.layers, jnp.asarray(tokens),
-                self.kv.pages_dev(), self.kv.lens_dev())
-            if self.tel.time_device:
-                # device-time attribution (DESIGN.md §9): block on the
-                # step outputs so [t_d0, t_d1] is dispatch+device time,
-                # separable from the host scheduler time around it
-                # analysis: allow(host-sync): opt-in --time-device sync
-                jax.block_until_ready((toks, self.kv.layers))
-                t_d1 = time.perf_counter()
-                self._h_dev_decode.observe((t_d1 - t_d0) * 1e3,
-                                           mac=self._mac)
-                if tr.enabled:
-                    tr.complete("device:decode", t_d0, t_d1,
-                                tid=TID_DEVICE, cat="device",
-                                args={"n_active": len(active)})
-        # analysis: allow(host-sync): THE step boundary — decoded tokens
-        # must reach the host for scheduling (eos/done/emit decisions)
-        toks = np.asarray(toks)
-        if tr.enabled:
-            tr.complete("decode_step", t_d0, time.perf_counter(),
-                        tid=TID_ENGINE, cat="engine",
-                        args={"n_active": len(active),
-                              "rids": [r.rid for r in active]})
-        now = time.perf_counter()
-        for req in active:
-            req.n_cached += 1
-            req.out.append(int(toks[req.slot]))
-            self._c_decode.inc(1, mac=self._mac)
-            if req.done:
-                self.sched.finish(req, now)
-                self._trace_finish(req)
+        with tr.span("serve.inputs"):
+            tokens = np.zeros((self.kv.n_slots, 1), np.int32)
+            # refresh lens for every slotted request (stalled ones
+            # included, so their dummy write this step lands past their
+            # pages → scratch; mid-prefill slots' dummy write lands at
+            # their cursor and is overwritten by their next chunk before
+            # it is ever read)
+            for r in self.sched.slots:
+                if r is not None:
+                    self.kv.set_len(r.slot, r.n_cached)
+            for req in active:
+                tokens[req.slot, 0] = req.out[-1]
+            tok_dev = jnp.asarray(tokens)
+            pages_dev, lens_dev = self.kv.pages_dev(), self.kv.lens_dev()
+        with tr.span("serve.decode", args={"n_active": len(active)}):
+            with tr.span("serve.dispatch"), self._mesh_ctx():
+                toks, self.kv.layers = self._step(
+                    self.params, self.kv.layers, tok_dev, pages_dev,
+                    lens_dev)
+            with tr.span("serve.wait"):
+                # analysis: allow(host-sync): THE step boundary — decoded
+                # tokens must reach the host for scheduling (eos/done/emit)
+                toks = np.asarray(toks)
+        with tr.span("serve.commit"):
+            now = time.perf_counter()
+            for req in active:
+                req.n_cached += 1
+                req.out.append(int(toks[req.slot]))
+                self._c_decode.inc(1, mac=self._mac)
+                if req.done:
+                    self.sched.finish(req, now)
+                    self._trace_finish(req)
+        return len(chunked), len(active)
 
-    def _spec_round(self, chunked) -> None:
+    def _spec_round(self, chunked) -> int:
         """One speculative draft+verify round (DESIGN.md §10).
 
         Per active slot: draft k greedy tokens with the drafter (ONE
@@ -648,7 +627,61 @@ class Engine:
         w = 1 stall exactly like the non-speculative path.  Non-active
         slotted requests get their device lens pushed to the end of their
         owned pages so the round's k+1 batched writes land in the scratch
-        page — never in a page a peer might share."""
+        page — never in a page a peer might share.  Returns the rows
+        that decoded."""
+        k = self.spec_k
+        tr = self.tel.tracer
+        with tr.span("serve.grow"):
+            active, wins = self._write_windows()
+        worked = set(chunked) | {r.rid for r in active}
+        self._c_occ.inc(len(worked) / self.kv.n_slots)
+        if not active:
+            if chunked or not self.sched.queue:
+                return 0
+            raise RuntimeError(
+                "page pool too small for the queued request "
+                f"(need {self.sched._pages_needed(self.sched.queue[0])}"
+                f" pages, {self.kv.alloc.n_free} free)")
+        with tr.span("serve.inputs"):
+            act = {r.rid for r in active}
+            for r in self.sched.slots:
+                if r is None:
+                    continue
+                if r.rid in act:
+                    self.kv.set_len(r.slot, r.n_cached)
+                else:
+                    self.kv.set_len(r.slot,
+                                    len(r.pages) * self.kv.page_size)
+            tokens = np.zeros((self.kv.n_slots, 1), np.int32)
+            for req in active:
+                tokens[req.slot, 0] = req.out[-1]
+            pages_dev, lens_dev = self.kv.pages_dev(), self.kv.lens_dev()
+            tok_dev = jnp.asarray(tokens)
+        args = {"k": k, "n_active": len(active)}
+        with tr.span("serve.draft", args=args), \
+                tr.span("serve.dispatch"), self._mesh_ctx():
+            d_toks, self.kv.layers = self._draft(
+                self.draft_params, self.kv.layers, tok_dev,
+                pages_dev, lens_dev)
+        with tr.span("serve.verify", args=args):
+            with tr.span("serve.dispatch"), self._mesh_ctx():
+                # d_toks stays on device: verify concatenates it with the
+                # round's input tokens inside the trace, so draft → verify
+                # is two back-to-back dispatches with no host sync between
+                v_toks, self.kv.layers = self._verify(
+                    self.params, self.kv.layers, tok_dev, d_toks,
+                    pages_dev, lens_dev)
+            with tr.span("serve.wait"):
+                # analysis: allow(host-sync): the round boundary —
+                # accept/rollback is host arithmetic over both token sets
+                d_np, v_np = np.asarray(d_toks), np.asarray(v_toks)
+        with tr.span("serve.commit"):
+            self._commit_round(active, wins, d_np, v_np)
+        return len(active)
+
+    def _write_windows(self):
+        """Speculative page growth: the rows that secured a write window
+        this round (oldest first) and each one's window."""
         k = self.spec_k
         tr = self.tel.tracer
         active, wins = [], {}
@@ -669,72 +702,12 @@ class Engine:
                 continue
             active.append(req)
         active = [r for r in active if r.state == DECODING]  # late evicts
-        worked = set(chunked) | {r.rid for r in active}
-        self._c_occ.inc(len(worked) / self.kv.n_slots)
-        if not active:
-            if chunked or not self.sched.queue:
-                return
-            raise RuntimeError(
-                "page pool too small for the queued request "
-                f"(need {self.sched._pages_needed(self.sched.queue[0])}"
-                f" pages, {self.kv.alloc.n_free} free)")
-        act = {r.rid for r in active}
-        for r in self.sched.slots:
-            if r is None:
-                continue
-            if r.rid in act:
-                self.kv.set_len(r.slot, r.n_cached)
-            else:
-                self.kv.set_len(r.slot, len(r.pages) * self.kv.page_size)
-        tokens = np.zeros((self.kv.n_slots, 1), np.int32)
-        for req in active:
-            tokens[req.slot, 0] = req.out[-1]
-        pages_dev, lens_dev = self.kv.pages_dev(), self.kv.lens_dev()
-        tok_dev = jnp.asarray(tokens)
-        t_d0 = time.perf_counter()
-        with self._mesh_ctx():
-            d_toks, self.kv.layers = self._draft(
-                self.draft_params, self.kv.layers, tok_dev,
-                pages_dev, lens_dev)
-            if self.tel.time_device:
-                # analysis: allow(host-sync): opt-in --time-device sync
-                jax.block_until_ready((d_toks, self.kv.layers))
-                t_d1 = time.perf_counter()
-                self._h_dev_draft.observe((t_d1 - t_d0) * 1e3,
-                                          mac=self.draft_cfg.mac.mode)
-                if tr.enabled:
-                    tr.complete("device:draft", t_d0, t_d1, tid=TID_DEVICE,
-                                cat="device", args={"k": k,
-                                                    "n_active": len(active)})
-        if tr.enabled:
-            tr.complete("draft_step", t_d0, time.perf_counter(),
-                        tid=TID_ENGINE, cat="engine",
-                        args={"k": k, "rids": [r.rid for r in active]})
-        t_v0 = time.perf_counter()
-        with self._mesh_ctx():
-            # d_toks stays on device: verify concatenates it with the
-            # round's input tokens inside the trace, so draft → verify is
-            # two back-to-back dispatches with no host sync between them
-            v_toks, self.kv.layers = self._verify(
-                self.params, self.kv.layers, tok_dev, d_toks,
-                pages_dev, lens_dev)
-            if self.tel.time_device:
-                # analysis: allow(host-sync): opt-in --time-device sync
-                jax.block_until_ready((v_toks, self.kv.layers))
-                t_v1 = time.perf_counter()
-                self._h_dev_verify.observe((t_v1 - t_v0) * 1e3,
-                                           mac=self._mac)
-                if tr.enabled:
-                    tr.complete("device:verify", t_v0, t_v1,
-                                tid=TID_DEVICE, cat="device",
-                                args={"k": k, "n_active": len(active)})
-        # analysis: allow(host-sync): the round boundary — accept/rollback
-        # is host arithmetic over the draft and verify tokens
-        d_np, v_np = np.asarray(d_toks), np.asarray(v_toks)
-        if tr.enabled:
-            tr.complete("verify_step", t_v0, time.perf_counter(),
-                        tid=TID_ENGINE, cat="engine",
-                        args={"k": k, "rids": [r.rid for r in active]})
+        return active, wins
+
+    def _commit_round(self, active, wins, d_np, v_np) -> None:
+        """Accept each row's longest agreeing draft prefix plus the
+        verifier's bonus token, and update the speculation counters."""
+        k = self.spec_k
         now = time.perf_counter()
         r_acc = r_cons = 0
         for req in active:
@@ -803,55 +776,45 @@ class Engine:
         C = self.prefill_chunk
         chunk = stream[start:start + C]
         n = int(chunk.shape[0])
-        padded = np.zeros((1, C), np.int32)
-        padded[0, :n] = chunk
         slot = req.slot
         tr = self.tel.tracer
-        t_c0 = time.perf_counter()
-        with self._mesh_ctx():
-            toks, self.kv.layers = self._prefill(
-                self.params, self.kv.layers, jnp.asarray(padded),
-                self.kv.pages_dev()[slot:slot + 1],
-                jnp.asarray([start], jnp.int32))
-            if self.tel.time_device:
-                # analysis: allow(host-sync): opt-in --time-device sync
-                jax.block_until_ready((toks, self.kv.layers))
-                t_c1 = time.perf_counter()
-                self._h_dev_prefill.observe((t_c1 - t_c0) * 1e3,
-                                            mac=self._mac)
-                if tr.enabled:
-                    tr.complete("device:prefill", t_c0, t_c1,
-                                tid=TID_DEVICE, cat="device",
-                                args={"rid": req.rid, "n": n})
-        if tr.enabled:
-            tr.complete("prefill_chunk", t_c0, time.perf_counter(),
-                        tid=TID_ENGINE, cat="engine",
-                        args={"rid": req.rid, "start": start, "n": n})
-        req.n_cached = start + n
-        self.kv.set_len(slot, req.n_cached)
-        self._c_chunks.inc(1, mac=self._mac)
-        self._c_prefill_tok.inc(n, mac=self._mac)
-        if req.n_cached < target:
-            return                          # more chunks to go
-        now = time.perf_counter()
-        req.state = DECODING
-        req.t_prefill_done = now
-        self._c_prefills.inc()
-        self.sched.note_prefilled(req)      # prompt pages → prefix index
-        if not req.out:
-            # analysis: allow(host-sync): first-token read at prefill
-            # completion — seeds the request's decode stream on the host
-            first = int(np.asarray(toks)[0, req.plen - 1 - start])
-            req.out = [first]
-            if req.t_first is None:         # honest TTFT across evictions
-                req.t_first = now
-                if tr.enabled:
-                    tr.instant("first_token", tid=req_tid(req.rid),
-                               cat="lifecycle", args={"rid": req.rid},
-                               t_s=now)
-        if req.done:                        # eos on the very first token
-            self.sched.finish(req, now)
-            self._trace_finish(req)
+        with tr.span("serve.prefill_chunk",
+                     args={"rid": req.rid, "start": start, "n": n}):
+            with tr.span("serve.inputs"):
+                padded = np.zeros((1, C), np.int32)
+                padded[0, :n] = chunk
+                args = (jnp.asarray(padded),
+                        self.kv.pages_dev()[slot:slot + 1],
+                        jnp.asarray([start], jnp.int32))
+            with tr.span("serve.dispatch"), self._mesh_ctx():
+                toks, self.kv.layers = self._prefill(
+                    self.params, self.kv.layers, *args)
+            req.n_cached = start + n
+            self.kv.set_len(slot, req.n_cached)
+            self._c_chunks.inc(1, mac=self._mac)
+            self._c_prefill_tok.inc(n, mac=self._mac)
+            if req.n_cached < target:
+                return                      # more chunks to go
+            now = time.perf_counter()
+            req.state = DECODING
+            req.t_prefill_done = now
+            self._c_prefills.inc()
+            self.sched.note_prefilled(req)  # prompt pages → prefix index
+            if not req.out:
+                with tr.span("serve.wait"):
+                    # analysis: allow(host-sync): first-token read at
+                    # prefill completion — seeds the decode stream on host
+                    first = int(np.asarray(toks)[0, req.plen - 1 - start])
+                req.out = [first]
+                if req.t_first is None:     # honest TTFT across evictions
+                    req.t_first = now
+                    if tr.enabled:
+                        tr.instant("first_token", tid=req_tid(req.rid),
+                                   cat="lifecycle", args={"rid": req.rid},
+                                   t_s=now)
+            if req.done:                    # eos on the very first token
+                self.sched.finish(req, now)
+                self._trace_finish(req)
 
     def _trace_finish(self, req: Request) -> None:
         """Emit the finished request's lifecycle phase spans on its own
@@ -945,11 +908,6 @@ class Engine:
             "mesh": (dict(zip(self.mesh.axis_names, self.mesh.devices.shape))
                      if self.mesh is not None else None),
         })
-        if self.tel.time_device:
-            m["device_decode_ms_p50"] = self._h_dev_decode.percentile(
-                50, mac=self._mac)
-            m["device_prefill_ms_p50"] = self._h_dev_prefill.percentile(
-                50, mac=self._mac)
         if self.spec_k:
             rounds = int(self._c_spec_rounds.total())
             prop = int(self._c_spec_prop.total())
@@ -964,11 +922,6 @@ class Engine:
                                           if rounds else 0.0),
                 "draft_mac_mode": self.draft_cfg.mac.mode,
             })
-            if self.tel.time_device:
-                m["device_draft_ms_p50"] = self._h_dev_draft.percentile(
-                    50, mac=self.draft_cfg.mac.mode)
-                m["device_verify_ms_p50"] = self._h_dev_verify.percentile(
-                    50, mac=self._mac)
         if self.tel.drift is not None and self.tel.drift.last is not None:
             m["encoded_drift_top1"] = self.tel.drift.last
         return m

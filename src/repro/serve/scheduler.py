@@ -155,7 +155,12 @@ class Scheduler:
         With the prefix index enabled, cached full prompt pages are mapped
         (shared, refcounted) into the request's page table first and only
         the remainder is freshly allocated; ``req.n_cached`` starts at the
-        hit length so the engine prefills only the suffix."""
+        hit length so the engine prefills only the suffix.  Spanned as
+        ``serve.admit`` (DESIGN.md §9)."""
+        with self.tel.tracer.span("serve.admit"):
+            return self._admit_fcfs()
+
+    def _admit_fcfs(self) -> List[Tuple[int, Request]]:
         out = []
         free = [i for i, r in enumerate(self.slots) if r is None]
         while self.queue and free:

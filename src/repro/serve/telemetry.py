@@ -7,26 +7,22 @@ reports through (DESIGN.md §9):
     engine's counters/gauges/histograms replace its old raw ``metrics``
     dict, and ``Engine.stats()`` / the BENCH json emitters are snapshots
     of it.
-  * ``tracer`` — the request-lifecycle event tracer (Chrome trace-event
-    export).  Disabled by default; when disabled every hook is a guarded
-    no-op so the engine hot loop pays ~nothing.
-  * ``time_device`` — device-time attribution: the engine brackets each
-    jitted prefill/decode call with ``block_until_ready`` timing, so
-    device step time and host scheduler time separate per engine step
-    (spans on the device track + ``device_*_ms`` histograms).
+  * ``tracer`` — the engine's span API (``repro.obs.Tracer``).  Its
+    ``serve.*`` spans always go to the profiler's trace (one
+    ``TraceAnnotation`` each, recorded only while a trace runs); the
+    Chrome trace-event export is off by default, and its lifecycle hooks
+    are guarded no-ops then.
   * ``drift`` — optional ``DriftMonitor``: online dense-vs-encoded top-1
     logit agreement, sampled every N steps, published as a gauge.
   * ``profile_dir`` — optional ``jax.profiler`` trace directory; the
     engine wraps ``run()`` in ``obs.profiler_trace``.
 
-Track-id layout for the tracer: tid 0 = the engine loop (step /
-prefill-chunk / decode spans — plus ``draft_step``/``verify_step``
-spans per speculative round under ``spec_decode``, DESIGN.md §10 —
-nested), tid 1 = device time (``device:prefill``/``device:decode``, and
-``device:draft``/``device:verify`` when speculating with
-``time_device``), and one track per request (``req_tid``) carrying its
-lifecycle — the contiguous ``queued`` → ``prefill`` → ``decode`` phase
-spans (whose durations sum to the request latency by construction — the
+Track-id layout for the tracer: tid 0 = the engine loop (the nested
+``serve.*`` spans of each step, DESIGN.md §9, with ``serve.draft`` /
+``serve.verify`` per speculative round under ``spec_decode``, §10), and
+one track per request (``req_tid``) carrying its lifecycle — the
+contiguous ``queued`` → ``prefill`` → ``decode`` phase spans (whose
+durations sum to the request latency by construction — the
 reconciliation the telemetry bench checks) plus
 submit/admit/first-token/evict/stall/COW instants.  Speculation adds
 ``spec_rounds``/``spec_draft_tokens``/``spec_accepted_tokens`` counters
@@ -39,7 +35,6 @@ from typing import Optional
 from repro.obs import DriftMonitor, MetricsRegistry, Tracer
 
 TID_ENGINE = 0
-TID_DEVICE = 1
 _TID_REQ_BASE = 16
 
 
@@ -50,14 +45,14 @@ def req_tid(rid: int) -> int:
 
 
 class ServeTelemetry:
-    """Bundle of registry + tracer + attribution/drift/profiler knobs.
+    """Bundle of registry + tracer + drift/profiler knobs.
 
     Engines that are handed no telemetry build a disabled one: metrics
     still accumulate (they are the engine's bookkeeping now) but the
-    tracer is off, no device sync is added, and no profiler runs.
+    Chrome tracer is off and no profiler runs.
     """
 
-    def __init__(self, *, trace: bool = False, time_device: bool = False,
+    def __init__(self, *, trace: bool = False,
                  drift: Optional[DriftMonitor] = None,
                  profile_dir: Optional[str] = None,
                  registry: Optional[MetricsRegistry] = None,
@@ -65,16 +60,14 @@ class ServeTelemetry:
         self.registry = registry if registry is not None else \
             MetricsRegistry()
         self.tracer = tracer if tracer is not None else Tracer(enabled=trace)
-        self.time_device = time_device
         self.drift = drift.bind(self.registry) if drift is not None else None
         self.profile_dir = profile_dir
         if self.tracer.enabled:
             self.tracer.thread(TID_ENGINE, "engine")
-            self.tracer.thread(TID_DEVICE, "device")
 
     @classmethod
     def disabled(cls) -> "ServeTelemetry":
-        """Metrics-only telemetry (tracer off, no sync, no profiler)."""
+        """Metrics-only telemetry (Chrome tracer off, no profiler)."""
         return cls()
 
     def write(self, trace_out: Optional[str] = None,

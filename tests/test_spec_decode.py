@@ -265,7 +265,7 @@ def test_spec_eviction_rollback_stress(qwen):
     # phase spans still telescope to the request span under spec rounds
     spans = [e for e in tel.tracer.chrome_events() if e["ph"] == "X"]
     names = {e["name"] for e in spans}
-    assert {"draft_step", "verify_step", "request"} <= names
+    assert {"serve.draft", "serve.verify", "request"} <= names
     for rid in rids:
         mine = {e["name"]: e for e in spans if e["tid"] == req_tid(rid)}
         total = sum(mine[n]["dur"] for n in ("queued", "prefill", "decode"))

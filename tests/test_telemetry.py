@@ -1,9 +1,12 @@
 """Observability subsystem (DESIGN.md §9): the shared percentile helper,
-the metrics registry, the lifecycle tracer (Chrome trace-event schema +
-disabled fast path), the engine's trace/metrics wiring (span presence,
-phase/latency reconciliation, in-flight TTFT), and drift-monitor parity
-with the offline logit-agreement measurement."""
+the metrics registry, the span API with its two sinks (the profiler's
+trace always, Chrome trace events when enabled; disabled path), the
+engine's trace/metrics wiring (``serve.*`` span names and nesting in both
+sinks, phase/latency reconciliation, in-flight TTFT), and drift-monitor
+parity with the offline logit-agreement measurement."""
+import glob
 import json
+import re
 
 import numpy as np
 import jax
@@ -12,12 +15,11 @@ import pytest
 from repro.configs import get_config
 from repro.models import init_model
 from repro.obs import (percentile, percentiles, Counter, Gauge, Histogram,
-                       MetricsRegistry, Tracer, NULL_SPAN, DriftMonitor,
+                       MetricsRegistry, Tracer, DriftMonitor,
                        logit_agreement)
 from repro.serve import Engine
 from repro.serve.scheduler import Request, FINISHED, DECODING
-from repro.serve.telemetry import (ServeTelemetry, req_tid, TID_ENGINE,
-                                   TID_DEVICE)
+from repro.serve.telemetry import ServeTelemetry, req_tid, TID_ENGINE
 
 
 @pytest.fixture(scope="module")
@@ -145,11 +147,15 @@ def test_tracer_chrome_schema(tmp_path):
 
 def test_disabled_tracer_is_free():
     tr = Tracer(enabled=False)
-    # span() hands back ONE shared no-op singleton: no per-call allocation
+    # span() hands back the profiler's annotation alone (what a span costs
+    # with the Chrome sink off), and records no Chrome event
     s1, s2 = tr.span("a", tid=3), tr.span("b", args={"x": 1})
-    assert s1 is NULL_SPAN and s2 is NULL_SPAN
+    assert type(s1) is jax.profiler.TraceAnnotation
+    assert type(s2) is jax.profiler.TraceAnnotation
     with s1:
         pass
+    with s2 as sp:
+        sp.set_metadata(y=2)
     tr.thread(0, "engine")
     tr.complete("c", 0.0, 1.0)
     tr.instant("d")
@@ -162,8 +168,8 @@ def test_serve_telemetry_bundle(tmp_path):
     tel.write()                                  # all-None export: no-op
     tel = ServeTelemetry(trace=True)
     ev = tel.tracer.chrome_events()
-    assert {e["tid"] for e in ev} == {TID_ENGINE, TID_DEVICE}
-    assert req_tid(0) > TID_DEVICE               # request tracks don't clash
+    assert {e["tid"] for e in ev} == {TID_ENGINE}
+    assert req_tid(0) > TID_ENGINE               # request tracks don't clash
     p = tmp_path / "t.json"
     tel.write(trace_out=str(p))
     assert "traceEvents" in json.loads(p.read_text())
@@ -173,11 +179,133 @@ def test_serve_telemetry_bundle(tmp_path):
 # engine wiring: spans, reconciliation, stats
 # ---------------------------------------------------------------------------
 
-def _pressure_run(params, cfg, *, time_device=False):
+SERVE_SPANS = {"serve.step", "serve.admit", "serve.prefill_chunk",
+               "serve.grow", "serve.inputs", "serve.decode",
+               "serve.dispatch", "serve.wait", "serve.commit",
+               "serve.gauges"}
+# the children of each span, in order, as a regular expression over their
+# names less the ``serve.`` prefix; a span not listed has no children
+CHILDREN = {
+    "step": r"admit ((prefill_chunk )+admit )*grow "
+            r"(inputs (decode |draft verify )commit )?gauges ",
+    "prefill_chunk": r"inputs dispatch (wait )?",
+    "decode": r"dispatch wait ",
+    "draft": r"dispatch ",
+    "verify": r"dispatch wait ",
+}
+
+
+def _chrome_spans(events):
+    """(name, start, end) of the engine track's ``serve.*`` spans."""
+    return [(e["name"], e["ts"], e["ts"] + e["dur"]) for e in events
+            if e["ph"] == "X" and e["name"].startswith("serve.")
+            and e["tid"] == TID_ENGINE]
+
+
+def _xplane_spans(log_dir):
+    """(name, start, end) of the ``serve.*`` host events of the one
+    profiler trace under ``log_dir``."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if not plane.name.startswith("/device:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("serve.")]
+
+
+def _span_tree(spans):
+    """Nest (name, start, end) spans of one thread by containment:
+    [(name, children)] in start order."""
+    roots, stack = [], []
+    for name, t0, t1 in sorted(spans, key=lambda x: (x[1], -x[2])):
+        while stack and stack[-1][2] <= t0:
+            stack.pop()
+        if stack:
+            assert t1 <= stack[-1][2], f"{name} overlaps {stack[-1][0]}"
+        node = (name, [])
+        (stack[-1][1][1] if stack else roots).append(node)
+        stack.append((name, node, t1))
+    return roots
+
+
+def _check_step_tree(spans):
+    """Assert that every span is under a ``serve.step`` and that each
+    span's children follow ``CHILDREN``; returns the tree as nested
+    tuples."""
+    def walk(node):
+        name, kids = node
+        seq = "".join(k[0].removeprefix("serve.") + " " for k in kids)
+        want = CHILDREN.get(name.removeprefix("serve."), "")
+        assert re.fullmatch(want, seq), (name, seq)
+        return name, tuple(walk(k) for k in kids)
+    tree = _span_tree(spans)
+    assert tree and all(name == "serve.step" for name, _ in tree)
+    return tuple(walk(n) for n in tree)
+
+
+def test_engine_spans_reach_the_profiler(qwen, tmp_path):
+    """One span API, two sinks.  With the Chrome tracer off the engine's
+    spans still reach the profiler's trace: one ``serve.step`` per engine
+    step with its children nested inside it in order, and no Chrome event
+    recorded.  With the tracer on, the profiler and the Chrome export get
+    the same names in the same nesting."""
+    cfg, params = qwen
+    off, on = tmp_path / "off", tmp_path / "on"
+    tel, eng, _ = _pressure_run(params, cfg, trace=False,
+                                profile_dir=str(off))
+    assert tel.tracer.events == [] and tel.tracer.chrome_events() == []
+    tree = _check_step_tree(_xplane_spans(off))
+    assert len(tree) == eng.stats()["steps"]
+    flat = str(tree)
+    assert "serve.prefill_chunk" in flat and "serve.decode" in flat
+    assert "('serve.wait', ())" in flat
+    tel, eng, _ = _pressure_run(params, cfg, trace=True, profile_dir=str(on))
+    assert _check_step_tree(_xplane_spans(on)) == tree
+    assert _check_step_tree(_chrome_spans(tel.tracer.chrome_events())) == \
+        tree
+
+
+def test_step_span_args(qwen):
+    """``serve.step`` carries its index and what it did, the chunk its
+    request and range, in the Chrome sink (the profiler's gets the same
+    metadata)."""
+    cfg, params = qwen
+    tel, eng, _ = _pressure_run(params, cfg)
+    ev = [e for e in tel.tracer.chrome_events() if e["ph"] == "X"]
+    steps = [e["args"] for e in ev if e["name"] == "serve.step"]
+    assert [a["step"] for a in steps] == list(range(1, len(steps) + 1))
+    assert sum(a["n_chunks"] for a in steps) == \
+        eng.stats()["prefill_chunks"]
+    assert sum(a["n_active"] for a in steps) == \
+        eng.stats()["decode_tokens"]
+    chunks = [e["args"] for e in ev if e["name"] == "serve.prefill_chunk"]
+    assert chunks and all(set(a) == {"rid", "start", "n"} for a in chunks)
+
+
+def test_spec_round_spans(qwen):
+    """A speculative round nests ``serve.draft`` (its dispatch) and
+    ``serve.verify`` (its dispatch and the one wait for both token sets)
+    where a plain step has ``serve.decode``."""
+    cfg, params = qwen
+    tel = ServeTelemetry(trace=True)
+    eng = Engine(params, cfg, n_slots=2, page_size=4, n_pages=16,
+                 prefill_chunk=4, spec_decode=2, telemetry=tel)
+    rng = np.random.default_rng(1)
+    for n in (5, 3):
+        eng.submit(rng.integers(0, cfg.vocab_size, n).astype(np.int32),
+                   max_new=6)
+    eng.run()
+    tree = _check_step_tree(_chrome_spans(tel.tracer.chrome_events()))
+    assert len(tree) == eng.stats()["steps"]
+    assert "serve.verify" in str(tree) and "serve.decode" not in str(tree)
+
+
+def _pressure_run(params, cfg, *, trace=True, profile_dir=None):
     """2 slots / 6×4-token pages / optimistic reserve: this geometry
     deterministically evicts AND page-stalls, so every lifecycle event
     kind lands in the trace."""
-    tel = ServeTelemetry(trace=True, time_device=time_device)
+    tel = ServeTelemetry(trace=trace, profile_dir=profile_dir)
     eng = Engine(params, cfg, n_slots=2, page_size=4, n_pages=6,
                  reserve="optimistic", prefill_chunk=4, telemetry=tel)
     rng = np.random.default_rng(0)
@@ -189,19 +317,20 @@ def _pressure_run(params, cfg, *, time_device=False):
 
 def test_engine_trace_lifecycle_and_reconciliation(qwen):
     cfg, params = qwen
-    tel, eng, rids = _pressure_run(params, cfg, time_device=True)
+    tel, eng, rids = _pressure_run(params, cfg)
     ev = tel.tracer.chrome_events()
     names = {e["name"] for e in ev}
-    assert {"submit", "admit", "first_token", "prefill_chunk",
-            "decode_step", "step", "evict", "stall", "request",
-            "queued", "prefill", "decode", "device:decode",
-            "device:prefill"} <= names
+    assert {"submit", "admit", "first_token", "evict", "stall", "request",
+            "queued", "prefill", "decode"} | SERVE_SPANS <= names
     spans = [e for e in ev if e["ph"] == "X"]
     assert all(e["dur"] >= 0 and e["ts"] >= 0 for e in spans)
-    # engine-track step spans are sequential (the loop never overlaps)
-    steps = [e for e in spans if e["name"] == "step"]
+    # engine-track step spans are sequential (the loop never overlaps),
+    # one per engine step, and every serve.* span nests inside one
+    steps = [e for e in spans if e["name"] == "serve.step"]
     ends = [s["ts"] + s["dur"] for s in steps]
     assert all(a["ts"] >= e - 1e-6 for a, e in zip(steps[1:], ends))
+    tree = _check_step_tree(_chrome_spans(ev))
+    assert len(tree) == len(steps) == eng.stats()["steps"]
     # phase spans telescope to the request span exactly (float rounding
     # only), for every request — including the evicted one
     for rid in rids:
@@ -216,9 +345,7 @@ def test_engine_trace_lifecycle_and_reconciliation(qwen):
     st = eng.stats()
     assert st["evictions"] >= 1 and st["stalls"] >= 1
     assert st["finished"] == 3
-    # device-time attribution: blocked per-call ms histograms populated
-    assert st["device_decode_ms_p50"] > 0
-    assert st["device_prefill_ms_p50"] > 0
+    assert not any(k.startswith("device_") for k in st)
     # registry gauges settle to an idle pool
     reg = tel.registry
     assert reg.gauge("pages_held").value() == 0

@@ -94,3 +94,36 @@ def test_paged_attention_compiles(one_chip, kv, sq):
     if quant:
         args += [_sds((n_pages, ps, N_KV), jnp.float32, one_chip)] * 2
     _assert_mosaic(f.lower(*args).compile())
+
+
+def test_encoded_matmul_op_name_is_pinned(one_chip):
+    """The kernel's device op keeps its name when the function around it
+    is renamed: the encoded-MAC roofline finds its ops by
+    ``%encoded_matmul_pallas.<n>``."""
+    kernel = encoded_matmul_pallas.__wrapped__
+
+    def renamed_outer(x, w, b):
+        return kernel(x, w, b, MONOS, bm=8, bn=128, bk=128)
+    compiled = jax.jit(renamed_outer).lower(
+        _sds((8, D_MODEL), jnp.int8, one_chip),
+        _sds((U, D_MODEL, D_MODEL), jnp.bfloat16, one_chip),
+        _sds((D_MODEL,), jnp.float32, one_chip)).compile()
+    _assert_mosaic(compiled)
+    assert "%encoded_matmul_pallas." in compiled.as_text()
+
+
+def test_paged_attention_op_name_is_pinned(one_chip):
+    """As above for the fused paged-attention kernel, whose roofline
+    finds its ops by ``%paged_attn_pallas.<n>``."""
+    kernel = paged_attn_pallas.__wrapped__
+
+    def renamed_outer(q, pk, pv, pg, ln):
+        return kernel(q, pk, pv, pg, ln, jnp.int32(2 ** 30),
+                      scale=HEAD_DIM ** -0.5, G=N_HEADS // N_KV)
+    pool = _sds((129, 16, N_KV, HEAD_DIM), jnp.float32, one_chip)
+    compiled = jax.jit(renamed_outer).lower(
+        _sds((8, 1, N_HEADS, HEAD_DIM), jnp.float32, one_chip), pool, pool,
+        _sds((8, 16), jnp.int32, one_chip),
+        _sds((8,), jnp.int32, one_chip)).compile()
+    _assert_mosaic(compiled)
+    assert "%paged_attn_pallas." in compiled.as_text()
