@@ -69,8 +69,11 @@ def _paged_attn_bench(rng):
     from repro.nn.paged import gather_kv, paged_attn_decode
 
     B, Hq, Hkv, D, ps, P = 4, 4, 2, 32, 16, 64       # 1024-token table
-    pool_k = jnp.asarray(rng.normal(size=(P + 1, ps, Hkv, D)), jnp.float32)
-    pool_v = jnp.asarray(rng.normal(size=(P + 1, ps, Hkv, D)), jnp.float32)
+    # one layer of stacked lane-dense pools (L, n_pages, ps, Hkv·D)
+    pool_k = jnp.asarray(rng.normal(size=(1, P + 1, ps, Hkv * D)),
+                         jnp.float32)
+    pool_v = jnp.asarray(rng.normal(size=(1, P + 1, ps, Hkv * D)),
+                         jnp.float32)
     pages = jnp.broadcast_to(jnp.arange(1, P + 1, dtype=jnp.int32)[None],
                              (B, P))
     kv_map = np.minimum(np.arange(Hq) // (Hq // Hkv), Hkv - 1)
@@ -78,7 +81,8 @@ def _paged_attn_bench(rng):
     scale = 1.0 / np.sqrt(D)
 
     def gather_ref(q, lens):
-        ck, cv = gather_kv(pool_k, pages), gather_kv(pool_v, pages)
+        ck = gather_kv(pool_k, 0, pages, Hkv)
+        cv = gather_kv(pool_v, 0, pages, Hkv)
         k_pos = jnp.arange(ck.shape[1])
         return paged_attn_decode(q, ck, cv, kv_map, scale=scale,
                                  q_pos=lens[:, None], k_pos=k_pos,
@@ -86,11 +90,11 @@ def _paged_attn_bench(rng):
 
     f_gather = jax.jit(gather_ref)
     f_blk = jax.jit(lambda q, lens: paged_attn(
-        q, pool_k, pool_v, pages, lens, scale=scale, kv_of_q=kv_map,
-        backend="blocked"))
+        q, pool_k, pool_v, pages, lens, layer=0, scale=scale,
+        kv_of_q=kv_map, backend="blocked"))
     f_int = jax.jit(lambda q, lens: paged_attn(
-        q, pool_k, pool_v, pages, lens, scale=scale, kv_of_q=kv_map,
-        backend="pallas_interpret"))
+        q, pool_k, pool_v, pages, lens, layer=0, scale=scale,
+        kv_of_q=kv_map, backend="pallas_interpret"))
     out = {"paged_attn_table_tokens": P * ps}
     for name, ln in (("short", 40), ("long", 512)):
         lens = jnp.full((B,), ln, jnp.int32)
@@ -117,14 +121,14 @@ def _paged_attn_dtype_axis(rng, B, Hq, Hkv, D, ps, P, kv_map, q, scale):
     here (unlike the shared-chain rows above) so the streamed bytes are
     real, not cache-resident."""
     from repro.kernels.paged_attention import paged_attn
-    from repro.quant.kvcache import quantize_kv
+    from repro.quant.kvcache import quantize_kv, stored_layout
 
     ln = 512
     P_own = ln // ps
     n_pages = 1 + B * P_own
-    dense_k = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, D)),
+    dense_k = jnp.asarray(rng.normal(size=(1, n_pages, ps, Hkv, D)),
                           jnp.float32)
-    dense_v = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, D)),
+    dense_v = jnp.asarray(rng.normal(size=(1, n_pages, ps, Hkv, D)),
                           jnp.float32)
     pg = np.zeros((B, P_own), np.int32)
     for b in range(B):
@@ -134,16 +138,17 @@ def _paged_attn_dtype_axis(rng, B, Hq, Hkv, D, ps, P, kv_map, q, scale):
     out = {}
     for mode in ("bf16", "int8", "int4"):
         if mode == "bf16":
-            pk, pv, sk, sv = dense_k, dense_v, None, None
+            pk, pv = stored_layout(dense_k), stored_layout(dense_v)
+            sk = sv = None
         else:
-            pk, sk = quantize_kv(dense_k, mode)
-            pv, sv = quantize_kv(dense_v, mode)
+            pk, sk = stored_layout(*quantize_kv(dense_k, mode))
+            pv, sv = stored_layout(*quantize_kv(dense_v, mode))
         f = jax.jit(lambda q, lens, pk=pk, pv=pv, sk=sk, sv=sv: paged_attn(
-            q, pk, pv, pages, lens, scale=scale, kv_of_q=kv_map,
+            q, pk, pv, pages, lens, layer=0, scale=scale, kv_of_q=kv_map,
             backend="blocked", scale_k=sk, scale_v=sv))
         us = time_call(f, q, lens, n=10)
         bytes_per_step = B * ln * (
-            2 * Hkv * pk.shape[-1] * pk.dtype.itemsize
+            2 * pk.shape[-1] * pk.dtype.itemsize
             + (2 * Hkv * 4 if sk is not None else 0))
         out[f"paged_attn_{mode}_us"] = us
         out[f"paged_attn_{mode}_pool_bytes"] = bytes_per_step

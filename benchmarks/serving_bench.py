@@ -682,7 +682,7 @@ def run_kv_quant(smoke: bool = False):
     import jax.numpy as jnp
     from repro.configs import get_config
     from repro.models import init_paged_cache
-    from repro.quant.kvcache import quantize_kv
+    from repro.quant.kvcache import quantize_kv, stored_layout
     from repro.kernels.paged_attention import paged_attn
     from repro.serve import Engine, PagedKVCache, ServeTelemetry
     from repro.serve.engine import make_paged_prefill
@@ -749,16 +749,17 @@ def run_kv_quant(smoke: bool = False):
         rng = np.random.default_rng(1)
         B, Hkv, D, P = 4, cfg.n_kv_p, cfg.head_dim_r, plen // ps
         n_pages = 1 + B * P                     # distinct chain per slot
-        dk = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, D))
+        dk = jnp.asarray(rng.normal(size=(1, n_pages, ps, Hkv, D))
                          .astype(np.float32))
-        dv = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, D))
+        dv = jnp.asarray(rng.normal(size=(1, n_pages, ps, Hkv, D))
                          .astype(np.float32))
         if kvd == "bf16":
-            pk, pv, sk, sv = dk.astype(cfg.cdtype), dv.astype(cfg.cdtype), \
-                None, None
+            pk = stored_layout(dk.astype(cfg.cdtype))
+            pv = stored_layout(dv.astype(cfg.cdtype))
+            sk = sv = None
         else:
-            pk, sk = quantize_kv(dk, kvd)
-            pv, sv = quantize_kv(dv, kvd)
+            pk, sk = stored_layout(*quantize_kv(dk, kvd))
+            pv, sv = stored_layout(*quantize_kv(dv, kvd))
         pg = np.zeros((B, P), np.int32)
         for b in range(B):
             pg[b] = 1 + b * P + np.arange(P)
@@ -769,11 +770,11 @@ def run_kv_quant(smoke: bool = False):
         q = jnp.asarray(rng.normal(size=(B, 1, cfg.n_heads, D)),
                         jnp.float32)
         f = jax.jit(lambda q: paged_attn(
-            q, pk, pv, pages, lens, scale=D ** -0.5, kv_of_q=kv_map,
-            backend="blocked", scale_k=sk, scale_v=sv))
+            q, pk, pv, pages, lens, layer=0, scale=D ** -0.5,
+            kv_of_q=kv_map, backend="blocked", scale_k=sk, scale_v=sv))
         us = time_call_local(f, q, n=n_iters)
         pool_bytes_read = B * plen * (
-            pk.dtype.itemsize * 2 * Hkv * pk.shape[-1]
+            pk.dtype.itemsize * 2 * pk.shape[-1]
             + (8 * Hkv if sk is not None else 0))   # 2 f32 scale rows
         return {"step_us": us,
                 "tokens_per_s": B / (us / 1e6),

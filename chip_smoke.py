@@ -166,35 +166,37 @@ def phase_kernels(args):
     from repro.kernels.paged_attention import paged_attn
     from repro.nn.paged import (gather_kv, gather_kv_dequant,
                                 paged_attn_decode)
-    from repro.quant.kvcache import quantize_kv
+    from repro.quant.kvcache import quantize_kv, stored_layout
     cfg = get_config(ARCH)
     H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_r
-    B, ps, P = 8, 16, 4
+    B, ps, P, L, layer = 8, 16, 4, 2, 1      # stacked pools; layer read
     n_pages = B * P + 1
     rng = np.random.default_rng(args.seed)
-    dense_k = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, D)), jnp.float32)
-    dense_v = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, D)), jnp.float32)
+    shape = (L, n_pages, ps, Hkv, D)
+    dense_k = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    dense_v = jnp.asarray(rng.normal(size=shape), jnp.float32)
     pages = jnp.asarray(1 + np.arange(B * P, dtype=np.int32).reshape(B, P))
     kv_map = np.arange(H, dtype=np.int32) // (H // Hkv)
     for pool in ("float32", "bfloat16", "int8", "int4"):
         if pool in ("int8", "int4"):
-            (pk, sk), (pv, sv) = quantize_kv(dense_k, pool), \
-                quantize_kv(dense_v, pool)
+            pk, sk = stored_layout(*quantize_kv(dense_k, pool))
+            pv, sv = stored_layout(*quantize_kv(dense_v, pool))
         else:
-            pk, pv = dense_k.astype(pool), dense_v.astype(pool)
+            pk = stored_layout(dense_k.astype(pool))
+            pv = stored_layout(dense_v.astype(pool))
             sk = sv = None
         for sq in (1, 5):
             q = jnp.asarray(rng.normal(size=(B, sq, H, D)), jnp.float32)
             lens = jnp.asarray(rng.integers(0, P * ps - sq, B), jnp.int32)
             t0 = time.perf_counter()
             out = jax.block_until_ready(paged_attn(
-                q, pk, pv, pages, lens, scale=D ** -0.5, kv_of_q=kv_map,
-                backend="pallas", scale_k=sk, scale_v=sv))
+                q, pk, pv, pages, lens, layer=layer, scale=D ** -0.5,
+                kv_of_q=kv_map, backend="pallas", scale_k=sk, scale_v=sv))
             dt = time.perf_counter() - t0
-            ck = gather_kv(pk, pages) if sk is None else \
-                gather_kv_dequant(pk, sk, pages)
-            cv = gather_kv(pv, pages) if sv is None else \
-                gather_kv_dequant(pv, sv, pages)
+            ck = gather_kv(pk, layer, pages, Hkv) if sk is None else \
+                gather_kv_dequant(pk, sk, layer, pages)
+            cv = gather_kv(pv, layer, pages, Hkv) if sv is None else \
+                gather_kv_dequant(pv, sv, layer, pages)
             k_pos = jnp.arange(ck.shape[1])
             ref = paged_attn_decode(
                 q, ck, cv, kv_map, scale=D ** -0.5,

@@ -208,9 +208,10 @@ def _executables(cfg, *, full: bool):
     return exes
 
 
-def _shard_args(exe, mesh, rules=None):
+def _shard_args(exe, mesh, rules=None, kv_heads=None):
     """Re-tag the abstract args with the engine's committed placements
-    (param rules / cache rules; scalars replicated)."""
+    (param rules / cache rules with the config's kv-head count; scalars
+    replicated)."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.parallel.sharding import param_specs
@@ -226,7 +227,7 @@ def _shard_args(exe, mesh, rules=None):
         if role == "params":
             out.append(tag(arg, param_specs(arg, mesh, rules=rules)))
         elif role in ("layers", "cache"):
-            out.append(tag(arg, cache_specs(arg, mesh)))
+            out.append(tag(arg, cache_specs(arg, mesh, kv_heads=kv_heads)))
         else:
             ndim = len(arg.shape)
             s = NamedSharding(mesh, P(*([None] * ndim)))
@@ -234,13 +235,14 @@ def _shard_args(exe, mesh, rules=None):
     return tuple(out)
 
 
-def _lower(exe, mesh, *, rules=None, donate_override=None):
+def _lower(exe, mesh, *, rules=None, donate_override=None, kv_heads=None):
     import jax
     from repro.parallel.sharding import set_mesh
     donate = exe["donate"] if donate_override is None else donate_override
     jf = exe["fn"] if exe.get("prejit") else \
         jax.jit(exe["fn"], donate_argnums=donate)
-    args = exe["args"] if mesh is None else _shard_args(exe, mesh, rules)
+    args = exe["args"] if mesh is None else \
+        _shard_args(exe, mesh, rules, kv_heads)
     ctx = set_mesh(mesh) if mesh is not None else contextlib.nullcontext()
     with ctx:
         lowered = jf.lower(*args)
@@ -511,7 +513,8 @@ def audit_cell(arch: str, cfg, dt: str, mesh, mesh_kind: str, *,
             continue
         label = f"{arch}/{dt}/{mesh_kind}/{mac_kind}/{name}"
         donate, args, lowered, compiled = _lower(
-            exe, mesh, rules=rules, donate_override=donate_override)
+            exe, mesh, rules=rules, donate_override=donate_override,
+            kv_heads=cfg.n_kv_p)
         hlo = compiled.as_text()
         findings += check_donation(hlo, exe["args"], donate, label,
                                    exact_shapes=(mesh is None),
@@ -650,7 +653,7 @@ def run_smoke_trace(arch: str = PRIMARY_ARCH, *,
     for shape in inject_decode_shapes:
         # a leaked shape retraces the decode step; pools are deep-copied
         # so the live (donated) buffers stay valid
-        layers = jax.tree.map(jnp.array, eng.kv.layers)
+        layers = jax.tree.map(jnp.array, eng.kv.pools)
         eng._step(eng.params, layers,
                   jnp.zeros(shape, jnp.int32),
                   jnp.zeros((shape[0], eng.kv.max_seq_pages), jnp.int32),

@@ -8,15 +8,15 @@ index maps stay inside their operand pools:
     ``paged_q_block_map``, exactly what ``paged_attn_pallas`` partials
     into its BlockSpecs) over the full ``(B, max_seq_pages)`` grid ×
     boundary ``lens`` values (0, 1, ps−1, ps, ps+1, 2ps−1, max_seq−Sq)
-    and asserts every returned page id equals the clamp contract
-    ``pages[b, min(p, (lens[b]+Sq−1)//ps)]`` — in-bounds AND never a
-    past-lens block;
+    and asserts every returned block reads the layer it was given and
+    the page of the clamp contract ``pages[b, min(p, (lens[b]+Sq−1)//ps)]``
+    — in-bounds AND never a past-lens block;
   * **paged attention, blocked (XLA) lowering** — executes
     ``_paged_attn_blocked`` under ``jax.disable_jit()`` with
-    ``jax.lax.dynamic_slice_in_dim`` / ``jnp.take`` replaced by guards
-    that assert every page-table slice and pool gather is in bounds, for
-    all three kv dtypes and both the multi-block and the pad-the-table
-    block widths;
+    ``jax.lax.dynamic_slice_in_dim`` / ``pool_pages`` replaced by guards
+    that assert every page-table slice and (layer, page) pool gather is
+    in bounds, for all three kv dtypes and both the multi-block and the
+    pad-the-table block widths;
   * **encoded matmul** — checks the grid index maps (``x_block_map`` …
     ``out_block_map``) against the padded operand shapes produced by
     ``kernels.ops``' padding helpers, over every registry linear
@@ -83,12 +83,16 @@ def check_paged_index_maps(kv_map: Optional[Callable] = None,
 
     if kv_map is None:
         kv_map = functools.partial(pa.paged_kv_block_map, Sq=Sq, ps=ps)
+    n_pages = B * P + 1
+    rows = min(pa.SCALE_ROWS, n_pages)    # page rows per scale block
     if scale_map is None:
-        scale_map = functools.partial(pa.paged_scale_block_map, Sq=Sq, ps=ps)
+        scale_map = functools.partial(pa.paged_scale_block_map, Sq=Sq, ps=ps,
+                                      rows=rows)
     if q_map is None:
         q_map = pa.paged_q_block_map
 
-    n_pages = B * P + 1
+    layer_id = 2                      # a nonzero layer, so layer 0 is wrong
+    layer = jnp.asarray([layer_id], jnp.int32)
     # distinct nonzero page ids per (b, p) cell so any mis-indexing is
     # visible as a wrong id, not a coincidental match
     pages_np = np.arange(1, n_pages).reshape(B, P).astype(np.int32)
@@ -110,13 +114,14 @@ def check_paged_index_maps(kv_map: Optional[Callable] = None,
             want = int(pages_np[b, min(p, last)])
             ctx = (f"{label} ps={ps} Sq={Sq} lens[b]={lens_list[b]} "
                    f"(b={b}, p={p})")
-            r = kv_map(b, p, pages, lens, win)
-            if len(r) != 4 or any(int(x) != 0 for x in r[1:]):
+            r = kv_map(b, p, pages, lens, win, layer)
+            if len(r) != 4 or int(r[0]) != layer_id or \
+                    any(int(x) != 0 for x in r[2:]):
                 out.append(Finding(RULE, kv_loc[0], kv_loc[1],
                                    f"kv map returned {r} — expected "
-                                   f"(page, 0, 0, 0) [{ctx}]"))
+                                   f"({layer_id}, page, 0, 0) [{ctx}]"))
                 continue
-            pid = int(r[0])
+            pid = int(r[1])
             if not 0 <= pid < n_pages:
                 out.append(Finding(
                     RULE, kv_loc[0], kv_loc[1],
@@ -129,14 +134,15 @@ def check_paged_index_maps(kv_map: Optional[Callable] = None,
                     RULE, kv_loc[0], kv_loc[1],
                     f"kv map reads page {pid}, contract says "
                     f"pages[b, min(p, {last})] = {want} — {kind} [{ctx}]"))
-            rs = scale_map(b, p, pages, lens, win)
-            if len(rs) != 3 or int(rs[0]) != want or \
-                    any(int(x) != 0 for x in rs[1:]):
+            rs = tuple(int(x) for x in scale_map(b, p, pages, lens, win,
+                                                 layer))
+            if rs != (layer_id, want // rows, 0):
                 out.append(Finding(
                     RULE, sc_loc[0], sc_loc[1],
-                    f"scale map returned {tuple(int(x) for x in rs)}, "
-                    f"expected ({want}, 0, 0) [{ctx}]"))
-            rq = q_map(b, p, pages, lens, win)
+                    f"scale map returned {rs}, expected ({layer_id}, "
+                    f"{want // rows}, 0): the {rows}-row block of page "
+                    f"{want} [{ctx}]"))
+            rq = q_map(b, p, pages, lens, win, layer)
             if tuple(int(x) for x in rq) != (b, 0, 0, 0):
                 out.append(Finding(
                     RULE, q_loc[0], q_loc[1],
@@ -145,25 +151,26 @@ def check_paged_index_maps(kv_map: Optional[Callable] = None,
     return out
 
 
-def _make_pools(mode: str, n_pages: int, ps: int, Hkv: int, D: int):
+def _make_pools(mode: str, L: int, n_pages: int, ps: int, Hkv: int,
+                D: int):
+    """Stacked lane-dense pools (L, n_pages, ps, Hkv·Dp) and, for
+    quantized modes, head-major scale rows (L, n_pages, Hkv·ps)."""
     import jax.numpy as jnp
-    if mode == "int8":
-        k = jnp.zeros((n_pages, ps, Hkv, D), jnp.int8)
-        s = jnp.zeros((n_pages, ps, Hkv), jnp.float32)
-        return k, k, s, s
-    if mode == "int4":
-        k = jnp.zeros((n_pages, ps, Hkv, D // 2), jnp.uint8)
-        s = jnp.zeros((n_pages, ps, Hkv), jnp.float32)
-        return k, k, s, s
-    k = jnp.zeros((n_pages, ps, Hkv, D), jnp.bfloat16)
-    return k, k, None, None
+    from repro.quant.kvcache import scale_pool_shape
+    if mode == "bf16":
+        k = jnp.zeros((L, n_pages, ps, Hkv * D), jnp.bfloat16)
+        return k, k, None, None
+    dt, dp = (jnp.int8, D) if mode == "int8" else (jnp.uint8, D // 2)
+    k = jnp.zeros((L, n_pages, ps, Hkv * dp), dt)
+    s = jnp.zeros(scale_pool_shape(L, n_pages, ps, Hkv), jnp.float32)
+    return k, k, s, s
 
 
 def check_blocked_lowering(*, ps: int, Sq: int, mode: str = "bf16",
                            bk: int, B: int = 2, P: int = 4) -> List[Finding]:
     """Run the XLA reference lowering eagerly with guarded slice/gather
     primitives: every ``dynamic_slice_in_dim`` over the page table and
-    every ``jnp.take`` into a pool must be in bounds, across boundary
+    every (layer, page) gather into a pool must be in bounds, across boundary
     lens values.  ``bk < ps`` exercises bp=1 multi-block stepping;
     ``bk=128`` exercises the pad-the-table path."""
     import numpy as np
@@ -171,18 +178,18 @@ def check_blocked_lowering(*, ps: int, Sq: int, mode: str = "bf16",
     import jax.numpy as jnp
     from repro.kernels import paged_attention as pa
 
-    Hq, Hkv, D = 2, 1, 4
+    Hq, Hkv, D, L = 2, 1, 4, 3
     n_pages = B * P + 1
     pages = jnp.asarray(
         np.arange(1, n_pages).reshape(B, P).astype(np.int32))
     pool_k, pool_v, scale_k, scale_v = _make_pools(
-        mode, n_pages, ps, Hkv, D)
+        mode, L, n_pages, ps, Hkv, D)
     q = jnp.zeros((B, Sq, Hq, D), jnp.float32)
     win = jnp.asarray(pa._NO_WINDOW, jnp.int32)
     errors: List[str] = []
 
     orig_ds = jax.lax.dynamic_slice_in_dim
-    orig_take = jnp.take
+    orig_pages = pa.pool_pages
 
     def guard_ds(operand, start, size, axis=0):
         s = int(start)
@@ -192,27 +199,27 @@ def check_blocked_lowering(*, ps: int, Sq: int, mode: str = "bf16",
                 f"{axis} of shape {operand.shape}")
         return orig_ds(operand, s, size, axis)
 
-    def guard_take(a, indices, axis=None, **kw):
-        if axis == 0 and hasattr(indices, "dtype") and \
-                jnp.issubdtype(indices.dtype, jnp.integer) and \
-                getattr(indices, "size", 0):
-            lo, hi = int(jnp.min(indices)), int(jnp.max(indices))
-            if lo < 0 or hi >= a.shape[0]:
-                errors.append(
-                    f"jnp.take gathers ids [{lo}, {hi}] from a pool of "
-                    f"{a.shape[0]} pages")
-        return orig_take(a, indices, axis=axis, **kw)
+    def guard_pages(pool, layer, pid):
+        lyr = int(layer)
+        lo, hi = int(jnp.min(pid)), int(jnp.max(pid))
+        if not 0 <= lyr < pool.shape[0]:
+            errors.append(f"pool gather reads layer {lyr} of a "
+                          f"{pool.shape[0]}-layer pool")
+        if lo < 0 or hi >= pool.shape[1]:
+            errors.append(f"pool gather reads pages [{lo}, {hi}] from a "
+                          f"pool of {pool.shape[1]} pages")
+        return orig_pages(pool, layer, pid)
 
     loc = _loc(pa._paged_attn_blocked)
     out: List[Finding] = []
     try:
         jax.lax.dynamic_slice_in_dim = guard_ds
-        jnp.take = guard_take
+        pa.pool_pages = guard_pages
         with jax.disable_jit():
             for ln in _boundary_lens(ps, P, Sq):
                 lens = jnp.asarray([ln] * B, jnp.int32)
                 pa._paged_attn_blocked(
-                    q, pool_k, pool_v, pages, lens, win, scale=1.0,
+                    q, pool_k, pool_v, pages, lens, win, L - 1, scale=1.0,
                     G=Hq // Hkv, bk=bk, scale_k=scale_k, scale_v=scale_v)
                 for e in errors:
                     out.append(Finding(
@@ -222,7 +229,7 @@ def check_blocked_lowering(*, ps: int, Sq: int, mode: str = "bf16",
                 errors.clear()
     finally:
         jax.lax.dynamic_slice_in_dim = orig_ds
-        jnp.take = orig_take
+        pa.pool_pages = orig_pages
     return out
 
 
@@ -305,8 +312,9 @@ def _registry_geometries():
 
 
 def _check_pool_layout(arch: str, cfg, dt: str, ps: int) -> List[Finding]:
-    """eval_shape the geometry's pool and cross-check the BlockSpec block
-    shape (1, ps, Hkv, Dp) the kernel would carve from it."""
+    """eval_shape the geometry's pools and cross-check the BlockSpec block
+    shapes the kernel would carve from them: (1, ps, Hkv·Dp) value pages
+    and head-major (Hkv·ps) scale rows."""
     import jax
     from repro.models import init_paged_cache
     out: List[Finding] = []
@@ -315,25 +323,25 @@ def _check_pool_layout(arch: str, cfg, dt: str, ps: int) -> List[Finding]:
         lambda: init_paged_cache(cfg, n_pages, ps))["layers"]
     quant = dt != "bf16"
     want_dp = cfg.head_dim_r // 2 if dt == "int4" else cfg.head_dim_r
+    hkv = cfg.n_kv_p
     for stage, st in abs_.items():
         pk = st["pool_k"]
-        if pk.shape[1:] != (n_pages, ps, cfg.n_kv_p, want_dp):
+        if pk.shape[1:] != (n_pages, ps, hkv * want_dp):
             out.append(Finding(
                 RULE, "src/repro/models/lm.py", 0,
                 f"{arch}/{stage} kv_dtype={dt}: pool shape "
                 f"{pk.shape} does not match the kernel block "
-                f"(1, {ps}, {cfg.n_kv_p}, {want_dp})"))
+                f"(1, {ps}, {hkv}·{want_dp})"))
         if quant != ("scale_k" in st):
             out.append(Finding(
                 RULE, "src/repro/models/lm.py", 0,
                 f"{arch}/{stage} kv_dtype={dt}: scale side pool "
                 f"{'missing' if quant else 'unexpected'}"))
-        elif quant and st["scale_k"].shape[1:] != (n_pages, ps,
-                                                   cfg.n_kv_p):
+        elif quant and st["scale_k"].shape[1:] != (n_pages, hkv * ps):
             out.append(Finding(
                 RULE, "src/repro/models/lm.py", 0,
                 f"{arch}/{stage} kv_dtype={dt}: scale pool shape "
-                f"{st['scale_k'].shape} mismatches (n_pages, ps, Hkv)"))
+                f"{st['scale_k'].shape} mismatches (n_pages, Hkv·ps)"))
     return out
 
 

@@ -35,9 +35,9 @@ def _kernel_off_by_one() -> bool:
     from repro.analysis.kernelcheck import check_paged_index_maps
     from repro.kernels import paged_attention as pa
 
-    def bad_map(b, p, pages_s, lens_s, win_s, *, Sq, ps):
+    def bad_map(b, p, pages_s, lens_s, win_s, layer_s, *, Sq, ps):
         p_eff = jnp.minimum(p + 1, (lens_s[b] + Sq - 1) // ps)  # off by one
-        return (pages_s[b, p_eff], 0, 0, 0)
+        return (layer_s[0], pages_s[b, p_eff], 0, 0)
 
     f = check_paged_index_maps(
         kv_map=functools.partial(bad_map, Sq=1, ps=8), ps=8, Sq=1,
@@ -48,8 +48,8 @@ def _kernel_off_by_one() -> bool:
 def _kernel_missing_clamp() -> bool:
     from repro.analysis.kernelcheck import check_paged_index_maps
 
-    def bad_map(b, p, pages_s, lens_s, win_s, *, Sq, ps):
-        return (pages_s[b, p], 0, 0, 0)             # reads past lens
+    def bad_map(b, p, pages_s, lens_s, win_s, layer_s, *, Sq, ps):
+        return (layer_s[0], pages_s[b, p], 0, 0)    # reads past lens
 
     f = check_paged_index_maps(
         kv_map=functools.partial(bad_map, Sq=1, ps=8), ps=8, Sq=1,

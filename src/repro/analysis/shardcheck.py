@@ -80,7 +80,8 @@ def check_param_coverage(arch: str, rules=None) -> List[Finding]:
 
 def check_cache_coverage(arch: str) -> List[Finding]:
     """Dense decode cache + paged pools (all kv dtypes): every leaf name
-    ruled, pools and scale pools sharded over the kv-head axis."""
+    ruled, pools and scale pools sharded over their head-major last
+    axis."""
     import dataclasses
     import jax
     from repro.configs.registry import get_config
@@ -121,11 +122,12 @@ def check_cache_coverage(arch: str) -> List[Finding]:
                     f"{tuple(leaf.shape)} has no _CACHE_RULES entry"))
                 continue
             if name.startswith(("pool_", "scale_")):
-                if len(items) <= 3 or items[3] != AXIS_MODEL:
+                if len(items) != len(leaf.shape) or items[-1] != AXIS_MODEL:
                     out.append(Finding(
                         RULE, STATESHARDING_REL, 0,
                         f"'{name}' rule {items} does not shard the "
-                        "kv-head axis (index 3) over the model axis"))
+                        "merged kv-head axis (the last) over the model "
+                        "axis"))
         if dt != "bf16" and not {"scale_k", "scale_v"} <= names:
             out.append(Finding(
                 RULE, "src/repro/models/lm.py", 0,

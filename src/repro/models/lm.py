@@ -24,7 +24,8 @@ from repro.nn.attention import init_kv_cache
 from repro.nn.mla import init_mla_cache
 from repro.nn.ssm import init_ssm_cache
 from repro.nn.xlstm import init_mlstm_cache, init_slstm_cache
-from repro.parallel.sharding import constrain, AXIS_BATCH, AXIS_MODEL
+from repro.parallel.sharding import constrain, get_mesh, AXIS_BATCH, AXIS_MODEL
+from repro.parallel.statesharding import pool_head_axis
 
 
 # ---------------------------------------------------------------------------
@@ -103,7 +104,7 @@ def init_model(key, cfg):
 # layer-stack execution
 # ---------------------------------------------------------------------------
 
-_CTX_KEYS = ("pos", "pages", "lens", "pad")  # broadcast layer-cache context
+_CTX_KEYS = ("pos", "pages", "lens", "pad", "layer")  # layer-cache context
 
 
 def _strip_pos(tree):
@@ -126,13 +127,30 @@ def _inject_pos(c_l, kind, ctx):
     return c_l
 
 
+def _pin_pools(st, cfg):
+    """Constrain a stage's paged pools to cache_specs' placement (whole
+    kv heads per model shard, else replicated).  Pinned on the way into
+    and out of every layer, so sharding propagation can neither split a
+    head inside the layer loop nor hand the donated pools back in
+    another layout than they came in (which would drop the donation)."""
+    hax = pool_head_axis(cfg.n_kv_p, get_mesh())
+    return {n: constrain(a, *([None] * (a.ndim - 1)), hax)
+            for n, a in st.items()}
+
+
 def _scan_stack(params_st, x, cfg, kind: str, windows, cache_st, positions,
                 ctx=None):
-    """Scan a homogeneous stacked stage. cache_st may be None."""
+    """Scan a homogeneous stacked stage. cache_st may be None.
+
+    A paged cache (``ctx`` holds the page table) is the stage's stacked
+    pools: they ride the scan carry and each layer writes its own rows in
+    place at its index, so a dispatch moves only the pool bytes it
+    writes or reads.  Any other cache is sliced per layer (scan xs/ys)."""
     ctx = ctx or {}
 
-    def apply_one(p_l, x, c_l, w_l):
-        c_l = _inject_pos(c_l, kind, ctx)
+    def apply_one(p_l, x, c_l, w_l, layer=None):
+        c_l = _inject_pos(c_l, kind, ctx if layer is None
+                          else dict(ctx, layer=layer))
         if kind == "hybrid":
             out, c2, a = B.hybrid_block_apply(p_l, x, cfg, window=w_l,
                                               cache=c_l, positions=positions)
@@ -144,21 +162,29 @@ def _scan_stack(params_st, x, cfg, kind: str, windows, cache_st, positions,
         return out, _strip_pos(c2) if c2 is not None else None, a
 
     fn = jax.checkpoint(apply_one) if cfg.remat else apply_one
+    paged = cache_st is not None and "pages" in ctx
+    L = windows.shape[0]
+    zero = jnp.zeros((), jnp.float32)
 
     if not cfg.scan_layers:       # cost probes: unrolled layer loop
-        L = jax.tree_util.tree_leaves(params_st)[0].shape[0]
-        aux = jnp.zeros((), jnp.float32)
+        aux = zero
         cs = []
         for i in range(L):
             p_l = jax.tree_util.tree_map(lambda a: a[i], params_st)
-            c_l = None if cache_st is None else \
-                jax.tree_util.tree_map(lambda a: a[i], cache_st)
-            x, c2, a = fn(p_l, x, c_l, windows[i])
+            if paged:
+                x, cache_st, a = fn(p_l, x, _pin_pools(cache_st, cfg),
+                                    windows[i], i)
+                cache_st = _pin_pools(cache_st, cfg)
+            else:
+                c_l = None if cache_st is None else \
+                    jax.tree_util.tree_map(lambda a: a[i], cache_st)
+                x, c2, a = fn(p_l, x, c_l, windows[i])
+                cs.append(c2)
             aux = aux + a
-            cs.append(c2)
-        new_cache = None if cache_st is None else \
-            jax.tree_util.tree_map(lambda *ls: jnp.stack(ls, 0), *cs)
-        return x, new_cache, aux
+        if cache_st is None or paged:
+            return x, cache_st, aux
+        return x, jax.tree_util.tree_map(
+            lambda *ls: jnp.stack(ls, 0), *cs), aux
 
     if cache_st is None:
         def body(carry, xs):
@@ -166,9 +192,19 @@ def _scan_stack(params_st, x, cfg, kind: str, windows, cache_st, positions,
             p_l, w_l = xs
             x, _, a = fn(p_l, x, None, w_l)
             return (x, aux + a), None
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                   (params_st, windows))
+        (x, aux), _ = jax.lax.scan(body, (x, zero), (params_st, windows))
         return x, None, aux
+
+    if paged:
+        def body(carry, xs):
+            x, aux, c = carry
+            p_l, w_l, i = xs
+            x, c, a = fn(p_l, x, _pin_pools(c, cfg), w_l, i)
+            return (x, aux + a, _pin_pools(c, cfg)), None
+        (x, aux, cache_st), _ = jax.lax.scan(
+            body, (x, zero, cache_st),
+            (params_st, windows, jnp.arange(L, dtype=jnp.int32)))
+        return x, cache_st, aux
 
     def body(carry, xs):
         x, aux = carry
@@ -176,7 +212,7 @@ def _scan_stack(params_st, x, cfg, kind: str, windows, cache_st, positions,
         x, c2, a = fn(p_l, x, c_l, w_l)
         return (x, aux + a), c2
     (x, aux), new_cache = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (params_st, windows, cache_st))
+        body, (x, zero), (params_st, windows, cache_st))
     return x, new_cache, aux
 
 
@@ -433,7 +469,10 @@ def supports_paged_cache(cfg) -> bool:
 
 def init_paged_cache(cfg, n_pages: int, page_size: int):
     """Block-paged serving cache: per attention stage a shared pool of
-    fixed-size pages, ``pool_k/pool_v (L, n_pages, page_size, n_kv, hd)``.
+    fixed-size pages, lane-dense ``pool_k/pool_v (L, n_pages, page_size,
+    n_kv·hd)``: on a TPU a page is a ``(page_size, n_kv·hd)`` tile in the
+    default layout, which is the paged kernel's block, so no dispatch
+    relayouts a pool (DESIGN.md §8).
 
     Sequences address the pool through (pages, lens) passed alongside the
     cache at apply time (see repro.nn.paged); page 0 is the scratch page.
@@ -441,28 +480,26 @@ def init_paged_cache(cfg, n_pages: int, page_size: int):
 
     With ``cfg.kv_cache_dtype`` 'int8'/'int4' the pools store quantized
     pages (int4 packs two head dims per byte) plus f32 per-token
-    per-kv-head ``scale_k/scale_v`` side pools (DESIGN.md §11)."""
+    per-kv-head ``scale_k/scale_v (L, n_pages, n_kv·page_size)`` side
+    pools (DESIGN.md §11)."""
     if not supports_paged_cache(cfg):
         raise ValueError(
             f"paged KV cache unsupported for arch {cfg.arch!r} "
             f"(family={cfg.family}, mla={cfg.use_mla}, "
             f"meta_tokens={cfg.meta_tokens}); use the dense init_cache")
-    from repro.quant.kvcache import kv_pool_layout
+    from repro.quant.kvcache import kv_pool_layout, scale_pool_shape
     pdt, phd, quant = kv_pool_layout(cfg)
     layers = {}
     for name, kind, n in _stages(cfg):
-        st = {
-            "pool_k": jnp.zeros((n, n_pages, page_size, cfg.n_kv_p, phd),
-                                pdt),
-            "pool_v": jnp.zeros((n, n_pages, page_size, cfg.n_kv_p, phd),
-                                pdt),
-        }
+        shape = (n, n_pages, page_size, cfg.n_kv_p * phd)
+        st = {"pool_k": jnp.zeros(shape, pdt),
+              "pool_v": jnp.zeros(shape, pdt)}
         if quant:
-            # per-token per-kv-head scale rows (DESIGN.md §11); page axis
-            # at position 1 like the pools so copy_page COW carries them
-            st["scale_k"] = jnp.zeros((n, n_pages, page_size, cfg.n_kv_p),
-                                      jnp.float32)
-            st["scale_v"] = jnp.zeros((n, n_pages, page_size, cfg.n_kv_p),
-                                      jnp.float32)
+            # per-token per-kv-head scales (DESIGN.md §11), one lane-dense
+            # row per page; page axis at position 1 like the pools so
+            # copy_page COW carries them
+            sshape = scale_pool_shape(n, n_pages, page_size, cfg.n_kv_p)
+            st["scale_k"] = jnp.zeros(sshape, jnp.float32)
+            st["scale_v"] = jnp.zeros(sshape, jnp.float32)
         layers[name] = st
     return {"layers": layers}

@@ -126,7 +126,10 @@ def attn_apply(p: dict, x: jnp.ndarray, cfg, *, layer_window=None,
         out = parallel_attn(q, k, v)
     elif "pool_k" in cache:
         # paged serving path (repro.serve): write-through into the shared
-        # page pool, then attend through the page table.  ``positions``
+        # page pool, then attend through the page table.  The cache holds
+        # the stage's stacked lane-dense pools (L, n_pages, ps, n_kv·D)
+        # and ``layer``, this layer's index into them (models/lm.py
+        # carries the pools through the layer scan).  ``positions``
         # is (B, S) here (per-slot ragged lens from the scheduler), so
         # decode (S == 1) and prefill chunks starting at arbitrary offsets
         # (chunked prefill, partial-prefix prefill after a prefix-cache
@@ -143,16 +146,17 @@ def attn_apply(p: dict, x: jnp.ndarray, cfg, *, layer_window=None,
         # kernels dequantize per page block in-loop, and the gather
         # reference dequantizes its page view.
         pages, lens = cache["pages"], cache["lens"]
+        layer = cache["layer"]           # index into the stacked pools
         quant = "scale_k" in cache
         if quant:
             pk, sk = scatter_kv_quant(cache["pool_k"], cache["scale_k"],
-                                      pages, positions, k)
+                                      layer, pages, positions, k)
             pv, sv = scatter_kv_quant(cache["pool_v"], cache["scale_v"],
-                                      pages, positions, v)
+                                      layer, pages, positions, v)
         else:
             sk = sv = None
-            pk = scatter_kv(cache["pool_k"], pages, positions, k)
-            pv = scatter_kv(cache["pool_v"], pages, positions, v)
+            pk = scatter_kv(cache["pool_k"], layer, pages, positions, k)
+            pv = scatter_kv(cache["pool_v"], layer, pages, positions, v)
         # ``paged_fused_max_sq`` (default 1) widens the fused gate for the
         # speculative-decoding verify step: the kernel scores Sq query
         # rows at positions lens..lens+Sq-1, which is exactly this
@@ -162,16 +166,18 @@ def attn_apply(p: dict, x: jnp.ndarray, cfg, *, layer_window=None,
                  and gqa_group(kv_map, cfg.n_heads_p, cfg.n_kv_p)
                  is not None)
         if fused:
-            out = paged_attn(q, pk, pv, pages, lens, scale=scale,
-                             window=window, cap=cfg.attn_softcap,
-                             kv_of_q=kv_map, backend=cfg.attention_backend,
+            out = paged_attn(q, pk, pv, pages, lens, layer=layer,
+                             scale=scale, window=window,
+                             cap=cfg.attn_softcap, kv_of_q=kv_map,
+                             backend=cfg.attention_backend,
                              scale_k=sk, scale_v=sv)
         else:
             if quant:
-                ck = gather_kv_dequant(pk, sk, pages)
-                cv = gather_kv_dequant(pv, sv, pages)
+                ck = gather_kv_dequant(pk, sk, layer, pages)
+                cv = gather_kv_dequant(pv, sv, layer, pages)
             else:
-                ck, cv = gather_kv(pk, pages), gather_kv(pv, pages)
+                ck = gather_kv(pk, layer, pages, cfg.n_kv_p)
+                cv = gather_kv(pv, layer, pages, cfg.n_kv_p)
             k_pos = jnp.arange(ck.shape[1])
             k_valid = k_pos[None, :] < (lens + S)[:, None]
             out = paged_attn_decode(q, ck, cv, kv_map, scale=scale,

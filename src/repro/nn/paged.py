@@ -1,10 +1,13 @@
 """Paged KV-cache primitives (vLLM-style block paging) — pure array ops.
 
-Layout: each attention stage shares one pool of fixed-size pages,
+Layout: each attention stage shares one stacked, lane-dense pool of
+fixed-size pages per K and V,
 
-    pool_k / pool_v : (n_pages, page_size, n_kv_heads, head_dim)
+    pool_k / pool_v : (L, n_pages, page_size, n_kv_heads·head_dim)
 
-indexed per sequence through a page table
+addressed by layer index (the layer scan carries the stage's pools and
+each layer reads and writes its own rows in place) and, per sequence,
+through a page table
 
     pages : (B, max_pages) int32 — pool page ids.  Page 0 is reserved as
         the *scratch* page (the allocator never hands it out), so
@@ -35,8 +38,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.paged_attention import ATTN_PRECISION, gqa_group
-from repro.quant.kvcache import dequantize_kv, kv_mode_of, quantize_kv
+from repro.kernels.paged_attention import (ATTN_PRECISION, gqa_group,
+                                           pool_pages)
+from repro.quant.kvcache import (dequantize_kv, kv_mode_of, quantize_kv,
+                                 rows_to_scales, scales_to_rows)
 from .common import softcap
 from .attention_mha import NEG_INF
 
@@ -53,45 +58,82 @@ def _page_slots(pages: jnp.ndarray, positions: jnp.ndarray, ps: int):
     return pid, positions % ps
 
 
-def scatter_kv(pool: jnp.ndarray, pages: jnp.ndarray,
+def scatter_kv(pool: jnp.ndarray, layer, pages: jnp.ndarray,
                positions: jnp.ndarray, val: jnp.ndarray) -> jnp.ndarray:
-    """Write ``val`` (B, S, H, D) at absolute ``positions`` (B, S) through
-    the page table (scratch-page routing per ``_page_slots``)."""
-    pid, off = _page_slots(pages, positions, pool.shape[1])
-    return pool.at[pid, off].set(val.astype(pool.dtype))
+    """Write ``val`` (B, S, H, D) into layer ``layer`` of the stacked
+    lane-dense pool (L, n_pages, ps, H·D) at absolute ``positions``
+    (B, S) through the page table (scratch-page routing per
+    ``_page_slots``): one scatter of B·S rows, in place when the pool is
+    donated or carried."""
+    pid, off = _page_slots(pages, positions, pool.shape[2])
+    rows = val.reshape(*val.shape[:2], -1).astype(pool.dtype)
+    return pool.at[layer, pid, off].set(rows)
 
 
-def scatter_kv_quant(pool: jnp.ndarray, scale: jnp.ndarray,
+def _scatter_scale_rows(scale: jnp.ndarray, layer, pages: jnp.ndarray,
+                        positions: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
+    """Write per-token scales ``s`` (B, S, H) at ``positions`` (B, S) —
+    contiguous per row, ``lens[:, None] + arange(S)`` as the paged branch
+    passes them — into layer ``layer`` of the head-major (L, n_pages,
+    H·ps) side pool.  Each page the chunk touches gets its whole row
+    rewritten, its other tokens' scales read back: a scatter whose window
+    is a whole row, which a TPU applies in place and a mesh splits by head
+    with no collective.  Pages past the table resolve to scratch."""
+    B, S, H = s.shape
+    ps = scale.shape[-1] // H
+    P = pages.shape[1]
+    n = (S + ps - 2) // ps + 1                     # pages a chunk can touch
+    start = positions[:, :1]                                  # (B, 1)
+    slot = start // ps + jnp.arange(n)                        # (B, n)
+    pid = jnp.take_along_axis(pages, jnp.minimum(slot, P - 1), axis=1)
+    pid = jnp.where(slot < P, pid, 0)
+    tok = (slot[..., None] * ps + jnp.arange(ps)
+           - start[..., None]).reshape(B, n * ps)             # chunk index
+    fresh = jnp.take_along_axis(s, jnp.clip(tok, 0, S - 1)[..., None], 1)
+    old = rows_to_scales(pool_pages(scale, layer, pid), ps)
+    new = jnp.where(((tok >= 0) & (tok < S))[..., None], fresh,
+                    old.reshape(B, n * ps, H))
+    return scale.at[layer, pid].set(scales_to_rows(new.reshape(B, n, ps, H)))
+
+
+def scatter_kv_quant(pool: jnp.ndarray, scale: jnp.ndarray, layer,
                      pages: jnp.ndarray, positions: jnp.ndarray,
                      val: jnp.ndarray):
     """Quantize-on-scatter (DESIGN.md §11): quantize fresh rows ``val``
     (B, S, H, D) to the pool's storage mode and write value bytes + f32
-    per-token per-head scales through the page table in one pass.
-    Returns ``(pool, scale)`` updated."""
+    per-token per-head scales (head-major page rows: lane ``h·ps + off``
+    of the (L, n_pages, H·ps) side pool) into layer ``layer`` through the
+    page table in one pass.  Returns ``(pool, scale)`` updated."""
     mode = kv_mode_of(pool)
     q, s = quantize_kv(val, mode)
-    pid, off = _page_slots(pages, positions, pool.shape[1])
-    return pool.at[pid, off].set(q), scale.at[pid, off].set(s)
+    pid, off = _page_slots(pages, positions, pool.shape[2])
+    rows = q.reshape(*q.shape[:2], -1)
+    return (pool.at[layer, pid, off].set(rows),
+            _scatter_scale_rows(scale, layer, pages, positions, s))
 
 
-def gather_kv(pool: jnp.ndarray, pages: jnp.ndarray) -> jnp.ndarray:
-    """(n_pages, ps, H, D) pool + (B, P) table → (B, P·ps, H, D) view."""
+def gather_kv(pool: jnp.ndarray, layer, pages: jnp.ndarray,
+              n_kv: int) -> jnp.ndarray:
+    """Layer ``layer`` of the (L, n_pages, ps, H·D) pool + (B, P) table →
+    (B, P·ps, H, D) view, one gather on (layer, page)."""
     B, P = pages.shape
-    ps = pool.shape[1]
-    return pool[pages].reshape(B, P * ps, *pool.shape[2:])
+    g = pool_pages(pool, layer, pages)                  # (B, P, ps, H·D)
+    return g.reshape(B, P * g.shape[2], n_kv, -1)
 
 
-def gather_kv_dequant(pool: jnp.ndarray, scale: jnp.ndarray,
+def gather_kv_dequant(pool: jnp.ndarray, scale: jnp.ndarray, layer,
                       pages: jnp.ndarray) -> jnp.ndarray:
-    """Quantized-pool gather for the reference path: (n_pages, ps, H,
-    Dp) pool + (n_pages, ps, H) scales + (B, P) table → dequantized f32
-    (B, P·ps, H, D) view.  The fused kernels dequantize per page block
-    instead and never build this view."""
+    """Quantized-pool gather for the reference path: layer ``layer`` of
+    the (L, n_pages, ps, H·Dp) pool + (L, n_pages, H·ps) scale rows +
+    (B, P) table → dequantized f32 (B, P·ps, H, D) view.  The fused
+    kernels dequantize per page block instead and never build this
+    view."""
     mode = kv_mode_of(pool)
     B, P = pages.shape
-    ps = pool.shape[1]
-    out = dequantize_kv(pool[pages], scale[pages], mode)
-    return out.reshape(B, P * ps, *out.shape[3:])
+    s = rows_to_scales(pool_pages(scale, layer, pages), pool.shape[2])
+    q = pool_pages(pool, layer, pages).reshape(*s.shape, -1)  # (B,P,ps,H,·)
+    out = dequantize_kv(q, s, mode)
+    return out.reshape(B, P * s.shape[2], *out.shape[3:])
 
 
 def paged_attn_decode(q, k, v, kv_of_q: np.ndarray, *, scale: float,

@@ -89,25 +89,46 @@ _CACHE_RULES = {
     "m":    (None, AXIS_BATCH, None),
     "c":    (None, AXIS_BATCH, AXIS_MODEL),
     "pos":  (),
-    # paged KV pools (L, n_pages, page_size, n_kv, hd): heads over model,
-    # mirroring the dense split-KV rule (falls back to replication when the
-    # kv-head count does not divide the axis)
-    "pool_k": (None, None, None, AXIS_MODEL, None),
-    "pool_v": (None, None, None, AXIS_MODEL, None),
-    # quantized-pool scale rows (L, n_pages, page_size, n_kv): kv-head
-    # axis sharded like the pools' head axis (DESIGN.md §11)
-    "scale_k": (None, None, None, AXIS_MODEL),
-    "scale_v": (None, None, None, AXIS_MODEL),
+    # paged KV pools (L, n_pages, page_size, n_kv·hd), lane-dense: the
+    # merged head axis over model, in whole kv heads only — cache_specs
+    # replicates them where n_kv does not divide the axis (the paged
+    # kernel runs shard-local only where it does)
+    "pool_k": (None, None, None, AXIS_MODEL),
+    "pool_v": (None, None, None, AXIS_MODEL),
+    # quantized-pool scale rows (L, n_pages, n_kv·page_size), head-major:
+    # sharded in whole heads like the pools (DESIGN.md §11)
+    "scale_k": (None, None, AXIS_MODEL),
+    "scale_v": (None, None, AXIS_MODEL),
 }
 
 
-def cache_specs(cache_abs, mesh):
+_HEAD_MAJOR = ("pool_k", "pool_v", "scale_k", "scale_v")
+
+
+def pool_head_axis(kv_heads, mesh):
+    """Mesh axis of the paged pools' merged head axis: the model axis
+    where ``kv_heads`` divides it (whole heads per shard), else None —
+    a split inside a head would make every layer gather its pool."""
+    if mesh is None or kv_heads is None or AXIS_MODEL not in mesh.axis_names:
+        return None
+    return AXIS_MODEL if kv_heads % mesh.shape[AXIS_MODEL] == 0 else None
+
+
+def cache_specs(cache_abs, mesh, kv_heads=None):
+    """Shardings for a decode cache.  The paged pools' last axis merges
+    ``kv_heads`` heads, so it is split over the model axis only in whole
+    heads (``pool_head_axis``); otherwise, or with ``kv_heads`` unknown,
+    the pools stay replicated."""
+    whole_heads = pool_head_axis(kv_heads, mesh) is not None
+
     def one(path, leaf):
         name = None
         for pp in reversed(path):
             if hasattr(pp, "key"):
                 name = str(pp.key)
                 break
+        if name in _HEAD_MAJOR and not whole_heads:
+            return _filt(mesh, [], leaf.shape)
         items = _CACHE_RULES.get(name, ())
         return _filt(mesh, list(items), leaf.shape)
     return jax.tree_util.tree_map_with_path(one, cache_abs)

@@ -19,11 +19,16 @@ Layout (``cfg.kv_cache_dtype``):
     pool bytes stay decodable), pool dtype uint8 at ``head_dim // 2``.
 
 Scales live in f32 *side pools* ``scale_k/scale_v (L, n_pages,
-page_size, n_kv)`` inside the same per-stage layers dict as the page
-pools — the page axis sits at position 1 in every leaf, so the COW
-``copy_page`` tree_map carries scale rows alongside page contents with
-no special casing, and the kv-head axis (last) shards over the model
-axis like the pools' head axis does.  Per-token rows (not whole-page
+n_kv·page_size)`` inside the same per-stage layers dict as the page
+pools: one lane-dense row per page, head-major (lane ``h·page_size + t``
+holds token t's scale for kv head h; ``rows_to_scales`` turns rows back
+into per-token ``(page_size, n_kv)``), so a TPU stores them unpadded in
+its default layout, pages on sublanes, and a scatter of whole page rows
+(``nn/paged.py``) updates them in place.  The page axis sits at
+position 1 in every leaf, so the COW ``copy_page`` tree_map carries
+scale rows alongside page contents with no special casing, and the
+head-major lane axis (last) shards over the model axis in whole heads
+like the pools' merged head axis does.  Per-token rows (not whole-page
 amax) because pages fill incrementally: decode appends one token at a
 time and each write must quantize independently without requantizing
 its page neighbours.
@@ -70,6 +75,45 @@ def kv_pool_layout(cfg):
         raise ValueError(f"unknown kv_cache_dtype {mode!r}; expected one "
                          f"of {KV_DTYPES}")
     return cfg.cdtype, hd, False
+
+
+def scale_pool_shape(n_layers: int, n_pages: int, page_size: int,
+                     n_kv: int) -> tuple:
+    """Shape of a stage's scale side pool: one head-major row of
+    ``n_kv·page_size`` f32 scales per page."""
+    return (n_layers, n_pages, n_kv * page_size)
+
+
+def rows_to_scales(rows: jnp.ndarray, page_size: int) -> jnp.ndarray:
+    """Head-major page rows ``(..., n_kv·page_size)`` → per-token scales
+    ``(..., page_size, n_kv)``."""
+    x = rows.reshape(*rows.shape[:-1], -1, page_size)
+    return jnp.swapaxes(x, -1, -2)
+
+
+def scales_to_rows(s: jnp.ndarray) -> jnp.ndarray:
+    """Per-token scales ``(..., page_size, n_kv)`` → head-major page rows
+    ``(..., n_kv·page_size)``; the inverse of ``rows_to_scales``."""
+    return jnp.swapaxes(s, -1, -2).reshape(*s.shape[:-2], -1)
+
+
+def stored_layout(pages: jnp.ndarray, scale=None):
+    """Per-head pages ``(..., page_size, n_kv, Dp)`` (and their per-token
+    scales ``(..., page_size, n_kv)``) → the pools' stored layout:
+    lane-dense ``(..., page_size, n_kv·Dp)`` (and head-major scale
+    rows)."""
+    flat = pages.reshape(*pages.shape[:-2], -1)
+    return flat if scale is None else (flat, scales_to_rows(scale))
+
+
+def heads_layout(pages: jnp.ndarray, scale=None, *, n_kv: int):
+    """The inverse of ``stored_layout``: lane-dense pages ``(...,
+    page_size, n_kv·Dp)`` (and head-major scale rows) → per head ``(...,
+    page_size, n_kv, Dp)`` (and per-token scales ``(..., page_size,
+    n_kv)``)."""
+    heads = pages.reshape(*pages.shape[:-1], n_kv, -1)
+    return heads if scale is None else \
+        (heads, rows_to_scales(scale, pages.shape[-2]))
 
 
 def pack_int4(q: jnp.ndarray) -> jnp.ndarray:

@@ -293,12 +293,14 @@ class Engine:
         if mesh is not None:
             # tensor-parallel serving (DESIGN.md §6): params per the
             # path-based rules (folded encoded tensors shard col/row over
-            # the model axis), page pools split over kv heads; every jitted
+            # the model axis), page pools split over kv heads where their
+            # count divides the model axis, else replicated; every jitted
             # step below runs under the mesh so model-code constraints and
             # the shard-local encoded kernel see it.
             self.params = _shard_params(params, mesh)
-            self.kv.layers = jax.device_put(
-                self.kv.layers, cache_specs(self.kv.layers, mesh))
+            self.kv.pools = jax.device_put(
+                self.kv.pools,
+                cache_specs(self.kv.pools, mesh, kv_heads=cfg.n_kv_p))
         self._prefill, self._step = _jitted_paged_steps(cfg, mesh)
         self.spec_k = int(spec_decode)
         if self.spec_k < 0:
@@ -588,8 +590,8 @@ class Engine:
             pages_dev, lens_dev = self.kv.pages_dev(), self.kv.lens_dev()
         with tr.span("serve.decode", args={"n_active": len(active)}):
             with tr.span("serve.dispatch"), self._mesh_ctx():
-                toks, self.kv.layers = self._step(
-                    self.params, self.kv.layers, tok_dev, pages_dev,
+                toks, self.kv.pools = self._step(
+                    self.params, self.kv.pools, tok_dev, pages_dev,
                     lens_dev)
             with tr.span("serve.wait"):
                 # analysis: allow(host-sync): THE step boundary — decoded
@@ -660,16 +662,16 @@ class Engine:
         args = {"k": k, "n_active": len(active)}
         with tr.span("serve.draft", args=args), \
                 tr.span("serve.dispatch"), self._mesh_ctx():
-            d_toks, self.kv.layers = self._draft(
-                self.draft_params, self.kv.layers, tok_dev,
+            d_toks, self.kv.pools = self._draft(
+                self.draft_params, self.kv.pools, tok_dev,
                 pages_dev, lens_dev)
         with tr.span("serve.verify", args=args):
             with tr.span("serve.dispatch"), self._mesh_ctx():
                 # d_toks stays on device: verify concatenates it with the
                 # round's input tokens inside the trace, so draft → verify
                 # is two back-to-back dispatches with no host sync between
-                v_toks, self.kv.layers = self._verify(
-                    self.params, self.kv.layers, tok_dev, d_toks,
+                v_toks, self.kv.pools = self._verify(
+                    self.params, self.kv.pools, tok_dev, d_toks,
                     pages_dev, lens_dev)
             with tr.span("serve.wait"):
                 # analysis: allow(host-sync): the round boundary —
@@ -787,8 +789,8 @@ class Engine:
                         self.kv.pages_dev()[slot:slot + 1],
                         jnp.asarray([start], jnp.int32))
             with tr.span("serve.dispatch"), self._mesh_ctx():
-                toks, self.kv.layers = self._prefill(
-                    self.params, self.kv.layers, *args)
+                toks, self.kv.pools = self._prefill(
+                    self.params, self.kv.pools, *args)
             req.n_cached = start + n
             self.kv.set_len(slot, req.n_cached)
             self._c_chunks.inc(1, mac=self._mac)

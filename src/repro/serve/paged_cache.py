@@ -34,17 +34,18 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import init_paged_cache, supports_paged_cache
+from repro.quant.kvcache import heads_layout, stored_layout
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _copy_page_jit(layers, src, dst):
+def _copy_page_jit(pools, src, dst):
     """In-place page duplication: the pool buffers are donated so XLA
     updates one page per pool instead of materializing a full copy of
     every pool (the eager ``a.at[...].set`` a COW event used to run
     reallocated the ENTIRE pool per layer leaf).  ``src``/``dst`` are
     traced scalars — one compile covers every page pair."""
     return jax.tree_util.tree_map(
-        lambda a: a.at[:, dst].set(a[:, src]), layers)
+        lambda a: a.at[:, dst].set(a[:, src]), pools)
 
 
 def pages_for(n_tokens: int, page_size: int) -> int:
@@ -281,10 +282,29 @@ class PrefixIndex:
         return sum(h for h, _ in self._recent) / lookup if lookup else 0.0
 
 
+def _convert_stage(st: dict, layout) -> dict:
+    """Apply a ``quant.kvcache`` layout conversion (``stored_layout`` or
+    ``heads_layout``) to a stage's k and v pools with their scale rows."""
+    out = {}
+    for s in ("k", "v"):
+        if f"scale_{s}" in st:
+            out[f"pool_{s}"], out[f"scale_{s}"] = layout(
+                st[f"pool_{s}"], st[f"scale_{s}"])
+        else:
+            out[f"pool_{s}"] = layout(st[f"pool_{s}"])
+    return out
+
+
 class PagedKVCache:
     """Device page pools + host page tables for a fixed slot count.
 
-    ``layers`` is the jit-carried pytree (donated through decode steps);
+    ``pools`` is the jit-carried pytree (donated through every step): per
+    stage the lane-dense ``pool_k/pool_v (L, n_pages, ps, n_kv·Dp)`` and,
+    for quantized modes, the ``scale_k/scale_v`` side pools.  ``layers``
+    is the same pools per head, for readers outside the steps.  The cache
+    holds one of the two forms at a time: reading ``layers`` turns the
+    pools into it, and the next read of ``pools`` turns them back (both
+    at once do not fit a chip's memory beside a deployment-size pool).
     ``ptab``/``lens`` are numpy, written by the scheduler and uploaded as
     small int arrays each step.  Unassigned table entries stay 0 →
     scratch page.
@@ -305,7 +325,8 @@ class PagedKVCache:
         self.n_pages = n_pages
         self.page_size = page_size
         self.max_seq_pages = min(max_seq_pages, n_pages - 1)
-        self.layers = init_paged_cache(cfg, n_pages, page_size)["layers"]
+        self._heads = None
+        self.pools = init_paged_cache(cfg, n_pages, page_size)["layers"]
         self.alloc = PageAllocator(n_pages)
         self.ptab = np.zeros((n_slots, self.max_seq_pages), np.int32)
         self.lens = np.zeros((n_slots,), np.int32)
@@ -313,6 +334,35 @@ class PagedKVCache:
         if sanitize:
             from repro.analysis.ledger import attach_ledger
             attach_ledger(self)          # sets self.ledger
+
+    @property
+    def pools(self) -> dict:
+        if self._pools is None:          # held per head since ``layers``
+            self._pools = {name: _convert_stage(st, stored_layout)
+                           for name, st in self._heads.items()}
+            self._heads = None
+        return self._pools
+
+    @pools.setter
+    def pools(self, pools: dict) -> None:
+        self._pools, self._heads = pools, None
+
+    @property
+    def layers(self) -> dict:
+        """The pools per head: ``pool_k/pool_v (L, n_pages, ps, n_kv, Dp)``
+        and, for quantized modes, ``scale_k/scale_v (L, n_pages, ps,
+        n_kv)``, for inspection outside the jitted steps, which carry the
+        lane-dense ``pools``.  Built on the first read after a step (a
+        copy of the pools, which the cache then drops), so a read between
+        steps costs two whole-pool relayouts: only the benchmark's check
+        after its window reads it.  It goes, with ``_heads``, once that
+        check reads ``pools`` (ROADMAP.md A13)."""
+        if self._heads is None:
+            to_heads = functools.partial(heads_layout, n_kv=self.cfg.n_kv_p)
+            self._heads = {name: _convert_stage(st, to_heads)
+                           for name, st in self._pools.items()}
+            self._pools = None
+        return self._heads
 
     @property
     def max_seq_tokens(self) -> int:
@@ -339,8 +389,8 @@ class PagedKVCache:
         Runs jitted with the pool buffers donated, so the copy is
         in-place (no full-pool reallocation; the COW test asserts
         pointer stability and scale carry)."""
-        self.layers = _copy_page_jit(self.layers, jnp.int32(src),
-                                     jnp.int32(dst))
+        self.pools = _copy_page_jit(self.pools, jnp.int32(src),
+                                    jnp.int32(dst))
 
     def pages_dev(self) -> jnp.ndarray:
         return jnp.asarray(self.ptab)
@@ -352,17 +402,14 @@ class PagedKVCache:
         """Total cache bytes: every pool leaf across stages (k+v value
         pools AND the quantized modes' scale side pools) plus the host
         page-table/lens buffers mirrored to device each step."""
-        total = self.ptab.nbytes + self.lens.nbytes
-        for st in self.layers.values():
-            for a in st.values():
-                total += a.size * a.dtype.itemsize
-        return total
+        return self.pool_bytes() + self.ptab.nbytes + self.lens.nbytes
 
     def pool_bytes(self) -> int:
         """Device pool bytes only (value + scale pools) — the HBM the
         page budget actually occupies."""
+        held = self._heads if self._pools is None else self._pools
         return sum(a.size * a.dtype.itemsize
-                   for st in self.layers.values() for a in st.values())
+                   for st in held.values() for a in st.values())
 
     def kv_bytes_per_token(self) -> float:
         """Pool bytes a single cached token costs across all layer

@@ -78,9 +78,9 @@ def test_off_by_one_index_map_is_caught():
     import functools
     import jax.numpy as jnp
 
-    def bad(b, p, pages_s, lens_s, win_s, *, Sq, ps):
+    def bad(b, p, pages_s, lens_s, win_s, layer_s, *, Sq, ps):
         p_eff = jnp.minimum(p + 1, (lens_s[b] + Sq - 1) // ps)
-        return (pages_s[b, p_eff], 0, 0, 0)
+        return (layer_s[0], pages_s[b, p_eff], 0, 0)
 
     f = check_paged_index_maps(
         kv_map=functools.partial(bad, Sq=1, ps=8), ps=8, Sq=1)
@@ -89,9 +89,25 @@ def test_off_by_one_index_map_is_caught():
 
 def test_missing_lens_clamp_is_caught():
     f = check_paged_index_maps(
-        kv_map=lambda b, p, pages, lens, win: (pages[b, p], 0, 0, 0),
+        kv_map=lambda b, p, pages, lens, win, layer: (layer[0], pages[b, p],
+                                                      0, 0),
         ps=8, Sq=1)
     assert any("past-lens" in x.message for x in f)
+
+
+def test_wrong_layer_index_map_is_caught():
+    """A map that ignores the layer scalar reads layer 0's pages."""
+    import functools
+    from repro.kernels import paged_attention as pa
+
+    def layer0(b, p, pages_s, lens_s, win_s, layer_s, *, Sq, ps):
+        _, page, _, _ = pa.paged_kv_block_map(b, p, pages_s, lens_s, win_s,
+                                               layer_s, Sq=Sq, ps=ps)
+        return (0, page, 0, 0)
+
+    f = check_paged_index_maps(
+        kv_map=functools.partial(layer0, Sq=1, ps=8), ps=8, Sq=1)
+    assert any("kv map returned" in x.message for x in f)
 
 
 def test_blocked_lowering_is_in_bounds():

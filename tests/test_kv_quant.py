@@ -22,7 +22,7 @@ from repro.kernels.paged_attention import paged_attn
 from repro.models import init_model, init_paged_cache
 from repro.nn.paged import gather_kv_dequant, paged_attn_decode
 from repro.quant.kvcache import (kv_mode_of, pack_int4, unpack_int4,
-                                 quantize_kv, dequantize_kv)
+                                 quantize_kv, dequantize_kv, stored_layout)
 from repro.serve import Engine
 
 
@@ -62,15 +62,18 @@ def test_quantize_all_zero_rows_stay_zero():
 # op parity: in-kernel dequant vs the dequantized-gather reference
 # ---------------------------------------------------------------------------
 
+N_LAYERS, LAYER = 3, 1               # stacked pools; the layer read
+
+
 def _quant_pool_case(rng, B, Hq, Hkv, D, ps, P, mode):
-    """Random dense pools quantized row-wise into value + scale pools."""
+    """Random dense stacked pools quantized row-wise into value + scale
+    pools."""
     n_pages = 1 + B * P
-    dense_k = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, D)),
-                          jnp.float32)
-    dense_v = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, D)),
-                          jnp.float32)
-    pool_k, scale_k = quantize_kv(dense_k, mode)
-    pool_v, scale_v = quantize_kv(dense_v, mode)
+    shape = (N_LAYERS, n_pages, ps, Hkv, D)
+    dense_k = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    dense_v = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    pool_k, scale_k = stored_layout(*quantize_kv(dense_k, mode))
+    pool_v, scale_v = stored_layout(*quantize_kv(dense_v, mode))
     pages = np.zeros((B, P), np.int32)
     for b in range(B):
         pages[b] = 1 + b * P + np.arange(P)
@@ -83,8 +86,8 @@ def _quant_pool_case(rng, B, Hq, Hkv, D, ps, P, mode):
 def _quant_reference(q, pool_k, pool_v, scale_k, scale_v, pages, lens,
                      kv_map, *, scale, window, cap):
     S = q.shape[1]
-    ck = gather_kv_dequant(pool_k, scale_k, pages)
-    cv = gather_kv_dequant(pool_v, scale_v, pages)
+    ck = gather_kv_dequant(pool_k, scale_k, LAYER, pages)
+    cv = gather_kv_dequant(pool_v, scale_v, LAYER, pages)
     k_pos = jnp.arange(ck.shape[1])
     k_valid = k_pos[None, :] < (lens + S)[:, None]
     q_pos = lens[:, None] + jnp.arange(S)[None, :]
@@ -113,9 +116,9 @@ def test_op_matches_dequant_gather_reference(backend, mode, ps, Hq, Hkv,
     scale = 1.0 / np.sqrt(D)
     ref = _quant_reference(q, pk, pv, sk, sv, pages, lens, kv_map,
                            scale=scale, window=window, cap=cap)
-    out = paged_attn(q, pk, pv, pages, lens, scale=scale, window=window,
-                     cap=cap, kv_of_q=kv_map, backend=backend,
-                     scale_k=sk, scale_v=sv)
+    out = paged_attn(q, pk, pv, pages, lens, layer=LAYER, scale=scale,
+                     window=window, cap=cap, kv_of_q=kv_map,
+                     backend=backend, scale_k=sk, scale_v=sv)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
 
@@ -131,10 +134,34 @@ def test_op_lens_sweep_quant(backend, mode):
         lens = jnp.asarray([ln, max(0, ln - 1)], jnp.int32)
         ref = _quant_reference(q, pk, pv, sk, sv, pages, lens, kv_map,
                                scale=0.3, window=None, cap=None)
-        out = paged_attn(q, pk, pv, pages, lens, scale=0.3, kv_of_q=kv_map,
-                         backend=backend, scale_k=sk, scale_v=sv)
+        out = paged_attn(q, pk, pv, pages, lens, layer=LAYER, scale=0.3,
+                         kv_of_q=kv_map, backend=backend, scale_k=sk,
+                         scale_v=sv)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-6, err_msg=f"lens {ln}")
+
+
+@pytest.mark.parametrize("backend", ["blocked", "pallas_interpret"])
+@pytest.mark.parametrize("mode", ["int8", "int4"])
+def test_op_reads_only_its_layers_scales(backend, mode):
+    """Every other layer's scale rows hold NaN: the given layer still
+    matches the reference, so the scales stream from its pages alone."""
+    rng = np.random.default_rng(17)
+    ps = 4
+    q, pk, pv, sk, sv, pages, kv_map = _quant_pool_case(
+        rng, 3, 4, 2, 8, ps, 5, mode)
+    other = (jnp.arange(N_LAYERS) != LAYER)[:, None, None]
+    sk = jnp.where(other, jnp.nan, sk)
+    sv = jnp.where(other, jnp.nan, sv)
+    lens = jnp.asarray([0, ps + 2, 3 * ps], jnp.int32)
+    ref = _quant_reference(q, pk, pv, sk, sv, pages, lens, kv_map,
+                           scale=0.3, window=None, cap=None)
+    assert np.isfinite(np.asarray(ref)).all()
+    out = paged_attn(q, pk, pv, pages, lens, layer=LAYER, scale=0.3,
+                     kv_of_q=kv_map, backend=backend, scale_k=sk,
+                     scale_v=sv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-6)
 
 
 @pytest.mark.parametrize("mode,tol", [("int8", 0.02), ("int4", 0.30)])
@@ -145,12 +172,13 @@ def test_quant_attention_tracks_dense(mode, tol):
     rng = np.random.default_rng(5)
     B, Hq, Hkv, D, ps, P = 3, 4, 2, 32, 4, 6
     n_pages = 1 + B * P
-    dense_k = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, D)),
-                          jnp.float32)
-    dense_v = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, D)),
-                          jnp.float32)
-    pk, sk = quantize_kv(dense_k, mode)
-    pv, sv = quantize_kv(dense_v, mode)
+    shape = (1, n_pages, ps, Hkv, D)
+    dense_k = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    dense_v = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    pk, sk = stored_layout(*quantize_kv(dense_k, mode))
+    pv, sv = stored_layout(*quantize_kv(dense_v, mode))
+    dense_k = dense_k.reshape(1, n_pages, ps, Hkv * D)
+    dense_v = dense_v.reshape(1, n_pages, ps, Hkv * D)
     pages = np.zeros((B, P), np.int32)
     for b in range(B):
         pages[b] = 1 + b * P + np.arange(P)
@@ -158,10 +186,11 @@ def test_quant_attention_tracks_dense(mode, tol):
     kv_map = np.arange(Hq, dtype=np.int32) // 2
     q = jnp.asarray(rng.normal(size=(B, 1, Hq, D)), jnp.float32)
     lens = jnp.asarray([5, 11, 23], jnp.int32)
-    dense = paged_attn(q, dense_k, dense_v, pages, lens, scale=0.2,
+    dense = paged_attn(q, dense_k, dense_v, pages, lens, layer=0, scale=0.2,
                        kv_of_q=kv_map, backend="blocked")
-    quant = paged_attn(q, pk, pv, pages, lens, scale=0.2, kv_of_q=kv_map,
-                       backend="blocked", scale_k=sk, scale_v=sv)
+    quant = paged_attn(q, pk, pv, pages, lens, layer=0, scale=0.2,
+                       kv_of_q=kv_map, backend="blocked", scale_k=sk,
+                       scale_v=sv)
     err = np.abs(np.asarray(quant) - np.asarray(dense))
     rel = err.max() / (np.abs(np.asarray(dense)).max() + 1e-9)
     assert rel < tol, f"{mode} relative error {rel:.4f} >= {tol}"

@@ -25,11 +25,16 @@ from repro.serve import Engine, generate
 # op-level parity vs the gathered-view reference
 # ---------------------------------------------------------------------------
 
+N_LAYERS, LAYER = 3, 1               # stacked pools; the layer read
+
+
 def _pool_case(rng, B, Hq, Hkv, D, ps, P):
-    """Random pools + per-row page tables + the uniform q→kv head map."""
+    """Random stacked lane-dense pools (L, n_pages, ps, Hkv·D) + per-row
+    page tables + the uniform q→kv head map."""
     n_pages = 1 + B * P
-    pool_k = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, D)), jnp.float32)
-    pool_v = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, D)), jnp.float32)
+    shape = (N_LAYERS, n_pages, ps, Hkv * D)
+    pool_k = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    pool_v = jnp.asarray(rng.normal(size=shape), jnp.float32)
     pages = np.zeros((B, P), np.int32)
     for b in range(B):
         pages[b] = 1 + b * P + np.arange(P)
@@ -40,9 +45,11 @@ def _pool_case(rng, B, Hq, Hkv, D, ps, P):
 
 
 def _reference(q, pool_k, pool_v, pages, lens, kv_map, *, scale, window,
-               cap):
+               cap, layer=LAYER):
     S = q.shape[1]
-    ck, cv = gather_kv(pool_k, pages), gather_kv(pool_v, pages)
+    n_kv = int(kv_map.max()) + 1
+    ck = gather_kv(pool_k, layer, pages, n_kv)
+    cv = gather_kv(pool_v, layer, pages, n_kv)
     k_pos = jnp.arange(ck.shape[1])
     k_valid = k_pos[None, :] < (lens + S)[:, None]
     q_pos = lens[:, None] + jnp.arange(S)[None, :]
@@ -69,8 +76,9 @@ def test_op_matches_gather_reference(backend, ps, Hq, Hkv, window, cap):
     scale = 1.0 / np.sqrt(D)
     ref = _reference(q, pool_k, pool_v, pages, lens, kv_map, scale=scale,
                      window=window, cap=cap)
-    out = paged_attn(q, pool_k, pool_v, pages, lens, scale=scale,
-                     window=window, cap=cap, kv_of_q=kv_map, backend=backend)
+    out = paged_attn(q, pool_k, pool_v, pages, lens, layer=LAYER,
+                     scale=scale, window=window, cap=cap, kv_of_q=kv_map,
+                     backend=backend)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
 
@@ -86,10 +94,30 @@ def test_op_lens_sweep_page_boundaries(backend):
         lens = jnp.asarray([ln, max(0, ln - 1)], jnp.int32)
         ref = _reference(q, pool_k, pool_v, pages, lens, kv_map,
                          scale=scale, window=None, cap=None)
-        out = paged_attn(q, pool_k, pool_v, pages, lens, scale=scale,
-                         kv_of_q=kv_map, backend=backend)
+        out = paged_attn(q, pool_k, pool_v, pages, lens, layer=LAYER,
+                         scale=scale, kv_of_q=kv_map, backend=backend)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-6, err_msg=f"lens {ln}")
+
+
+@pytest.mark.parametrize("backend", ["blocked", "pallas_interpret"])
+def test_op_reads_only_its_layer(backend):
+    """Every other layer of the stacked pools holds NaN: the given layer
+    still matches the reference, so only its pages are read."""
+    rng = np.random.default_rng(5)
+    ps = 4
+    q, pool_k, pool_v, pages, kv_map = _pool_case(rng, 3, 4, 2, 8, ps, 5)
+    other = (jnp.arange(N_LAYERS) != LAYER)[:, None, None, None]
+    pool_k = jnp.where(other, jnp.nan, pool_k)
+    pool_v = jnp.where(other, jnp.nan, pool_v)
+    lens = jnp.asarray([0, ps + 2, 3 * ps], jnp.int32)
+    ref = _reference(q, pool_k, pool_v, pages, lens, kv_map, scale=0.3,
+                     window=None, cap=None)
+    assert np.isfinite(np.asarray(ref)).all()
+    out = paged_attn(q, pool_k, pool_v, pages, lens, layer=LAYER,
+                     scale=0.3, kv_of_q=kv_map, backend=backend)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-6)
 
 
 @pytest.mark.parametrize("backend", ["blocked", "pallas_interpret"])
@@ -105,8 +133,8 @@ def test_op_kquery_matches_gather_reference(backend, S):
     scale = 1.0 / np.sqrt(D)
     ref = _reference(q, pool_k, pool_v, pages, lens, kv_map, scale=scale,
                      window=None, cap=None)
-    out = paged_attn(q, pool_k, pool_v, pages, lens, scale=scale,
-                     kv_of_q=kv_map, backend=backend)
+    out = paged_attn(q, pool_k, pool_v, pages, lens, layer=LAYER,
+                     scale=scale, kv_of_q=kv_map, backend=backend)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
 
@@ -133,8 +161,9 @@ def test_op_kquery_sweep(backend, S, ps, Hq, Hkv, window, cap):
     scale = 1.0 / np.sqrt(D)
     ref = _reference(q, pool_k, pool_v, pages, lens, kv_map, scale=scale,
                      window=window, cap=cap)
-    out = paged_attn(q, pool_k, pool_v, pages, lens, scale=scale,
-                     window=window, cap=cap, kv_of_q=kv_map, backend=backend)
+    out = paged_attn(q, pool_k, pool_v, pages, lens, layer=LAYER,
+                     scale=scale, window=window, cap=cap, kv_of_q=kv_map,
+                     backend=backend)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=3e-5, atol=3e-6)
 
@@ -148,8 +177,8 @@ def test_op_rejects_irregular_maps():
     irregular = np.array([0, 1, 1, 0], np.int32)   # not grouped
     assert gqa_group(irregular, 4, 2) is None
     with pytest.raises(ValueError, match="gather path"):
-        paged_attn(q, pool_k, pool_v, pages, lens, scale=1.0,
-                   kv_of_q=irregular)
+        paged_attn(q, pool_k, pool_v, pages, lens, layer=LAYER,
+                   scale=1.0, kv_of_q=irregular)
 
 
 # ---------------------------------------------------------------------------
@@ -192,8 +221,8 @@ def test_pallas_backend_refuses_non_tpu(qwen):
     q, pool_k, pool_v, pages, kv_map = _pool_case(rng, 2, 4, 2, 8, 4, 4)
     lens = jnp.asarray([3, 5], jnp.int32)
     with pytest.raises(RuntimeError, match="needs a TPU"):
-        paged_attn(q, pool_k, pool_v, pages, lens, scale=1.0,
-                   kv_of_q=kv_map, backend="pallas")
+        paged_attn(q, pool_k, pool_v, pages, lens, layer=LAYER,
+                   scale=1.0, kv_of_q=kv_map, backend="pallas")
     prompt = rng.integers(0, cfg.vocab_size, 5).astype(np.int32)
     with pytest.raises(RuntimeError, match="needs a TPU"):
         _serve(params, cfg, [prompt], "pallas", n_slots=1, page_size=4,
@@ -244,3 +273,18 @@ def test_mesh_paged_attn_parity():
     assert r.returncode == 0, \
         f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-4000:]}"
     assert "ALL_PAGED_ATTN_MESH_OK" in r.stdout
+
+
+def test_mesh_pools_replicate_when_kv_heads_do_not_divide():
+    """The lane-dense pools shard only in whole kv heads: 2 kv heads at
+    model=4 stay replicated (no pool-sized gather, donation kept, tokens
+    identical), while model=2 shards them (4 fake devices, subprocess)."""
+    script = os.path.join(os.path.dirname(__file__),
+                          "paged_pool_mesh4_script.py")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    r = subprocess.run([sys.executable, script], capture_output=True,
+                       text=True, timeout=1200, env=env)
+    assert r.returncode == 0, \
+        f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-4000:]}"
+    assert "ALL_PAGED_POOL_MESH4_OK" in r.stdout

@@ -103,20 +103,60 @@ def test_kv_copy_page_cow(qwen):
     cfg, _ = qwen
     kv = PagedKVCache(cfg, n_slots=1, n_pages=8, page_size=4,
                       max_seq_pages=4)
-    kv.layers = jax.tree_util.tree_map(
-        lambda a: a.at[:, 1].set(3.0), kv.layers)
+    kv.pools = jax.tree_util.tree_map(
+        lambda a: a.at[:, 1].set(3.0), kv.pools)
     kv.copy_page(0, 3)          # warm the jitted copy (first call may alloc)
     ptrs = [a.unsafe_buffer_pointer()
-            for st in kv.layers.values() for a in st.values()]
+            for st in kv.pools.values() for a in st.values()]
     kv.copy_page(1, 2)
     # COW is in-place: donated pool buffers, no full-pool reallocation
     assert [a.unsafe_buffer_pointer()
-            for st in kv.layers.values() for a in st.values()] == ptrs
-    for st in kv.layers.values():
+            for st in kv.pools.values() for a in st.values()] == ptrs
+    for st in kv.pools.values():
         for a in st.values():
             np.testing.assert_array_equal(np.asarray(a[:, 2]),
                                           np.asarray(a[:, 1]))
             assert float(np.asarray(a[:, 3]).sum()) == 0.0  # others untouched
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_kv_layers_is_the_per_head_view_of_the_pools(qwen, kv_dtype):
+    """``layers`` splits the lane-dense pools the steps carry into
+    (L, n_pages, ps, n_kv, Dp) — head h is lanes [h·Dp, (h+1)·Dp) — and
+    the head-major scale rows into per-token (L, n_pages, ps, n_kv); the
+    next read of ``pools`` gives back the stored pools unchanged."""
+    import dataclasses
+    cfg, _ = qwen
+    c = dataclasses.replace(cfg, kv_cache_dtype=kv_dtype)
+    n_pages, ps, H, D = 8, 4, c.n_kv_p, c.head_dim_r
+    kv = PagedKVCache(c, n_slots=1, n_pages=n_pages, page_size=ps,
+                      max_seq_pages=4)
+    rng = np.random.default_rng(0)
+    kv.pools = jax.tree_util.tree_map(
+        lambda a: jnp.asarray(rng.integers(-100, 100, a.shape), a.dtype),
+        kv.pools)
+    stored = jax.tree_util.tree_map(np.asarray, kv.pools)
+    nbytes = kv.pool_bytes()
+    for name, st in kv.layers.items():
+        L = stored[name]["pool_k"].shape[0]
+        for k in ("pool_k", "pool_v"):
+            view = np.asarray(st[k])
+            assert view.shape == (L, n_pages, ps, H, D)
+            for h in range(H):
+                np.testing.assert_array_equal(
+                    view[..., h, :], stored[name][k][..., h * D:(h + 1) * D])
+        for k in ("scale_k", "scale_v"):
+            assert (k in st) == (kv_dtype == "int8")
+            if k in st:
+                view = np.asarray(st[k])
+                assert view.shape == (L, n_pages, ps, H)
+                for h in range(H):
+                    np.testing.assert_array_equal(
+                        view[..., h],
+                        stored[name][k][..., h * ps:(h + 1) * ps])
+    assert kv.pool_bytes() == nbytes
+    back = jax.tree_util.tree_map(np.asarray, kv.pools)
+    jax.tree_util.tree_map(np.testing.assert_array_equal, back, stored)
 
 
 def test_kv_copy_page_cow_quant_carries_scales(qwen):
@@ -129,19 +169,19 @@ def test_kv_copy_page_cow_quant_carries_scales(qwen):
     c = dataclasses.replace(cfg, kv_cache_dtype="int8")
     kv = PagedKVCache(c, n_slots=1, n_pages=8, page_size=4,
                       max_seq_pages=4)
-    names = {k for st in kv.layers.values() for k in st}
+    names = {k for st in kv.pools.values() for k in st}
     assert {"pool_k", "pool_v", "scale_k", "scale_v"} <= names
-    kv.layers = jax.tree_util.tree_map(
+    kv.pools = jax.tree_util.tree_map(
         lambda a: a.at[:, 1].set(3 if a.dtype == jnp.int8 else 3.0),
-        kv.layers)
+        kv.pools)
     kv.copy_page(0, 3)          # warm the jitted copy (first call may alloc)
     ptrs = [a.unsafe_buffer_pointer()
-            for st in kv.layers.values() for a in st.values()]
+            for st in kv.pools.values() for a in st.values()]
     kv.copy_page(1, 2)
     # COW is in-place across ALL leaves, scale pools included
     assert [a.unsafe_buffer_pointer()
-            for st in kv.layers.values() for a in st.values()] == ptrs
-    for st in kv.layers.values():
+            for st in kv.pools.values() for a in st.values()] == ptrs
+    for st in kv.pools.values():
         for a in st.values():
             np.testing.assert_array_equal(np.asarray(a[:, 2]),
                                           np.asarray(a[:, 1]))
