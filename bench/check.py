@@ -1,7 +1,8 @@
 """The comparison that decides ``correct``.
 
-What the timed path produced is held against the plain reference
-(``reference.py``) on the same weights, after the window has closed:
+What the timed path produced is held against the plain reference (the
+``Reference`` of the cell's architecture module, ``bench/arch``) on the
+same weights, after the window has closed:
 
   token_gap   the widest gap by which a served token's reference logit
               lies below the reference's best logit at that position, over
@@ -25,8 +26,6 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-
-from .reference import Reference
 
 
 @dataclasses.dataclass
@@ -70,9 +69,10 @@ def _kv_err(prog, ref, n) -> Tuple[List[float], List[float]]:
     return np.asarray(d).tolist(), np.asarray(r).tolist()
 
 
-def compare(ref: Reference, sample: List[Served], length: int
+def compare(ref, sample: List[Served], length: int
             ) -> Dict[str, float]:
-    """The program's numbers over ``sample``; every sequence is padded to
+    """The program's numbers over ``sample`` against ``ref`` (an
+    architecture module's ``Reference``); every sequence is padded to
     ``length`` (one compile per cell)."""
     gap, diffs, norms = 0.0, [], []
     for s in sample:
@@ -113,7 +113,7 @@ def by_layer(diffs: List[List[float]], norms: List[List[float]]
     return leaf_errors(diffs, norms).max(axis=0).tolist()
 
 
-def control(ref: Reference, ctl: Reference, sample: List[Served],
+def control(ref, ctl, sample: List[Served],
             length: int) -> Dict[str, float]:
     """The control's numbers: the control put in the program's place, on
     the same prompts and served tokens.  At each served position the gap

@@ -1,7 +1,7 @@
 """What a per-layer metric reader is handed: the window, the host's step
-records, the traced sub-window's summary and the cell's sizes.  A reader
-returns a number, or None when it finds nothing to read; it never makes
-up a 0 for a share of a roofline or a peak."""
+records, the traced sub-window's summary and host spans, and the cell's
+sizes.  A reader returns a number, or None when it finds nothing to read;
+it never makes up a 0 for a share of a roofline or a peak."""
 from __future__ import annotations
 
 import dataclasses
@@ -10,8 +10,9 @@ import sys
 from typing import Dict, List, Optional, Tuple
 
 from . import roofline as R
+from .host_spans import host_spans
 from .stats import Window
-from .trace import Summary
+from .trace import Event, Summary
 from .window import StepRecord
 
 
@@ -24,8 +25,12 @@ class Run:
     traced: Tuple[int, int]              # [lo, hi) of steps under the trace
     summary: Optional[Summary]
     counters: Dict[str, float]           # engine counter deltas, window
-    dims: R.Dims
+    dims: R.Dims                         # the architecture module's dims
     peaks: dict
+    # every host event of the traced sub-window: the harness's ``bench.*``
+    # and ``engine.step`` spans, the program's ``serve.*`` spans, and any
+    # other the process wrote
+    events: List[Event] = dataclasses.field(default_factory=list)
 
     def traced_steps(self) -> List[StepRecord]:
         lo, hi = self.traced
@@ -36,6 +41,11 @@ class Run:
 
     def chunks(self) -> List[tuple]:
         return [c for s in self.traced_steps() for c in s.chunks]
+
+    def spans(self, *names: str) -> List[Event]:
+        """The traced host spans named ``names``, by start (outermost
+        first where two start together)."""
+        return host_spans(self.events, names)
 
     def op_seconds(self, pattern: str) -> Tuple[float, int]:
         """Device seconds and count of traced ops matching ``pattern``."""

@@ -19,7 +19,7 @@ def read(run):
     ms = [len(s.decode_ctx) for s in steps if s.decode_ctx]
     ms += [n for s in steps for _, n in s.chunks]
     secs, n = run.op_seconds(MATCH)
-    lin = run.dims.linears()
+    lin = run.dims.linears
     want = len(ms) * len(lin) * run.dims.layers
     if n != want or secs <= 0:
         run.note(f"encoded_matmul_roofline: {n} kernel ops traced, "

@@ -1,11 +1,11 @@
 """Layer: kernels/paged_attention.py.  The fused paged-attention kernel's
 share of its roofline over the traced decode steps: the least time the
-chip needs for the K/V bytes of each active row's valid keys (pool dtype)
-plus q and out, and 4·keys·heads·head_dim FLOPs, over the device time of
-the kernel's ops.  Match: device ops named ``%paged_attn_pallas.<n>`` (the
-Pallas call takes the name of the jitted function around it,
-``kernels/paged_attention.paged_attn_pallas``); one per layer per decode
-step.  Moves itl_p95_ms."""
+chip needs for the K/V bytes of each active row's valid keys (pool dtype;
+up to each layer's window) plus q and out, and 4·keys·heads·head_dim
+FLOPs, over the device time of the kernel's ops.  Match: device ops named
+``%paged_attn_pallas.<n>`` (the Pallas call takes the name of the jitted
+function around it, ``kernels/paged_attention.paged_attn_pallas``); one
+per layer per decode step.  Moves output_tokens_per_s."""
 from bench import roofline as R
 
 MATCH = r"^%paged_attn_pallas\."
@@ -22,7 +22,6 @@ def read(run):
                  f"{want}; not read")
         return None
     p = run.peaks
-    need = sum(run.dims.layers * R.roofline_s(
-        *R.paged_attn_call(run.dims, s.decode_ctx), p["bf16_flops"],
-        p["hbm_bytes_per_s"]) for s in steps)
+    need = sum(R.paged_attn_step_s(run.dims, s.decode_ctx, p["bf16_flops"],
+                                   p["hbm_bytes_per_s"]) for s in steps)
     return 100.0 * need / secs

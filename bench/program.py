@@ -70,15 +70,21 @@ def _gather_pages(pool_k, pool_v, row):
     return tuple(out)
 
 
-def kv_of(engine, req, n_pages: int) -> Tuple[np.ndarray, np.ndarray]:
-    """The K and V the paged pool holds in the first ``n_pages`` pages of
-    a decoding request's page table, copied to the host so the pool can be
-    freed: (layers, n_pages * page_size, kv heads, head dim) each; the
-    first ``req.n_cached`` rows are its cached tokens."""
-    st = engine.kv.layers["stack"]
+def cache_of(engine, req, n_pages: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The K and V the paged pools hold in the first ``n_pages`` pages of
+    a decoding request's page table, copied to the host so the pools can
+    be freed: every stage of the cache (``dense_prefix`` then
+    ``moe_stack``, say) in the program's stage order, joined on the layer
+    axis, (layers, n_pages * page_size, kv heads, head dim) each; the
+    first ``req.n_cached`` rows are its cached tokens.  An architecture
+    module whose cache is not paged K and V gives its own ``cache_of``."""
     row = jnp.asarray(engine.kv.ptab[req.slot][:n_pages], jnp.int32)
-    k, v = _gather_pages(st["pool_k"], st["pool_v"], row)
-    return np.asarray(k), np.asarray(v)
+    ks, vs = [], []
+    for st in engine.kv.layers.values():
+        k, v = _gather_pages(st["pool_k"], st["pool_v"], row)
+        ks.append(np.asarray(k))
+        vs.append(np.asarray(v))
+    return np.concatenate(ks), np.concatenate(vs)
 
 
 @dataclasses.dataclass

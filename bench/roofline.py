@@ -9,7 +9,7 @@ counted.
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterable, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 # Published peaks of one chip, keyed by ``jax.Device.device_kind``.
 # Source: Google Cloud documentation, "TPU v5e" (system architecture):
@@ -31,47 +31,57 @@ def peaks(device_kind: str) -> dict:
 
 @dataclasses.dataclass(frozen=True)
 class Dims:
-    """A dense decoder's sizes, read from a configuration file."""
+    """What the operation and byte counts need of a decoder, from its
+    architecture module's ``dims(c)`` (``bench/arch``)."""
     layers: int
-    d_model: int
     heads: int
     kv_heads: int
     head_dim: int
-    d_ff: int
-    vocab: int
-    kv_bytes: int = 4                 # bytes per cached K or V element
+    kv_bytes: int                      # bytes per cached K or V element
+    # (k, n) of one layer's linears that a token multiplies, in the order
+    # the program runs them
+    linears: Tuple[Tuple[int, int], ...]
+    # weights a token multiplies: every layer's linears (of its routed
+    # experts only) plus the output head; the embedding lookup multiplies
+    # nothing
+    matmul_params: int
+    # per layer, the most keys a token attends (a sliding window), or None
+    windows: Tuple[Optional[int], ...]
 
-    @classmethod
-    def from_config(cls, c: dict) -> "Dims":
-        heads = int(c["num_attention_heads"])
-        return cls(layers=int(c["num_hidden_layers"]),
-                   d_model=int(c["hidden_size"]), heads=heads,
-                   kv_heads=int(c["num_key_value_heads"]),
-                   head_dim=int(c["hidden_size"]) // heads,
-                   d_ff=int(c["intermediate_size"]),
-                   vocab=int(c["vocab_size"]),
-                   kv_bytes=4 if c["torch_dtype"] == "float32" else 2)
+    def __post_init__(self):
+        if len(self.windows) != self.layers:
+            raise ValueError(f"{len(self.windows)} windows for "
+                             f"{self.layers} layers")
 
-    def linears(self) -> Tuple[Tuple[int, int], ...]:
-        """(k, n) of one layer's linears: q, k, v, o, up, gate, down."""
-        d, hd = self.d_model, self.head_dim
-        return ((d, self.heads * hd), (d, self.kv_heads * hd),
-                (d, self.kv_heads * hd), (self.heads * hd, d),
-                (d, self.d_ff), (d, self.d_ff), (self.d_ff, d))
+    def window_layers(self) -> List[Tuple[Optional[int], int]]:
+        """(window, number of layers with it), in first-layer order."""
+        out: Dict[Optional[int], int] = {}
+        for w in self.windows:
+            out[w] = out.get(w, 0) + 1
+        return list(out.items())
 
-    def matmul_params(self) -> int:
-        """Weights a token multiplies with: every layer's linears plus the
-        output head (the embedding lookup multiplies nothing)."""
-        per_layer = sum(k * n for k, n in self.linears())
-        return self.layers * per_layer + self.d_model * self.vocab
+    def attended(self, first: int, last: int) -> int:
+        """Keys attended over every layer by tokens that would see
+        ``first``, ``first + 1``, ..., ``last`` keys without a window; a
+        layer's window caps each at its size."""
+        total = 0
+        for w, n in self.window_layers():
+            if w is None or last <= w:
+                keys = (first + last) * (last - first + 1) // 2
+            elif first >= w:
+                keys = w * (last - first + 1)
+            else:
+                keys = (first + w) * (w - first + 1) // 2 + w * (last - w)
+            total += n * keys
+        return total
 
 
 def token_flops(dims: Dims, context: int) -> float:
     """Model FLOPs of one token that attends to ``context`` keys (itself
-    included): 2 per weight, plus 4 per key, head dim and head for the
-    scores and the weighted sum."""
-    return 2.0 * dims.matmul_params() + \
-        4.0 * dims.layers * dims.heads * dims.head_dim * context
+    included) in each layer, up to the layer's window: 2 per weight, plus
+    4 per key, head dim and head for the scores and the weighted sum."""
+    return 2.0 * dims.matmul_params + \
+        4.0 * dims.heads * dims.head_dim * dims.attended(context, context)
 
 
 def decode_step_flops(dims: Dims, contexts: Iterable[int]) -> float:
@@ -82,22 +92,34 @@ def decode_step_flops(dims: Dims, contexts: Iterable[int]) -> float:
 
 def prefill_chunk_flops(dims: Dims, start: int, n: int) -> float:
     """A prefill chunk of ``n`` real tokens at offset ``start`` (padding
-    is not work): token i attends to start + i + 1 keys."""
-    keys = n * start + n * (n + 1) // 2
-    return 2.0 * dims.matmul_params() * n + \
-        4.0 * dims.layers * dims.heads * dims.head_dim * keys
+    is not work): token i attends to start + i + 1 keys, up to each
+    layer's window."""
+    return 2.0 * dims.matmul_params * n + \
+        4.0 * dims.heads * dims.head_dim * dims.attended(start + 1,
+                                                         start + n)
 
 
 def paged_attn_call(dims: Dims, contexts: Iterable[int]) -> Tuple[float,
                                                                   float]:
     """(flops, bytes) of one layer's paged attention over the active rows:
-    K and V of each row's valid keys at the pool's dtype, plus q and the
-    output in f32."""
+    K and V of each row's ``contexts[b]`` keys at the pool's dtype, plus q
+    and the output in f32."""
     ctx = list(contexts)
     hd, h = dims.head_dim, dims.heads
     flops = 4.0 * h * hd * sum(ctx)
     kv = 2.0 * dims.kv_heads * hd * dims.kv_bytes * sum(ctx)
     return flops, kv + 2.0 * len(ctx) * h * hd * 4
+
+
+def paged_attn_step_s(dims: Dims, contexts: Iterable[int], peak_flops: float,
+                      bytes_per_s: float) -> float:
+    """Least time of one decode step's paged attention: one call per
+    layer, each at its roofline over every row's keys up to the layer's
+    window."""
+    ctx = list(contexts)
+    return sum(n * roofline_s(*paged_attn_call(
+        dims, [c if w is None else min(c, w) for c in ctx]), peak_flops,
+        bytes_per_s) for w, n in dims.window_layers())
 
 
 def encoded_matmul_call(m: int, k: int, n: int) -> Tuple[float, float]:

@@ -9,14 +9,17 @@ device in one jitted call; the engine the cell's files describe; a
 warm-up that compiles every program the window uses (or loads it from
 the persistent compile cache in the checkout).  Then the window: the
 cell's traffic for ``--seconds``, driven from this one thread.  Then the
-check: a sample of what the window served against the plain reference.
+check: a sample of what the window served against the plain reference
+(the cell's architecture module, ``bench/arch``).
 
 ``--trace 0`` prints the cell's end-to-end metrics; ``--trace 1`` traces
 a few-second sub-window with the profiler and prints the per-layer
-metrics, read by ``bench/metrics/<name>.py``.  The last line of standard
-output is one JSON object; the last lines of standard error are the
-numbers compared, each beside its limit.  Off a TPU, or with fewer chips
-than the cell asks for, the run exits non-zero and prints no result.
+metrics, read by ``bench/metrics/<name>.py`` from the trace's device ops,
+its host spans (the harness's and the program's) and the engine's
+counters.  The last line of standard output is one JSON object; the last
+lines of standard error are the numbers compared, each beside its limit.
+Off a TPU, or with fewer chips than the cell asks for, the run exits
+non-zero and prints no result.
 """
 from __future__ import annotations
 
@@ -87,25 +90,26 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         log(f"needs {cell.chips} TPU chip(s); JAX found {len(devices)} "
             f"{dev.platform!r} device(s)")
         raise SystemExit(1)
-    c, mix = cell.config, cell.traffic
+    c, mix, arch = cell.config, cell.traffic, cell.arch
     jax.config.update("jax_default_matmul_precision", c["matmul_precision"])
     log(f"cell {cell.name}: {c['name']} x {mix['loop']} loop; seed {seed}; "
         f"{dev.platform} {dev.device_kind} x{len(devices)}; compile cache "
         f"{cache_dir}")
     with program.CompileCounter() as compiles:
         params, engine, driver, requests, setup_s = _set_up(
-            c, mix, seed, seconds, engine_hook, compiles)
+            arch, c, mix, seed, seconds, engine_hook, compiles)
         win, tr, log_dir = _window(engine, driver, seconds, trace,
                                    compiles)
     peak = int((dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
-    sample = _served_sample(mix, seed, requests, win, engine)
+    sample = _served_sample(getattr(arch, "cache_of", program.cache_of),
+                            mix, seed, requests, win, engine)
     steps = driver.steps
     traced = (tr.step_lo, tr.step_hi) if tr else (-1, -1)
     counters = driver.counters
     del driver, engine                     # frees the pool before the check
     gc.collect()
 
-    numbers, ctl_numbers = _check(c, mix, params, sample, control)
+    numbers, ctl_numbers = _check(arch, c, mix, params, sample, control)
     unread = () if any(s.kv is not None for s in sample) else \
         ("kv_rel_err",)
     correct, table = check.verdict(numbers, c["check"], unread)
@@ -126,18 +130,20 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         result["device"] = device
     else:
         try:
-            summary = summarize(load_xplane(find_xplane(log_dir), SPANS),
-                                SPANS)
+            events = load_xplane(find_xplane(log_dir))
+            summary = summarize(events, SPANS)
+            run = Run(config=c, traffic=mix, window=win, steps=steps,
+                      traced=traced, summary=summary, counters=counters,
+                      dims=arch.dims(c),
+                      peaks=(roofline.peaks(dev.device_kind) if require_tpu
+                             else None),
+                      events=[e for e in events
+                              if not e.plane.startswith("/device:")])
+            readers = spec.readers(cell.per_layer)
+            got = {m["name"]: (readers[m["name"]](run), m["unit"])
+                   for m in cell.per_layer}
         finally:
             shutil.rmtree(log_dir, ignore_errors=True)
-        run = Run(config=c, traffic=mix, window=win, steps=steps,
-                  traced=traced, summary=summary, counters=counters,
-                  dims=roofline.Dims.from_config(c),
-                  peaks=(roofline.peaks(dev.device_kind) if require_tpu
-                         else None))
-        readers = spec.readers(cell.per_layer)
-        got = {m["name"]: (readers[m["name"]](run), m["unit"])
-               for m in cell.per_layer}
         result["metrics"] = {n: {"value": float(v), "unit": u}
                              for n, (v, u) in got.items() if v is not None}
         device.update(busy_s=summary.busy_s, window_s=summary.window_s)
@@ -161,12 +167,12 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     return result
 
 
-def _set_up(c, mix, seed, seconds, engine_hook, compiles):
+def _set_up(arch, c, mix, seed, seconds, engine_hook, compiles):
     """Weights, the engine, its warm-up and the requests: everything
     ``setup_s`` counts."""
     marks = [("start", T_PROCESS), ("jax", time.perf_counter())]
     params = jax.block_until_ready(
-        weights.make_params(c, weights.weight_seed(c, seed)))
+        weights.make_params(arch, c, weights.weight_seed(c, seed)))
     marks.append(("weights", time.perf_counter()))
     served, cfg, info = program.serving_params(c, params)
     marks.append(("encoded", time.perf_counter()))
@@ -218,10 +224,10 @@ def _window(engine, driver, seconds, trace, compiles):
     return win, tr, log_dir
 
 
-def _served_sample(mix, seed, requests, win, engine):
+def _served_sample(cache_of, mix, seed, requests, win, engine):
     """What the window served, drawn from the seed with the longest
     included: finished requests, and requests in flight with the K and V
-    the pool holds for them."""
+    the cache holds for them (``cache_of``)."""
     rng = np.random.default_rng([seed & 0xFFFFFFFF, seed >> 32, 99])
     prompt = {r.index: r.prompt for r in requests}
     reqs = engine.requests
@@ -239,26 +245,25 @@ def _served_sample(mix, seed, requests, win, engine):
         ps = int(mix["engine"]["page_size"])
         n_pages = -(-max(reqs[r.rid].n_cached for r in fly) // ps)
         sample += [check.Served(prompt[r.index], list(reqs[r.rid].out),
-                                kv=program.kv_of(engine, reqs[r.rid],
-                                                 n_pages))
+                                kv=cache_of(engine, reqs[r.rid], n_pages))
                    for r in fly]
     return sample
 
 
-def _check(c, mix, params, sample, control):
-    """The program's numbers over the sample against the reference, and
-    (``control``) the control's."""
+def _check(arch, c, mix, params, sample, control):
+    """The program's numbers over the sample against the reference of the
+    cell's architecture, and (``control``) the control's."""
     if not sample:
         return {}, None
     amax = None
     if c.get("encoded"):
         e = c["encoded"]
-        amax = reference.calibrate(
+        amax = arch.calibrate(
             c, params, seq_len=int(e["calib_seq"]), seed=int(e["calib_seed"]),
             n_batches=int(e["calib_batches"]),
             batch_size=int(e["calib_batch_size"]))
     bits = int((c.get("encoded") or {}).get("bits", 0))
-    ref = reference.Reference(c, params, bits=bits, amax=amax)
+    ref = arch.Reference(c, params, bits=bits, amax=amax)
     length = reference.padded_length(
         int(mix["prompt_len"]["max"]) + int(mix["output_len"]["max"]))
     t0 = time.perf_counter()
@@ -272,8 +277,8 @@ def _check(c, mix, params, sample, control):
             f"{x:.3g}" for x in numbers["kv_rel_err_by_layer"]))
     if not control:
         return numbers, None
-    ctl = reference.Reference(c, params, mode=c["control"]["mode"],
-                              bits=int(c["control"]["bits"]), amax=amax)
+    ctl = arch.Reference(c, params, mode=c["control"]["mode"],
+                         bits=int(c["control"]["bits"]), amax=amax)
     return numbers, check.control(ref, ctl, sample, length)
 
 

@@ -1,7 +1,7 @@
 """Reduce a profiler trace (``.xplane.pb``) to the numbers the per-layer
 metrics read: device busy time, its idle gaps and what the host was doing
 in them, the time of each device op, and the executions of each jitted
-program.
+program; the host's spans are read as they are (``context.Run.spans``).
 
 A device op is an event on a device plane's ``XLA Ops`` line; a program
 execution is one on its ``XLA Modules`` line.  Busy time is the union of
@@ -41,17 +41,19 @@ def find_xplane(log_dir: str) -> str:
     return paths[-1]
 
 
-def load_xplane(path: str, host_spans: Sequence[str]) -> List[Event]:
+def load_xplane(path: str,
+                host_spans: Optional[Sequence[str]] = None) -> List[Event]:
     """Device planes' events in full, and the host events named in
-    ``host_spans``."""
+    ``host_spans`` (every host event when None)."""
     from jax.profiler import ProfileData
     out = []
-    wanted = set(host_spans)
+    wanted = None if host_spans is None else set(host_spans)
     for plane in ProfileData.from_file(path).planes:
         device = plane.name.startswith("/device:")
         for line in plane.lines:
             for ev in line.events:
-                if not device and ev.name not in wanted:
+                if not device and wanted is not None and \
+                        ev.name not in wanted:
                     continue
                 text = ev.name
                 if device:

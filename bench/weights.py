@@ -1,7 +1,7 @@
 """Random weights from the seed, made on the device in one jitted call, in
 the type they are served in and in the parameter layout the serving
-engine reads.  The reference reads the same arrays; neither side makes
-them."""
+engine reads (the architecture module's ``shapes``).  The reference
+reads the same arrays; neither side makes them."""
 from __future__ import annotations
 
 import functools
@@ -25,25 +25,6 @@ def weight_seed(c: dict, seed: int) -> int:
     prompts and arrivals still follow the whole seed."""
     n = c.get("weight_seeds")
     return int(seed) % int(n) if n else int(seed)
-
-
-def shapes(c: dict) -> dict:
-    """Leaf shapes of a dense Qwen2-style decoder, layers stacked first."""
-    L, d = int(c["num_hidden_layers"]), int(c["hidden_size"])
-    h, hkv = int(c["num_attention_heads"]), int(c["num_key_value_heads"])
-    hd, ff, v = d // h, int(c["intermediate_size"]), int(c["vocab_size"])
-    return {
-        "embed": {"table": (v, d)},
-        "final_norm_g": (d,),
-        "stack": {
-            "attn": {"wq": (L, d, h * hd), "wq_b": (L, h * hd),
-                     "wk": (L, d, hkv * hd), "wk_b": (L, hkv * hd),
-                     "wv": (L, d, hkv * hd), "wv_b": (L, hkv * hd),
-                     "wo": (L, h * hd, d)},
-            "mlp": {"wi": (L, d, ff), "wg": (L, d, ff), "wo": (L, ff, d)},
-            "ln1_g": (L, d), "ln2_g": (L, d),
-        },
-    }
 
 
 def _scale(path: str, shape: tuple) -> tuple:
@@ -78,10 +59,11 @@ def _flat(tree, prefix=""):
             yield f"{prefix}{k}", tuple(v)
 
 
-def make_params(c: dict, seed: int) -> dict:
+def make_params(arch, c: dict, seed: int) -> dict:
     """The configuration's weights for ``seed`` as a nested dict of device
-    arrays in ``c['torch_dtype']``."""
-    flat = tuple(_flat(shapes(c)))
+    arrays in ``c['torch_dtype']``, in the leaf shapes that its
+    architecture module ``arch`` gives (``arch.shapes(c)``)."""
+    flat = tuple(_flat(arch.shapes(c)))
     leaves = _make(seed_key(seed), flat, jnp.dtype(c["torch_dtype"]))
     out: dict = {}
     for (path, _), leaf in zip(flat, leaves):

@@ -55,14 +55,14 @@ def tiny_traffic(loop="closed") -> dict:
 def tiny_cell():
     """A Cell over the tiny configuration, reporting every metric the
     real cells report."""
-    from bench.spec import Cell, load_json
+    from bench.spec import Cell, arch_module, load_json
     bm = load_json(os.path.join(ROOT, "BENCHMARK.json"))
 
     def make(encoded=False, loop="closed", limits=None, extra_layer=()):
         c = tiny_config(encoded=encoded)
         if limits is not None:
             c["check"] = dict(limits)
-        return Cell(name="tiny", chips=1, config=c,
+        return Cell(name="tiny", chips=1, config=c, arch=arch_module(c),
                     traffic=tiny_traffic(loop), end_to_end=bm["end_to_end"],
                     per_layer=bm["per_layer"] + list(extra_layer))
     return make

@@ -110,8 +110,10 @@ def _run(events):
              StepRecord(0.0, 0.0, [34], [])]
     return Run(config=c, traffic={}, window=None, steps=steps,
                traced=(0, 2), summary=T.summarize(events, SPANS),
-               counters={}, dims=R.Dims.from_config(c),
-               peaks=R.peaks("TPU v5 lite"))
+               counters={}, dims=spec.arch_module(c).dims(c),
+               peaks=R.peaks("TPU v5 lite"),
+               events=[e for e in events
+                       if not e.plane.startswith("/device:")])
 
 
 def test_recorded_spans_nest(events):
@@ -168,3 +170,17 @@ def test_recorded_step_split(events):
         sum(step_less_wait) / 2 * 1e-6)
     assert 0 < got["scheduler_host_ms"] < got["engine_host_ms"]
     assert 0 < got["dispatch_host_ms"] < got["engine_host_ms"]
+
+
+def test_host_metric_readers_read_the_step_split(events):
+    """The three host metrics read ``step_split`` through the run's span
+    access; with the program's spans gone from the trace they read
+    nothing, not 0."""
+    want = H.step_split(events, 2, pytest.fail)
+    run = _run(events)
+    assert [e.name for e in run.spans(H.STEP)] == [H.STEP, H.STEP]
+    for name in ("engine_host_ms", "dispatch_host_ms", "scheduler_host_ms"):
+        assert spec.metric_reader(name)(run) == want[name]
+    bare = _run([e for e in events if e.name not in H.SPANS])
+    for name in ("engine_host_ms", "dispatch_host_ms", "scheduler_host_ms"):
+        assert spec.metric_reader(name)(bare) is None

@@ -1,23 +1,25 @@
-"""The plain reference against the program's own forward at a tiny size,
-the weights' layout against the program's parameter tree, and the
-calibration stream against the program's."""
+"""The plain reference (``bench/arch/Qwen2ForCausalLM.py``) against the
+program's own forward at a tiny size, the weights' layout against the
+program's parameter tree, and the calibration stream against the
+program's."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference as Rf, weights
+from bench import reference as Rf, spec, weights
 from conftest import tiny_config
 
 SEED = 2 ** 35 + 3
+QWEN2 = spec.arch_module(tiny_config())
 
 
 @pytest.fixture(scope="module")
 def setup():
     from bench.program import model_config
     c = tiny_config()
-    return c, model_config(c), weights.make_params(c, SEED)
+    return c, model_config(c), weights.make_params(QWEN2, c, SEED)
 
 
 def test_weights_layout_is_the_programs(setup):
@@ -33,8 +35,8 @@ def test_weights_layout_is_the_programs(setup):
 
 def test_weights_deterministic_per_seed(setup):
     c, _, params = setup
-    again = weights.make_params(c, SEED)
-    other = weights.make_params(c, SEED + 1)
+    again = weights.make_params(QWEN2, c, SEED)
+    other = weights.make_params(QWEN2, c, SEED + 1)
     leaf = lambda p: np.asarray(p["stack"]["attn"]["wq"])  # noqa: E731
     assert np.array_equal(leaf(params), leaf(again))
     assert not np.array_equal(leaf(params), leaf(other))
@@ -46,7 +48,7 @@ def test_reference_matches_the_program_forward(setup):
     toks = np.random.default_rng(0).integers(0, 512, 40).astype(np.int32)
     logits = np.asarray(apply_model(params, cfg, jnp.asarray(toks[None]))[0]
                         )[0]
-    ref = Rf.Reference(c, params)
+    ref = QWEN2.Reference(c, params)
     mx, tg, am, ks, vs = ref.run(toks, 256, np.argmax(logits, -1))
     assert np.allclose(mx, logits.max(-1), atol=1e-5)
     assert np.array_equal(am, logits.argmax(-1))
@@ -66,7 +68,7 @@ def test_bf16_3x_is_between_bf16_and_f32():
 def test_integer_linear_is_exact():
     x = jnp.asarray([[0.5, -1.0, 0.25]])
     w = jnp.asarray([[1.0], [2.0], [-4.0]])
-    out = Rf.qlinear(x, w, jnp.float32(1.0), 8, "highest")
+    out = QWEN2.qlinear(x, w, jnp.float32(1.0), 8, "highest")
     # codes: x -> [64, -127, 32] (scale 1/127), w -> [32, 64, -127]
     sa, sw = 1.0 / 127, 4.0 / 127
     want = (64 * 32 - 127 * 64 - 32 * 127) * sa * sw
@@ -87,7 +89,7 @@ def test_calibration_matches_the_programs_scales():
     from bench.program import serving_params
     import bench.program as P
     c = tiny_config(encoded=True)
-    params = weights.make_params(c, SEED)
+    params = weights.make_params(QWEN2, c, SEED)
     import tempfile
     with tempfile.TemporaryDirectory() as d:
         old, P.BUNDLE_DIR = P.BUNDLE_DIR, d
@@ -96,9 +98,9 @@ def test_calibration_matches_the_programs_scales():
         finally:
             P.BUNDLE_DIR = old
     e = c["encoded"]
-    amax = Rf.calibrate(c, params, seq_len=e["calib_seq"],
+    amax = QWEN2.calibrate(c, params, seq_len=e["calib_seq"],
                         seed=e["calib_seed"], n_batches=e["calib_batches"],
                         batch_size=e["calib_batch_size"])
-    for block, name in Rf.LINEARS:
+    for block, name in QWEN2.LINEARS:
         prog = np.asarray(served["stack"][block][name + "_as"]) * 127
         assert np.allclose(amax[(block, name)], prog, rtol=1e-5)

@@ -99,7 +99,7 @@ def test_closed_loop_ramp_drains_the_first_prefill_wave(tiny_cell):
     cell = tiny_cell()
     c, mix = cell.config, cell.traffic
     served, cfg, _ = program.serving_params(
-        c, weights.make_params(c, weights.weight_seed(c, SEED)))
+        c, weights.make_params(cell.arch, c, weights.weight_seed(c, SEED)))
     engine = program.make_engine(served, cfg, mix["engine"])
     reqs = traffic.make_requests(mix, SEED, SECONDS, int(c["vocab_size"]))
     driver = Driver(engine, reqs, mix["loop"])
@@ -131,6 +131,22 @@ def test_open_loop_runs_and_reports_queueing(tiny_cell):
     assert "paged_attn_roofline" not in m and "decode_mfu" not in m
     assert {"busy_s", "window_s"} <= set(res["device"])
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+def test_traced_run_reads_the_programs_host_spans(tiny_cell):
+    """A traced run hands the readers the program's ``serve.*`` spans: the
+    host metrics read a number (on the CPU too: they come from host
+    spans), and the trace's directory is gone once they have."""
+    import tempfile
+    before = set(os.listdir(tempfile.gettempdir()))
+    res = run.run_cell(tiny_cell(limits=LIMITS), SEED, SECONDS, True,
+                       require_tpu=False, compile_cache=False)
+    assert res["correct"], res["checks"]
+    m = {k: v["value"] for k, v in res["metrics"].items()}
+    assert 0 < m["scheduler_host_ms"] < m["engine_host_ms"]
+    assert 0 < m["dispatch_host_ms"] < m["engine_host_ms"]
+    left = set(os.listdir(tempfile.gettempdir())) - before
+    assert not [d for d in left if d.startswith("bench-trace-")]
 
 
 def _bench_cmd(cwd):

@@ -104,7 +104,7 @@ def test_paged_attention_roofline_reader(events):
     with 40 keys each."""
     c = spec.load_json(os.path.join(spec.BENCH, "configs",
                                     "qwen1.5-0.5b.json"))
-    dims = R.Dims.from_config(c)
+    dims = spec.arch_module(c).dims(c)
     s = T.summarize(events, SPANS)
     step = StepRecord(0.0, 1.0, decode_ctx=[40] * 48, chunks=[(0, 32)])
     run = Run(config=c, traffic={}, window=Window(0, 1, [], 0),
@@ -149,3 +149,23 @@ def test_load_xplane_reads_host_spans(tmp_path):
     assert names == {SPAN_STEP, SPAN_READ}
     s = T.summarize(ev, SPANS)
     assert s.window_s > 0 and s.busy_s == 0.0     # no device on the CPU
+
+
+def test_load_xplane_reads_every_host_span(tmp_path):
+    """With no names given, the reader keeps every host event: the
+    program's spans beside the harness's."""
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation(SPAN_STEP):
+        with jax.profiler.TraceAnnotation("serve.step", step=3):
+            with jax.profiler.TraceAnnotation("serve.dispatch"):
+                pass
+    jax.profiler.stop_trace()
+    ev = T.load_xplane(T.find_xplane(str(tmp_path)))
+    names = {e.name for e in ev if not e.plane.startswith("/device:")}
+    assert {SPAN_STEP, "serve.step", "serve.dispatch"} <= names
+    run = Run(config={}, traffic={}, window=None, steps=[], traced=(0, 0),
+              summary=T.summarize(ev, SPANS), counters={}, dims=None,
+              peaks=None, events=ev)
+    got = run.spans("serve.dispatch", "serve.step")
+    assert [e.name for e in got] == ["serve.step", "serve.dispatch"]
