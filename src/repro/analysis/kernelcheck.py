@@ -8,9 +8,11 @@ index maps stay inside their operand pools:
     ``paged_q_block_map``, exactly what ``paged_attn_pallas`` partials
     into its BlockSpecs) over the full ``(B, max_seq_pages)`` grid ×
     boundary ``lens`` values (0, 1, ps−1, ps, ps+1, 2ps−1, max_seq−Sq)
-    and asserts every returned block reads the layer it was given and
-    the page of the clamp contract ``pages[b, min(p, (lens[b]+Sq−1)//ps)]``
-    — in-bounds AND never a past-lens block;
+    × windows (none, ps, 2ps+1) and asserts every returned block reads
+    the layer it was given and the page of the clamp contract
+    ``pages[b, clip(p, first, (lens[b]+Sq−1)//ps)]``, ``first`` the
+    window's first block ``max(0, (lens[b]−window+1)//ps)`` — in-bounds
+    AND never a past-lens or before-window block;
   * **paged attention, blocked (XLA) lowering** — executes
     ``_paged_attn_blocked`` under ``jax.disable_jit()`` with
     ``jax.lax.dynamic_slice_in_dim`` / ``pool_pages`` replaced by guards
@@ -71,8 +73,11 @@ def check_paged_index_maps(kv_map: Optional[Callable] = None,
                            scale_map: Optional[Callable] = None,
                            q_map: Optional[Callable] = None, *,
                            ps: int, Sq: int, B: int = 3, P: int = 4,
-                           label: str = "") -> List[Finding]:
-    """Evaluate the paged-attention index maps over grid × boundary lens.
+                           label: str = "",
+                           windowed: bool = True) -> List[Finding]:
+    """Evaluate the paged-attention index maps over grid × boundary lens
+    × windows (``windowed``: the walk a windowed model compiles; else
+    only ``_NO_WINDOW``, the walk of a model with no window layer).
 
     Defaults to the real kernel maps; pass mutated maps to prove the
     checker catches them (self-test).  Returns findings (empty = sound).
@@ -82,12 +87,13 @@ def check_paged_index_maps(kv_map: Optional[Callable] = None,
     from repro.kernels import paged_attention as pa
 
     if kv_map is None:
-        kv_map = functools.partial(pa.paged_kv_block_map, Sq=Sq, ps=ps)
+        kv_map = functools.partial(pa.paged_kv_block_map, Sq=Sq, ps=ps,
+                                   windowed=windowed)
     n_pages = B * P + 1
     rows = min(pa.SCALE_ROWS, n_pages)    # page rows per scale block
     if scale_map is None:
         scale_map = functools.partial(pa.paged_scale_block_map, Sq=Sq, ps=ps,
-                                      rows=rows)
+                                      rows=rows, windowed=windowed)
     if q_map is None:
         q_map = pa.paged_q_block_map
 
@@ -97,23 +103,25 @@ def check_paged_index_maps(kv_map: Optional[Callable] = None,
     # visible as a wrong id, not a coincidental match
     pages_np = np.arange(1, n_pages).reshape(B, P).astype(np.int32)
     pages = jnp.asarray(pages_np)
-    win = jnp.asarray([pa._NO_WINDOW], jnp.int32)
     out: List[Finding] = []
     lens_vals = _boundary_lens(ps, P, Sq)
     # uniform sweeps plus one mixed row assignment
     configs = [[v] * B for v in lens_vals]
     configs.append([lens_vals[i % len(lens_vals)] for i in range(B)])
+    windows = (int(pa._NO_WINDOW),) + ((ps, 2 * ps + 1) if windowed else ())
 
     kv_loc = _loc(kv_map)
     sc_loc = _loc(scale_map)
     q_loc = _loc(q_map)
-    for lens_list in configs:
+    for lens_list, window in itertools.product(configs, windows):
         lens = jnp.asarray(lens_list, jnp.int32)
+        win = jnp.asarray([window], jnp.int32)
         for b, p in itertools.product(range(B), range(P)):
             last = (lens_list[b] + Sq - 1) // ps
-            want = int(pages_np[b, min(p, last)])
+            first = max(0, (lens_list[b] - window + 1) // ps)
+            want = int(pages_np[b, min(max(p, first), last)])
             ctx = (f"{label} ps={ps} Sq={Sq} lens[b]={lens_list[b]} "
-                   f"(b={b}, p={p})")
+                   f"window={window} (b={b}, p={p})")
             r = kv_map(b, p, pages, lens, win, layer)
             if len(r) != 4 or int(r[0]) != layer_id or \
                     any(int(x) != 0 for x in r[2:]):
@@ -128,12 +136,14 @@ def check_paged_index_maps(kv_map: Optional[Callable] = None,
                     f"kv map reads page {pid} outside the "
                     f"[0, {n_pages}) pool [{ctx}]"))
             elif pid != want:
-                kind = ("past-lens block (clamp violated)"
-                        if p > last else "wrong page")
+                kind = ("past-lens block (clamp violated)" if p > last
+                        else "before-window block" if p < first
+                        else "wrong page")
                 out.append(Finding(
                     RULE, kv_loc[0], kv_loc[1],
                     f"kv map reads page {pid}, contract says "
-                    f"pages[b, min(p, {last})] = {want} — {kind} [{ctx}]"))
+                    f"pages[b, clip(p, {first}, {last})] = {want} — {kind} "
+                    f"[{ctx}]"))
             rs = tuple(int(x) for x in scale_map(b, p, pages, lens, win,
                                                  layer))
             if rs != (layer_id, want // rows, 0):
@@ -356,8 +366,9 @@ def run_kernelcheck() -> Tuple[List[Finding], Dict]:
     # the index maps depend only on (ps, Sq) — evaluate once per pair,
     # then pin every registry geometry to a layout cross-check
     for ps, sq in itertools.product(PAGE_SIZES, SQ_VALUES):
-        findings.extend(check_paged_index_maps(ps=ps, Sq=sq,
-                                               label="pallas"))
+        for windowed in (False, True):
+            findings.extend(check_paged_index_maps(
+                ps=ps, Sq=sq, label="pallas", windowed=windowed))
     for arch, cfg, dt in geoms:
         for ps in PAGE_SIZES:
             findings.extend(_check_pool_layout(arch, cfg, dt, ps))

@@ -51,6 +51,14 @@ class ModelConfig:
     mlp_bias: bool = False
     rope: bool = True
     rope_theta: float = 10000.0
+    # yarn-scaled rotary positions on the full-attention layers, as
+    # transformers' ``_compute_yarn_parameters`` defines them; layers with
+    # a sliding window keep plain ``rope_theta``.  0 = no scaling
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_max: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_attention_factor: Optional[float] = None  # None: 0.1·ln f + 1
     tie_embeddings: bool = False
     embed_scale: bool = False
     post_norm: bool = False           # gemma2 sandwich norms
@@ -69,7 +77,6 @@ class ModelConfig:
     first_k_dense: int = 0
     router_type: str = "softmax"
     norm_topk: bool = True
-    capacity_factor: float = 1.25
     aux_loss_weight: float = 0.01
     # mla
     use_mla: bool = False
@@ -133,6 +140,14 @@ class ModelConfig:
     fsdp: bool = False
     # applicability notes (DESIGN.md §4)
     sub_quadratic: bool = False       # runs long_500k?
+
+    def __post_init__(self):
+        # a configuration read from JSON gives lists; tuples keep the
+        # frozen config hashable, so jitted steps memoize on it
+        for f in dataclasses.fields(self):
+            v = getattr(self, f.name)
+            if isinstance(v, list):
+                object.__setattr__(self, f.name, tuple(v))
 
     # ---- derived ----
     @property

@@ -23,6 +23,10 @@ is cheap.  This kernel instead walks each sequence's page chain directly:
     index map to the last needed page (no new DMA is issued for a
     repeated block) and skip compute via ``pl.when`` — a slot at 40
     cached tokens touches 3 pages of a 1024-token-wide table, not 64;
+  * window-aware start: under a sliding window the walk starts at the
+    page of the oldest key the row's earliest query may see
+    (``first_block``); earlier blocks clamp to it the same way, so a
+    window layer reads its window's pages, not the whole context;
   * grouped GQA layout and f32 accumulation mirror the dense ``mha`` op
     order (q scaled in storage dtype, logits/softcap/mask/softmax in f32)
     so greedy decode stays token-identical to the gather path;
@@ -118,23 +122,46 @@ def _softcap(s, cap):
 # are bound by functools.partial at call-site.
 # ---------------------------------------------------------------------------
 
-def paged_kv_block_map(b, p, pages_s, lens_s, win_s, layer_s, *, Sq, ps):
+def first_block(lens_b, win, ps: int):
+    """The page block of the oldest key that query ``lens_b`` (a row's
+    earliest query) may see under a window of ``win`` keys
+    (``q_pos - k_pos < win``): blocks before it hold no key any query of
+    the row attends.  0 with ``_NO_WINDOW``."""
+    return jnp.maximum(0, (lens_b - win + 1) // ps)
+
+
+def _walk_block(p, b, lens_s, win_s, *, Sq, ps, windowed):
+    """The page block grid cell (b, p) reads: p itself inside the walk,
+    else the nearest block of it — the last holding a query's position
+    or, ``windowed`` only, the first inside the window."""
+    last = (lens_s[b] + Sq - 1) // ps
+    if not windowed:
+        return jnp.minimum(p, last)
+    return jnp.clip(p, first_block(lens_s[b], win_s[0], ps), last)
+
+
+def paged_kv_block_map(b, p, pages_s, lens_s, win_s, layer_s, *, Sq, ps,
+                       windowed=False):
     """Stacked K/V pool block index for grid cell (b, p): layer
-    ``layer_s[0]``, the page id holding page block p of row b.  Past-lens
-    blocks clamp to the last needed page — positions <= lens[b] + Sq - 1
-    — so the index map repeats and no new DMA is issued for blocks the
-    kernel body skips via ``pl.when``."""
-    p_eff = jnp.minimum(p, (lens_s[b] + Sq - 1) // ps)
+    ``layer_s[0]``, the page id holding page block p of row b.  Blocks
+    past the last query's position — and, ``windowed``, before the
+    window's first block — clamp to the nearest needed page, so the index
+    map repeats and no new DMA is issued for blocks the kernel body skips
+    via ``pl.when``.  ``windowed`` is static: a model with no sliding
+    window compiles the walk without the window's arithmetic."""
+    p_eff = _walk_block(p, b, lens_s, win_s, Sq=Sq, ps=ps,
+                        windowed=windowed)
     return (layer_s[0], pages_s[b, p_eff], 0, 0)
 
 
 def paged_scale_block_map(b, p, pages_s, lens_s, win_s, layer_s, *, Sq, ps,
-                          rows):
+                          rows, windowed=False):
     """Same page clamp for the (L, n_pages, Hkv·ps) f32 scale side pools of
     quantized KV modes (DESIGN.md §11): the block of ``rows`` page rows
     (``SCALE_ROWS``) holding the page's scales, which stream with its
     value page."""
-    p_eff = jnp.minimum(p, (lens_s[b] + Sq - 1) // ps)
+    p_eff = _walk_block(p, b, lens_s, win_s, Sq=Sq, ps=ps,
+                        windowed=windowed)
     return (layer_s[0], pages_s[b, p_eff] // rows, 0)
 
 
@@ -148,7 +175,8 @@ def paged_q_block_map(b, p, *_):
 # ---------------------------------------------------------------------------
 
 def _decode_kernel(pages_s, lens_s, win_s, layer_s, q_ref, k_ref, v_ref,
-                   *rest, ps, n_pb, cap, G, Sq, Dp, rows, mode="bf16"):
+                   *rest, ps, n_pb, cap, G, Sq, Dp, rows, mode="bf16",
+                   windowed=False):
     if mode == "bf16":
         sk_ref = sv_ref = None
         o_ref, m_ref, l_ref, acc_ref = rest
@@ -165,8 +193,11 @@ def _decode_kernel(pages_s, lens_s, win_s, layer_s, q_ref, k_ref, v_ref,
 
     ln = lens_s[b]                   # tokens already cached for this row
     nb = (ln + Sq - 1) // ps + 1     # page blocks holding positions <= ln+Sq-1
+    walk = p < nb
+    if windowed:                     # from the window's first block
+        walk = walk & (p >= first_block(ln, win_s[0], ps))
 
-    @pl.when(p < nb)
+    @pl.when(walk)
     def _block():
         f32 = jnp.float32
         hkv, R = q_ref.shape[1], q_ref.shape[2]      # R = Sq·G query rows
@@ -243,15 +274,17 @@ def unfold_q(out, S: int, G: int):
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "cap", "G",
-                                             "interpret"))
+                                             "interpret", "windowed"))
 def paged_attn_pallas(q, pool_k, pool_v, pages, lens, window, layer, *,
                       scale: float, cap=None, G: int = 1,
-                      interpret: bool = False,
+                      interpret: bool = False, windowed: bool = True,
                       scale_k=None, scale_v=None):
     """q (B, Sq, Hq, D); pool_k/v (L, n_pages, ps, Hkv·Dp), a stage's
     lane-dense stacked pools; pages (B, P) int32; lens (B,) int32; window
     () int32 (``_NO_WINDOW`` ⇒ global); layer () int32, the pool layer to
-    read.  Query s of row b sits at absolute position ``lens[b] + s``; its
+    read; ``windowed`` False (static) only where ``window`` is
+    ``_NO_WINDOW``: the walk then leaves out the window's arithmetic.
+    Query s of row b sits at absolute position ``lens[b] + s``; its
     K/V must already be scattered into the pools.  Quantized pools (int8,
     or uint8 = packed int4 with Dp = D/2) pass their ``scale_k/scale_v
     (L, n_pages, Hkv·ps)`` f32 head-major page rows; page blocks of values
@@ -267,12 +300,14 @@ def paged_attn_pallas(q, pool_k, pool_v, pages, lens, window, layer, *,
     lyr = jnp.asarray(layer, jnp.int32).reshape(1)
 
     rows = min(SCALE_ROWS, pool_k.shape[1])  # page rows per scale block
-    page_idx = functools.partial(paged_kv_block_map, Sq=S, ps=ps)
+    page_idx = functools.partial(paged_kv_block_map, Sq=S, ps=ps,
+                                 windowed=windowed)
     scale_idx = functools.partial(paged_scale_block_map, Sq=S, ps=ps,
-                                  rows=rows)
+                                  rows=rows, windowed=windowed)
 
     kern = functools.partial(_decode_kernel, ps=ps, n_pb=n_pb, cap=cap, G=G,
-                             Sq=S, Dp=Dp, rows=rows, mode=mode)
+                             Sq=S, Dp=Dp, rows=rows, mode=mode,
+                             windowed=windowed)
     in_specs = [
         pl.BlockSpec((1, Hkv, R, D), paged_q_block_map),
         pl.BlockSpec((None, 1, ps, HDp), page_idx),
@@ -321,8 +356,9 @@ def _paged_attn_blocked(q, pool_k, pool_v, pages, lens, window, layer, *,
     """The kernel's algorithm in plain XLA: a ``fori_loop`` over K blocks
     of ``max(1, bk // page_size)`` pages (~``bk`` tokens, the flash
     kernel's K-block width — single-page steps drown in loop overhead on
-    CPU), bounded by ``max(lens)`` — the batch-wide early exit (the
-    Pallas path additionally skips per row).  Rows whose blocks are fully
+    CPU), bounded by ``max(lens)`` — the batch-wide early exit — and
+    started at the earliest block any row's window reaches (the Pallas
+    path skips per row at both ends).  Rows whose blocks are fully
     masked contribute exp(NEG_INF − m) == 0, so short rows match the
     per-row skip exactly."""
     B, S, Hq, D = q.shape
@@ -341,6 +377,8 @@ def _paged_attn_blocked(q, pool_k, pool_v, pages, lens, window, layer, *,
     if P % bp:                                       # pad table → scratch
         pages = jnp.pad(pages, ((0, 0), (0, bp - P % bp)))
     nb = (jnp.max(lens) + S - 1) // blk + 1
+    # the earliest block any row's window reaches (0 with no window)
+    j0 = jnp.min(first_block(lens, win, ps)) // bp
     t0 = jnp.arange(blk)
     rq = jnp.arange(S * G, dtype=jnp.int32) // G     # query index per row
 
@@ -377,7 +415,7 @@ def _paged_attn_blocked(q, pool_k, pool_v, pages, lens, window, layer, *,
     init = (jnp.full((B, Hkv, S * G), NEG_INF, f32),
             jnp.zeros((B, Hkv, S * G), f32),
             jnp.zeros((B, Hkv, S * G, D), f32))
-    m, l, acc = jax.lax.fori_loop(0, nb, body, init)
+    m, l, acc = jax.lax.fori_loop(j0, nb, body, init)
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return unfold_q(out, S, G).astype(q.dtype)
 
@@ -387,7 +425,7 @@ def _paged_attn_blocked(q, pool_k, pool_v, pages, lens, window, layer, *,
 # ---------------------------------------------------------------------------
 
 def _local(q, pool_k, pool_v, pages, lens, win, layer, *, scale, cap, G,
-           backend, scale_k=None, scale_v=None):
+           backend, windowed, scale_k=None, scale_v=None):
     if backend == "blocked":
         return _paged_attn_blocked(q, pool_k, pool_v, pages, lens, win,
                                    layer, scale=scale, cap=cap, G=G,
@@ -395,7 +433,8 @@ def _local(q, pool_k, pool_v, pages, lens, win, layer, *, scale, cap, G,
     interpret = interpret_mode(backend)
     return paged_attn_pallas(q, pool_k, pool_v, pages, lens, win, layer,
                              scale=scale, cap=cap, G=G, interpret=interpret,
-                             scale_k=scale_k, scale_v=scale_v)
+                             windowed=windowed, scale_k=scale_k,
+                             scale_v=scale_v)
 
 
 def paged_attn(q, pool_k, pool_v, pages, lens, *, layer, scale: float,
@@ -415,7 +454,8 @@ def paged_attn(q, pool_k, pool_v, pages, lens, *, layer, scale: float,
     be the identity or uniform grouped map (see ``gqa_group``); callers
     with irregular maps use the gather path.  ``window`` is None, an int,
     or a traced scalar (negative never reaches here — blocks resolve −1
-    to a huge window).  Sq is static: each distinct value compiles its
+    to a huge window); None compiles the walk without the window's
+    arithmetic.  Sq is static: each distinct value compiles its
     own kernel (the engine uses exactly two).
 
     Quantized pools (``cfg.kv_cache_dtype`` int8/int4 — detected from the
@@ -456,7 +496,8 @@ def paged_attn(q, pool_k, pool_v, pages, lens, *, layer, scale: float,
     win = _NO_WINDOW if window is None else window
     win = jnp.asarray(win, jnp.int32)
     layer = jnp.asarray(layer, jnp.int32)
-    kw = dict(scale=scale, cap=cap, G=G, backend=backend)
+    kw = dict(scale=scale, cap=cap, G=G, backend=backend,
+              windowed=window is not None)
 
     mesh = get_mesh()
     if mesh is not None and AXIS_MODEL in mesh.axis_names \

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.nn.common import (embed_init, embed_apply, norm_init, norm_apply,
                              linear_init, linear, softcap, mm)
 from repro.nn import blocks as B
+from repro.nn.moe import EXPERT_STACKS
 from repro.nn.attention import init_kv_cache
 from repro.nn.mla import init_mla_cache
 from repro.nn.ssm import init_ssm_cache
@@ -145,26 +146,39 @@ def _scan_stack(params_st, x, cfg, kind: str, windows, cache_st, positions,
     A paged cache (``ctx`` holds the page table) is the stage's stacked
     pools: they ride the scan carry and each layer writes its own rows in
     place at its index, so a dispatch moves only the pool bytes it
-    writes or reads.  Any other cache is sliced per layer (scan xs/ys)."""
+    writes or reads.  Any other cache is sliced per layer (scan xs/ys).
+    An expert stage's expert stacks stay whole in the same way: every
+    layer gets them with its index (``moe.EXPERT_STACKS``), so the
+    grouped matmul reads its layer's experts in place — a slice per
+    layer would copy them before every custom call."""
     ctx = ctx or {}
+    paged = cache_st is not None and "pages" in ctx
+    held = {}
+    if kind == "moe":
+        held = {n: params_st["moe"][n] for n in EXPERT_STACKS}
+        params_st = dict(params_st, moe={
+            n: a for n, a in params_st["moe"].items() if n not in held})
 
-    def apply_one(p_l, x, c_l, w_l, layer=None):
-        c_l = _inject_pos(c_l, kind, ctx if layer is None
-                          else dict(ctx, layer=layer))
+    def apply_one(p_l, x, c_l, w_l, layer):
+        c_l = _inject_pos(c_l, kind, dict(ctx, layer=layer) if paged
+                          else ctx)
         if kind == "hybrid":
             out, c2, a = B.hybrid_block_apply(p_l, x, cfg, window=w_l,
                                               cache=c_l, positions=positions)
         else:
             ffn = "moe" if kind == "moe" else "dense"
+            if held:
+                p_l = dict(p_l, moe=dict(p_l["moe"], **held))
             out, c2, a = B.decoder_block_apply(p_l, x, cfg, ffn=ffn,
                                                window=w_l, cache=c_l,
-                                               positions=positions)
+                                               positions=positions,
+                                               layer=layer)
         return out, _strip_pos(c2) if c2 is not None else None, a
 
     fn = jax.checkpoint(apply_one) if cfg.remat else apply_one
-    paged = cache_st is not None and "pages" in ctx
     L = windows.shape[0]
-    zero = jnp.zeros((), jnp.float32)
+    zero = jnp.zeros((B.AUX_SIZE,), jnp.float32)
+    layers = jnp.arange(L, dtype=jnp.int32)
 
     if not cfg.scan_layers:       # cost probes: unrolled layer loop
         aux = zero
@@ -173,12 +187,12 @@ def _scan_stack(params_st, x, cfg, kind: str, windows, cache_st, positions,
             p_l = jax.tree_util.tree_map(lambda a: a[i], params_st)
             if paged:
                 x, cache_st, a = fn(p_l, x, _pin_pools(cache_st, cfg),
-                                    windows[i], i)
+                                    windows[i], layers[i])
                 cache_st = _pin_pools(cache_st, cfg)
             else:
                 c_l = None if cache_st is None else \
                     jax.tree_util.tree_map(lambda a: a[i], cache_st)
-                x, c2, a = fn(p_l, x, c_l, windows[i])
+                x, c2, a = fn(p_l, x, c_l, windows[i], layers[i])
                 cs.append(c2)
             aux = aux + a
         if cache_st is None or paged:
@@ -189,10 +203,11 @@ def _scan_stack(params_st, x, cfg, kind: str, windows, cache_st, positions,
     if cache_st is None:
         def body(carry, xs):
             x, aux = carry
-            p_l, w_l = xs
-            x, _, a = fn(p_l, x, None, w_l)
+            p_l, w_l, i = xs
+            x, _, a = fn(p_l, x, None, w_l, i)
             return (x, aux + a), None
-        (x, aux), _ = jax.lax.scan(body, (x, zero), (params_st, windows))
+        (x, aux), _ = jax.lax.scan(body, (x, zero),
+                                   (params_st, windows, layers))
         return x, None, aux
 
     if paged:
@@ -202,17 +217,16 @@ def _scan_stack(params_st, x, cfg, kind: str, windows, cache_st, positions,
             x, c, a = fn(p_l, x, _pin_pools(c, cfg), w_l, i)
             return (x, aux + a, _pin_pools(c, cfg)), None
         (x, aux, cache_st), _ = jax.lax.scan(
-            body, (x, zero, cache_st),
-            (params_st, windows, jnp.arange(L, dtype=jnp.int32)))
+            body, (x, zero, cache_st), (params_st, windows, layers))
         return x, cache_st, aux
 
     def body(carry, xs):
         x, aux = carry
-        p_l, w_l, c_l = xs
-        x, c2, a = fn(p_l, x, c_l, w_l)
+        p_l, w_l, c_l, i = xs
+        x, c2, a = fn(p_l, x, c_l, w_l, i)
         return (x, aux + a), c2
     (x, aux), new_cache = jax.lax.scan(
-        body, (x, zero), (params_st, windows, cache_st))
+        body, (x, zero), (params_st, windows, cache_st, layers))
     return x, new_cache, aux
 
 
@@ -321,12 +335,15 @@ def _scan_xlstm(params_st, x, cfg, cache_st):
 # ---------------------------------------------------------------------------
 
 def apply_model(params, cfg, tokens, *, img=None, enc_x=None, cache=None,
-                return_hidden: bool = False):
+                return_hidden: bool = False, moe_stats: bool = False):
     """tokens (B, S) int32 → (logits (B, S', vocab_p), new_cache, aux).
 
     img: (B, n_patches, d) VLM patch embeddings (replace leading positions).
     enc_x: encoder frame embeddings for enc-dec models.
     cache: decode/prefill cache (None for training).
+    return_hidden: also return the final hidden states.
+    moe_stats: also return (2,) f32, summed over expert layers: the routed
+    (token, expert) assignments computed and the experts that got one.
     """
     if cfg.family == "encdec":
         from .encdec import apply_encdec
@@ -368,7 +385,7 @@ def apply_model(params, cfg, tokens, *, img=None, enc_x=None, cache=None,
         positions = pos0 + jnp.arange(S)
         ctx = {"pos": pos0}
 
-    aux = jnp.zeros((), jnp.float32)
+    aux = jnp.zeros((B.AUX_SIZE,), jnp.float32)
     new_layers = {}
     windows_all = np.asarray(
         [w if w is not None else -1 for w in cfg.layer_windows], np.int32)
@@ -396,9 +413,12 @@ def apply_model(params, cfg, tokens, *, img=None, enc_x=None, cache=None,
         new_cache = {"pos": pos0 + S, "layers": new_layers}
         if pad is not None:
             new_cache["pad"] = pad
+    out = (logits, new_cache, aux[0])
     if return_hidden:
-        return logits, new_cache, aux, h
-    return logits, new_cache, aux
+        out += (h,)
+    if moe_stats:
+        out += (aux[1:],)
+    return out
 
 
 def _head(params, cfg, h):

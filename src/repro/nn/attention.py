@@ -26,12 +26,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.parallel.sharding import constrain, AXIS_BATCH, AXIS_MODEL
-from .common import linear, linear_init, apply_rope, softcap, norm_init, \
-    norm_apply
+from .common import linear, linear_init, apply_rope, layer_rope, softcap, \
+    norm_init, norm_apply
 from .attention_mha import mha, NEG_INF, _mask  # grouped-layout core op
 from .paged import (scatter_kv, scatter_kv_quant, gather_kv,
                     gather_kv_dequant, paged_attn_decode)
-from repro.kernels.paged_attention import paged_attn, gqa_group
+from repro.kernels.paged_attention import paged_attn, gqa_group, _NO_WINDOW
 
 
 def kv_of_q_map(n_heads: int, n_kv: int, n_heads_p: int, n_kv_p: int
@@ -96,11 +96,18 @@ def attn_apply(p: dict, x: jnp.ndarray, cfg, *, layer_window=None,
     if positions is None:
         pos0 = 0 if cache is None else cache["pos"]
         positions = pos0 + jnp.arange(S)
-    if cfg.rope:
+    window = layer_window if layer_window is not None else cfg.sliding_window
+    if cfg.rope and cfg.rope_yarn_factor:
+        # yarn on full layers, plain on window ones: chosen from the
+        # layer's window, which the layer scan carries as data
+        full = True if window is None else window >= _NO_WINDOW
+        inv_freq, rs = layer_rope(cfg, full)
+        q = apply_rope(q, positions, inv_freq=inv_freq, scale=rs)
+        k = apply_rope(k, positions, inv_freq=inv_freq, scale=rs)
+    elif cfg.rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
 
-    window = layer_window if layer_window is not None else cfg.sliding_window
     scale = cfg.attn_scale or (1.0 / np.sqrt(hd))
     kv_map = kv_of_q_map(cfg.n_heads, cfg.n_kv_heads, cfg.n_heads_p,
                          cfg.n_kv_p)
@@ -166,8 +173,11 @@ def attn_apply(p: dict, x: jnp.ndarray, cfg, *, layer_window=None,
                  and gqa_group(kv_map, cfg.n_heads_p, cfg.n_kv_p)
                  is not None)
         if fused:
+            # a model with no window layer walks without the window's
+            # arithmetic (its scanned window is traced all the same)
+            windowed = any(w is not None for w in cfg.layer_windows)
             out = paged_attn(q, pk, pv, pages, lens, layer=layer,
-                             scale=scale, window=window,
+                             scale=scale, window=window if windowed else None,
                              cap=cfg.attn_softcap, kv_of_q=kv_map,
                              backend=cfg.attention_backend,
                              scale_k=sk, scale_v=sv)

@@ -22,6 +22,9 @@ from .ssm import ssm_init, ssm_apply
 from .xlstm import (mlstm_init, mlstm_apply, slstm_init, slstm_apply)
 
 GLOBAL_WINDOW = np.int32(2 ** 30)   # "-1 == global" sentinel resolves to this
+# a layer's aux, summed over layers: (load-balance loss, routed (token,
+# expert) assignments, experts that got one); only expert layers fill it
+AUX_SIZE = 3
 
 
 def _win(window):
@@ -54,7 +57,9 @@ def decoder_block_init(key, cfg, ffn: str = "dense") -> dict:
 
 
 def decoder_block_apply(p, x, cfg, *, ffn: str = "dense", window=None,
-                        cache=None, positions=None):
+                        cache=None, positions=None, layer=None):
+    """``layer``: the layer's index into whole expert stacks in
+    ``p['moe']`` (None: they are this layer's own, see ``moe_apply``)."""
     h = norm_apply(p, x, cfg.norm, cfg.norm_eps, "ln1")
     if cfg.use_mla:
         a, new_cache = mla_apply(p["mla"], h, cfg, cache=cache,
@@ -66,9 +71,10 @@ def decoder_block_apply(p, x, cfg, *, ffn: str = "dense", window=None,
         a = norm_apply(p, a, cfg.norm, cfg.norm_eps, "ln1p")
     x = x + a
     h = norm_apply(p, x, cfg.norm, cfg.norm_eps, "ln2")
-    aux = jnp.zeros((), jnp.float32)
+    aux = jnp.zeros((AUX_SIZE,), jnp.float32)
     if ffn == "moe":
-        f, aux = moe_apply(p["moe"], h, cfg)
+        f, loss, stats = moe_apply(p["moe"], h, cfg, layer=layer)
+        aux = jnp.concatenate([loss[None], stats])
     else:
         f = mlp_apply(p["mlp"], h, cfg.mac, cfg.act, cfg.gated_mlp,
                       cfg.cdtype)

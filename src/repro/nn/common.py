@@ -5,6 +5,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 
@@ -112,16 +113,63 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
                             / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float
-               ) -> jnp.ndarray:
-    """x: (B, S, H, D); positions: (B, S) or (S,)."""
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN's inverse frequencies (D/2,) float32, as transformers'
+    ``_compute_yarn_parameters`` computes them (``truncate`` on): the
+    dimensions that turn fewer than ``beta_slow`` times over the original
+    context are interpolated by ``factor``, those that turn more than
+    ``beta_fast`` times keep their frequency, and a linear ramp joins
+    them."""
+    d = head_dim
+    pos_freqs = theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def corr_dim(rot):
+        return d * np.log(original_max / (rot * 2 * np.pi)) / \
+            (2 * np.log(theta))
+    low = max(np.floor(corr_dim(beta_fast)), 0)
+    high = min(np.ceil(corr_dim(beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d // 2) - low) / (high - low), 0, 1)
+    extrap = 1.0 - ramp                 # share kept at the base frequency
+    inv = (1.0 / (factor * pos_freqs)) * (1 - extrap) + \
+        (1.0 / pos_freqs) * extrap
+    return inv.astype(np.float32)
+
+
+def layer_rope(cfg, full):
+    """(inv_freq (D/2,), cos/sin scale) of a layer: yarn's on a
+    full-attention layer (``full``: a bool, or a traced one read from the
+    scanned per-layer window), plain ``rope_theta`` otherwise."""
+    d = cfg.head_dim_r
+    plain = (1.0 / cfg.rope_theta ** (np.arange(0, d, 2, dtype=np.float64)
+                                      / d)).astype(np.float32)
+    yarn = yarn_inv_freq(d, cfg.rope_theta, cfg.rope_yarn_factor,
+                         cfg.rope_yarn_original_max, cfg.rope_yarn_beta_fast,
+                         cfg.rope_yarn_beta_slow)
+    mscale = cfg.rope_yarn_attention_factor
+    if mscale is None:
+        mscale = 0.1 * np.log(cfg.rope_yarn_factor) + 1.0
+    return (jnp.where(full, yarn, plain),
+            jnp.where(full, np.float32(mscale), np.float32(1.0)))
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = None,
+               *, inv_freq=None, scale=None) -> jnp.ndarray:
+    """x: (B, S, H, D); positions: (B, S) or (S,).  Frequencies from
+    ``theta``, or ``inv_freq`` (D/2,) as given; ``scale`` multiplies cos
+    and sin (yarn's attention factor)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # (D/2,)
+    freqs = rope_freqs(d, theta) if inv_freq is None else inv_freq
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, D/2)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return out.astype(x.dtype)

@@ -172,12 +172,20 @@ def make_paged_prefill(cfg):
 
 
 def make_paged_decode_step(cfg):
-    """One token for every slot against the shared page pool (greedy)."""
+    """One token for every slot against the shared page pool (greedy).
+    An expert model appends two numbers to the (n_slots,) tokens, summed
+    over its expert layers: the routed (token, expert) assignments the
+    step computed and the experts that got one, so the host reads both
+    with the tokens in one transfer."""
     def step(params, layers, tokens, pages, lens):
         cache = {"layers": layers, "pages": pages, "lens": lens}
-        logits, nc, _ = apply_model(params, cfg, tokens, cache=cache)
+        logits, nc, _, *routed = apply_model(params, cfg, tokens,
+                                             cache=cache,
+                                             moe_stats=bool(cfg.n_experts))
         toks = jnp.argmax(logits[:, -1, :cfg.vocab_size], -1
                           ).astype(jnp.int32)
+        if routed:
+            toks = jnp.concatenate([toks, routed[0].astype(jnp.int32)])
         return toks, nc["layers"]
     return step
 
@@ -369,6 +377,15 @@ class Engine:
             "stalls", "decode steps a request sat page-starved")
         self._c_rejects = reg.counter("rejects",
                                       "requests rejected at submit")
+        # expert models: routed work of the decode steps, summed over
+        # expert layers (every slot's row is routed, idle ones included)
+        self._c_moe_assign = reg.counter(
+            "moe_assignments",
+            "routed (token, expert) pairs computed by decode steps")
+        self._c_moe_active = reg.counter(
+            "moe_experts_active",
+            "experts that got at least one token in a decode step, summed "
+            "over steps and expert layers")
         self._c_jit = reg.counter(
             "jit_compiles",
             "XLA compilations of the jitted serving steps since this "
@@ -421,6 +438,8 @@ class Engine:
             "prefills": int(self._c_prefills.total()),
             "prefill_chunks": int(self._c_chunks.total()),
             "occupancy_sum": self._c_occ.total(),
+            "moe_assignments": int(self._c_moe_assign.total()),
+            "moe_experts_active": int(self._c_moe_active.total()),
         }
 
     @property
@@ -586,23 +605,29 @@ class Engine:
                     self.kv.set_len(r.slot, r.n_cached)
             for req in active:
                 tokens[req.slot, 0] = req.out[-1]
-            tok_dev = jnp.asarray(tokens)
-            pages_dev, lens_dev = self.kv.pages_dev(), self.kv.lens_dev()
         with tr.span("serve.decode", args={"n_active": len(active)}):
             with tr.span("serve.dispatch"), self._mesh_ctx():
+                # the host arrays go to the jitted step as they are: its
+                # call puts them on the device, with less host time than
+                # a jnp.asarray each.  The step is read back below before
+                # the page table or lens change again.
                 toks, self.kv.pools = self._step(
-                    self.params, self.kv.pools, tok_dev, pages_dev,
-                    lens_dev)
+                    self.params, self.kv.pools, tokens, self.kv.ptab,
+                    self.kv.lens)
             with tr.span("serve.wait"):
                 # analysis: allow(host-sync): THE step boundary — decoded
                 # tokens must reach the host for scheduling (eos/done/emit)
                 toks = np.asarray(toks)
+            if self.cfg.n_experts:
+                n = self.kv.n_slots
+                self._c_moe_assign.inc(int(toks[n]))
+                self._c_moe_active.inc(int(toks[n + 1]))
         with tr.span("serve.commit"):
             now = time.perf_counter()
+            self._c_decode.inc(len(active), mac=self._mac)
             for req in active:
                 req.n_cached += 1
                 req.out.append(int(toks[req.slot]))
-                self._c_decode.inc(1, mac=self._mac)
                 if req.done:
                     self.sched.finish(req, now)
                     self._trace_finish(req)

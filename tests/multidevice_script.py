@@ -66,7 +66,7 @@ print("OK dp_tp_matches_single")
 cfg_moe = dataclasses.replace(
     get_config("qwen3-moe-235b-a22b").reduced(), n_layers=2, d_model=64,
     head_dim=16, n_heads=4, n_kv_heads=2, d_ff_expert=32, vocab_size=128,
-    n_experts=8, top_k=2, capacity_factor=8.0)
+    n_experts=8, top_k=2)
 params_moe = init_model(jax.random.PRNGKey(1), cfg_moe)
 toks = jnp.asarray(np.random.default_rng(0).integers(
     0, cfg_moe.vocab_size, (4, 8)), jnp.int32)

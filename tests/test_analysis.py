@@ -71,7 +71,9 @@ def test_metric_docs_sync_round_trip():
 
 def test_real_index_maps_are_sound():
     for ps, sq in ((8, 1), (16, 5)):
-        assert check_paged_index_maps(ps=ps, Sq=sq) == []
+        for windowed in (False, True):
+            assert check_paged_index_maps(ps=ps, Sq=sq,
+                                          windowed=windowed) == []
 
 
 def test_off_by_one_index_map_is_caught():
@@ -93,6 +95,17 @@ def test_missing_lens_clamp_is_caught():
                                                       0, 0),
         ps=8, Sq=1)
     assert any("past-lens" in x.message for x in f)
+
+
+def test_missing_window_start_is_caught():
+    """A map that walks from page 0 under a window reads blocks before
+    the window's first page."""
+    import jax.numpy as jnp
+
+    def from_zero(b, p, pages, lens, win, layer):
+        return (layer[0], pages[b, jnp.minimum(p, lens[b] // 8)], 0, 0)
+    f = check_paged_index_maps(kv_map=from_zero, ps=8, Sq=1)
+    assert any("before-window" in x.message for x in f)
 
 
 def test_wrong_layer_index_map_is_caught():

@@ -113,3 +113,35 @@ else:
                              [(0, 1), (7, 2), (123, 3), (4242, 1)])
     def test_causality_property(seed, pert_pos):
         _check_causality(seed, pert_pos)
+
+
+def test_yarn_on_full_layers_only():
+    """With yarn configured, a full layer's rotary (no window, or the
+    scanned "global" window) takes yarn's frequencies and scales cos and
+    sin by the attention factor, so q·k at position 0 grows by its
+    square; a window layer keeps plain ``rope_theta``."""
+    from repro.configs.base import ModelConfig
+    from repro.nn.blocks import GLOBAL_WINDOW
+    from repro.nn.common import apply_rope, layer_rope, rope_freqs, \
+        yarn_inv_freq
+    cfg = ModelConfig(head_dim=16, n_heads=2, d_model=32, rope_theta=1e4,
+                      rope_yarn_factor=8.0, rope_yarn_original_max=64)
+    att = 0.1 * np.log(8.0) + 1.0
+    for full in (True, jnp.asarray(GLOBAL_WINDOW) >= GLOBAL_WINDOW):
+        inv, scale = layer_rope(cfg, full)
+        np.testing.assert_array_equal(
+            np.asarray(inv), yarn_inv_freq(16, 1e4, 8.0, 64))
+        assert float(scale) == pytest.approx(att)
+    inv, scale = layer_rope(cfg, jnp.asarray(24) >= GLOBAL_WINDOW)
+    np.testing.assert_allclose(np.asarray(inv), np.asarray(rope_freqs(16,
+                                                                      1e4)),
+                               rtol=1e-6)
+    assert float(scale) == 1.0
+    q, k, _ = _qkv(7, B=1, S=4, Hq=1, Hkv=1, D=16)
+    pos = jnp.arange(4)
+    inv, scale = layer_rope(cfg, True)
+    qr = apply_rope(q, pos, inv_freq=inv, scale=scale)
+    kr = apply_rope(k, pos, inv_freq=inv, scale=scale)
+    np.testing.assert_allclose(np.asarray((qr * kr).sum(-1))[0, 0],
+                               att ** 2 * np.asarray((q * k).sum(-1))[0, 0],
+                               rtol=1e-5, atol=1e-5)

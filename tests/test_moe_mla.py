@@ -12,8 +12,22 @@ from repro.nn.common import act_fn
 
 
 def _moe_cfg():
-    cfg = get_config("qwen3-moe-235b-a22b").reduced()
-    return dataclasses.replace(cfg, capacity_factor=8.0)  # no drops
+    return get_config("qwen3-moe-235b-a22b").reduced()
+
+
+def _dense_moe(x, idx, w, p, act):
+    """Every token through each of its routed experts, in numpy."""
+    wi, wg, wo = (np.asarray(p[k]) for k in
+                  ("experts_wi", "experts_wg", "experts_wo"))
+    xn = np.asarray(x)
+    ref = np.zeros(xn.shape, np.float32)
+    for t in range(xn.shape[0]):
+        for j in range(idx.shape[1]):
+            e = int(idx[t, j])
+            h = xn[t] @ wi[e]
+            g = np.asarray(act_fn(act)(jnp.asarray(xn[t] @ wg[e])))
+            ref[t] += float(w[t, j]) * ((g * h) @ wo[e])
+    return ref
 
 
 def test_dispatch_matches_dense_reference():
@@ -24,38 +38,81 @@ def test_dispatch_matches_dense_reference():
     T, d = 24, cfg.d_model
     x = jnp.asarray(rng.normal(size=(T, d)), jnp.float32)
     idx, w, aux = route(p, x, cfg)
-    cap = max(4, int(cfg.capacity_factor * T * cfg.top_k / cfg.n_experts))
-    got = dispatch_compute(x, idx, w, p["experts_wi"], p["experts_wg"],
-                           p["experts_wo"], n_experts_total=cfg.n_experts,
-                           capacity=cap, act=cfg.act, axis_name=None)
-
-    # dense reference: every token through its top-k experts
-    wi, wg, wo = (np.asarray(p[k]) for k in
-                  ("experts_wi", "experts_wg", "experts_wo"))
-    ref = np.zeros((T, d), np.float32)
-    xn = np.asarray(x)
-    for t in range(T):
-        for j in range(cfg.top_k):
-            e = int(idx[t, j])
-            h = xn[t] @ wi[e]
-            g = np.asarray(act_fn(cfg.act)(jnp.asarray(xn[t] @ wg[e])))
-            ref[t] += float(w[t, j]) * ((g * h) @ wo[e])
+    got, stats = dispatch_compute(x, idx, w, p["experts_wi"],
+                                  p["experts_wg"], p["experts_wo"],
+                                  act=cfg.act)
+    ref = _dense_moe(x, idx, w, p, cfg.act)
     np.testing.assert_allclose(np.asarray(got), ref, rtol=2e-3, atol=2e-3)
+    used = len(np.unique(np.asarray(idx)))
+    assert np.asarray(stats).tolist() == [T * cfg.top_k, used]
 
 
-def test_capacity_drops_tokens_deterministically():
-    cfg = dataclasses.replace(_moe_cfg(), capacity_factor=0.01)
+def test_no_drop_routing_under_a_skewed_router():
+    """Every token sends its first choice to expert 3: the expert gets all
+    T of them (a capacity of 1.25·T·k/E would drop most) and each is
+    computed exactly as the dense reference computes it."""
+    cfg = _moe_cfg()
     p = moe_init(jax.random.PRNGKey(1), cfg)
-    x = jnp.asarray(np.random.default_rng(1).normal(size=(16, cfg.d_model)),
+    T = 32
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(T, cfg.d_model)),
                     jnp.float32)
     idx, w, _ = route(p, x, cfg)
-    out1 = dispatch_compute(x, idx, w, p["experts_wi"], p["experts_wg"],
-                            p["experts_wo"], n_experts_total=cfg.n_experts,
-                            capacity=4, act=cfg.act, axis_name=None)
-    out2 = dispatch_compute(x, idx, w, p["experts_wi"], p["experts_wg"],
-                            p["experts_wo"], n_experts_total=cfg.n_experts,
-                            capacity=4, act=cfg.act, axis_name=None)
-    np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
+    idx = np.asarray(idx).copy()
+    for t in range(T):                    # 3 first, the rest distinct
+        rest = [e for e in idx[t] if e != 3][:cfg.top_k - 1]
+        idx[t] = [3] + rest
+    idx = jnp.asarray(idx)
+    out, stats = dispatch_compute(x, idx, w, p["experts_wi"],
+                                  p["experts_wg"], p["experts_wo"],
+                                  act=cfg.act)
+    ref = _dense_moe(x, idx, w, p, cfg.act)
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3, atol=2e-3)
+    assert int(stats[0]) == T * cfg.top_k
+    again, _ = dispatch_compute(x, idx, w, p["experts_wi"], p["experts_wg"],
+                                p["experts_wo"], act=cfg.act)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(again))
+
+
+def test_expert_shares_add_up_to_the_uncut_layer():
+    """Four shares of the 8 experts (2 each, first expert 0, 2, 4, 6), as
+    four chips of an expert-parallel deployment hold them: their parts of
+    the output and their counts add up to the layer that holds all 8."""
+    cfg = _moe_cfg()
+    assert cfg.n_experts == 8
+    p = moe_init(jax.random.PRNGKey(4), cfg)
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(20, cfg.d_model)),
+                    jnp.float32)
+    idx, w, _ = route(p, x, cfg)
+    whole, whole_stats = dispatch_compute(
+        x, idx, w, p["experts_wi"], p["experts_wg"], p["experts_wo"],
+        act=cfg.act)
+    parts = [dispatch_compute(x, idx, w, p["experts_wi"][o:o + 2],
+                              p["experts_wg"][o:o + 2],
+                              p["experts_wo"][o:o + 2], act=cfg.act,
+                              offset=o) for o in (0, 2, 4, 6)]
+    np.testing.assert_allclose(sum(np.asarray(o) for o, _ in parts),
+                               np.asarray(whole), rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(sum(np.asarray(s) for _, s in parts),
+                                  np.asarray(whole_stats))
+
+
+def test_tpu_grouped_matmul_matches_ragged_dot(monkeypatch):
+    """The TPU lowering (rows padded to the kernel's block, the Pallas
+    kernel in interpret mode here) against ``lax.ragged_dot``; rows past
+    the groups are not compared (left undefined)."""
+    from repro.nn import moe
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    monkeypatch.setattr(moe, "moe_experts", lambda x, w, g, *, tiling: gmm(
+        x, w, g, tiling=tiling, interpret=True))
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(size=(40, 256)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(4, 256, 384)), jnp.float32)
+    sizes = jnp.asarray([9, 0, 20, 7], jnp.int32)
+    got = moe._grouped_tpu(x, w, sizes)
+    want = jax.lax.ragged_dot(x, w, sizes,
+                              precision=jax.lax.Precision.HIGHEST)
+    np.testing.assert_allclose(np.asarray(got)[:36], np.asarray(want)[:36],
+                               rtol=1e-4, atol=1e-3)
 
 
 def test_router_weights_normalized():

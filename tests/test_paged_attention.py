@@ -84,6 +84,38 @@ def test_op_matches_gather_reference(backend, ps, Hq, Hkv, window, cap):
 
 
 @pytest.mark.parametrize("backend", ["blocked", "pallas_interpret"])
+@pytest.mark.parametrize("S", [1, 3])
+@pytest.mark.parametrize("window", [5, 12, 13, 40])
+def test_window_walk_matches_the_masked_full_walk(backend, S, window):
+    """The walk that starts at the window's first page against the gather
+    reference's full masked walk, for windows smaller than, equal to and
+    larger than the contexts (12 and 13 keys for the first query of
+    rows 1 and 2).  Pallas: pages wholly before every query's window are
+    filled with NaN, so a walk that still read them could not match."""
+    rng = np.random.default_rng(window * 10 + S)
+    B, D, P, ps, Hq, Hkv = 3, 16, 6, 4, 8, 2
+    q, pool_k, pool_v, pages, kv_map = _pool_case(rng, B, Hq, Hkv, D, ps, P)
+    q = jnp.asarray(rng.normal(size=(B, S, Hq, D)), jnp.float32)
+    lens = np.asarray([3, 11, 12], np.int32)
+    if backend == "pallas_interpret":
+        for b in range(B):
+            for blk in range(max(0, (lens[b] - window + 1) // ps)):
+                pid = int(pages[b, blk])
+                pool_k = pool_k.at[LAYER, pid].set(jnp.nan)
+                pool_v = pool_v.at[LAYER, pid].set(jnp.nan)
+    lens = jnp.asarray(lens)
+    scale = 1.0 / np.sqrt(D)
+    ref = _reference(q, jnp.nan_to_num(pool_k), jnp.nan_to_num(pool_v),
+                     pages, lens, kv_map, scale=scale, window=window,
+                     cap=None)
+    out = paged_attn(q, pool_k, pool_v, pages, lens, layer=LAYER,
+                     scale=scale, window=window, kv_of_q=kv_map,
+                     backend=backend)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("backend", ["blocked", "pallas_interpret"])
 def test_op_lens_sweep_page_boundaries(backend):
     """Every lens around each page boundary, incl. the last table slot."""
     rng = np.random.default_rng(7)

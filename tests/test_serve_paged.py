@@ -381,3 +381,52 @@ def test_generate_runs_no_wasted_decode_step(qwen, monkeypatch):
     out = generate(params, cfg, jnp.asarray(p)[None], max_new=4)
     assert out.shape == (1, 4)
     assert calls["n"] == 3
+
+
+# ---------------------------------------------------------------------------
+# configurations read from JSON; expert models
+# ---------------------------------------------------------------------------
+
+def test_config_from_json_hashes_and_memoizes_steps():
+    """A configuration file gives lists; the frozen config keeps tuples,
+    so it hashes and two engines share one pair of jitted steps."""
+    import json
+    from repro.configs.base import ModelConfig
+    from repro.serve.engine import _jitted_paged_steps
+    kw = json.loads(json.dumps(dict(
+        n_layers=4, sliding_window=8, global_layers=[3], vocab_size=64)))
+    a, b = ModelConfig(**kw), ModelConfig(**kw)
+    assert a.global_layers == (3,) and hash(a) == hash(b)
+    assert a.layer_windows == [8, 8, 8, None]
+    assert _jitted_paged_steps(a, None) is _jitted_paged_steps(b, None)
+
+
+def test_expert_engine_counts_routed_work():
+    """Each decode step of an expert model reads its routed work with its
+    tokens: every slot's row to top_k experts in every expert layer; a
+    dense model counts nothing and its step returns the tokens alone."""
+    import dataclasses
+    cfg = dataclasses.replace(get_config("qwen3-moe-235b-a22b").reduced(),
+                              attention_backend="auto")
+    params = init_model(jax.random.PRNGKey(3), cfg)
+    eng = Engine(params, cfg, n_slots=3, page_size=8, n_pages=32)
+    calls = []
+    step = eng._step
+    eng._step = lambda *a: calls.append(step(*a)) or calls[-1]
+    rid = eng.submit(_prompts(cfg, (9,))[0], max_new=5)
+    eng.run()
+    assert len(eng.results()[rid]) == 5
+    m = eng.metrics
+    assert calls and all(t.shape == (3 + 2,) for t, _ in calls)
+    assert m["moe_assignments"] == \
+        len(calls) * 3 * cfg.top_k * cfg.n_layers
+    assert 0 < m["moe_experts_active"] <= \
+        len(calls) * cfg.n_experts * cfg.n_layers
+
+    dense_cfg = get_config("qwen1.5-0.5b").reduced()
+    dense = Engine(init_model(jax.random.PRNGKey(0), dense_cfg), dense_cfg,
+                   n_slots=3, page_size=8, n_pages=32)
+    dense.submit(_prompts(dense_cfg, (9,))[0], max_new=3)
+    dense.run()
+    assert dense.metrics["moe_assignments"] == 0
+    assert dense.metrics["moe_experts_active"] == 0

@@ -295,3 +295,70 @@ def test_paged_decode_compiles_on_a_model4_mesh(topo, monkeypatch, n_kv):
     assert [(op, n) for op, n, _ in collective_instrs(hlo)
             if n >= shard_bytes] == []
     assert len(input_output_aliases(hlo)) == 2
+
+
+# ---------------------------------------------------------------------------
+# mellum2-12b-a2.5b (bench/configs/mellum2-12b-a2.5b-4L.json): 4 kv heads
+# of 128 under 32 q heads, windowed and full layers, 64 experts of 896
+# ---------------------------------------------------------------------------
+
+def _mellum_cfg():
+    import json
+    from repro.configs.base import ModelConfig
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench", "configs",
+                           "mellum2-12b-a2.5b-4L.json")) as f:
+        return ModelConfig(**dict(json.load(f)["program"],
+                                  attention_backend="pallas"))
+
+
+@pytest.mark.parametrize("window", [1024, 2 ** 30])
+def test_paged_attention_compiles_at_mellum_widths(one_chip, window):
+    """The kernel at 4 kv heads of 128 and G = 8 over f32 pools, a
+    1024-key window (the walk starts inside the table) and none: 48
+    rows, 16-token pages, 320 table pages."""
+    B, ps, P, n_pages, L = 48, 16, 320, 1024, 4
+    f = jax.jit(lambda q, pk, pv, pg, ln, w: paged_attn_pallas(
+        q, pk, pv, pg, ln, w, jnp.int32(1), scale=128 ** -0.5, G=8))
+    pool = _sds((L, n_pages, ps, 4 * 128), jnp.float32, one_chip)
+    with jax.default_matmul_precision("highest"):
+        compiled = f.lower(
+            _sds((B, 1, 32, 128), jnp.float32, one_chip), pool, pool,
+            _sds((B, P), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip),
+            _sds((), jnp.int32, one_chip)).compile()
+    _assert_mosaic(compiled)
+
+
+def test_mellum_decode_step_compiles_with_pinned_expert_op_name(
+        one_chip, monkeypatch):
+    """The donated paged decode step of the configuration at its widths
+    (48 rows, f32 at highest), its expert layers on the grouped-matmul
+    kernel: three ``%moe_experts.<n>`` ops (up, gate, down) beside the
+    paged-attention kernel, which ``moe_experts_roofline`` and
+    ``paged_attn_roofline`` find by name; the routed work rides out with
+    the tokens."""
+    from repro.kernels import paged_attention as pa
+    from repro.models import init_model, init_paged_cache
+    from repro.serve.engine import make_paged_decode_step
+    monkeypatch.setattr(pa, "interpret_mode", lambda backend: False)
+    cfg = _mellum_cfg()
+    B, ps, P, n_pages = 48, 16, 320, 512
+
+    def place(tree):
+        return jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+    params = place(jax.eval_shape(
+        lambda: init_model(jax.random.PRNGKey(0), cfg)))
+    pools = place(jax.eval_shape(
+        lambda: init_paged_cache(cfg, n_pages, ps))["layers"])
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(make_paged_decode_step(cfg),
+                           donate_argnums=(1,)).lower(
+            params, pools, _sds((B, 1), jnp.int32, one_chip),
+            _sds((B, P), jnp.int32, one_chip),
+            _sds((B,), jnp.int32, one_chip)).compile()
+    hlo = compiled.as_text()
+    _assert_mosaic(compiled)
+    names = set(re.findall(r"%(moe_experts\.\d+) = ", hlo))
+    assert len(names) == 3, names
+    assert "%paged_attn_pallas." in hlo
+    assert compiled.out_info[0].shape == (B + 2,)
