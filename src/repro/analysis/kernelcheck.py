@@ -1,18 +1,22 @@
 """Pallas kernel bounds checker (DESIGN.md §12).
 
-Proves, before anything runs on a device, that the kernels' BlockSpec
-index maps stay inside their operand pools:
+Proves, before anything runs on a device, that the kernels' copies and
+BlockSpec index maps stay inside their operand pools:
 
   * **paged attention, Pallas lowering** — evaluates the REAL module-level
-    index maps (``paged_kv_block_map`` / ``paged_scale_block_map`` /
-    ``paged_q_block_map``, exactly what ``paged_attn_pallas`` partials
-    into its BlockSpecs) over the full ``(B, max_seq_pages)`` grid ×
-    boundary ``lens`` values (0, 1, ps−1, ps, ps+1, 2ps−1, max_seq−Sq)
-    × windows (none, ps, 2ps+1) and asserts every returned block reads
-    the layer it was given and the page of the clamp contract
-    ``pages[b, clip(p, first, (lens[b]+Sq−1)//ps)]``, ``first`` the
-    window's first block ``max(0, (lens[b]−window+1)//ps)`` — in-bounds
-    AND never a past-lens or before-window block;
+    walk (``block_pages`` / ``pool_page`` / ``scale_rows_start`` /
+    ``paged_q_block_map``, exactly what ``_decode_kernel`` calls for its
+    copies) for every row and every block of the kernel's loop
+    (``n_blocks``) × boundary ``lens`` values (page and compute-block
+    edges, the last the table holds) × windows (none, ps, 2ps+1, a
+    block and a bit) × the block sizes the registry's geometries and kv
+    dtypes give, and asserts the blocks' live slots tile the row's
+    needed slots ``first..(lens[b]+Sq−1)//ps`` exactly once, in order,
+    ``first`` the window's first page ``max(0, (lens[b]−window+1)//ps)``
+    — never a past-lens or before-window slot, never past the table —
+    that each copy reads ``(layer, pages[b, slot])``, and that a page's
+    scale copy reads the aligned block of rows holding its own, inside
+    the pool's tiles;
   * **paged attention, blocked (XLA) lowering** — executes
     ``_paged_attn_blocked`` under ``jax.disable_jit()`` with
     ``jax.lax.dynamic_slice_in_dim`` / ``pool_pages`` replaced by guards
@@ -27,11 +31,11 @@ index maps stay inside their operand pools:
 Geometry coverage is driven by the configs registry: every paged-servable
 arch × page sizes (8, 16) × kv dtypes (bf16, int8, int4) × Sq ∈ {1, 5}
 (decode and spec-verify shapes); each geometry's pool layout is
-``eval_shape``d and cross-checked against the BlockSpec block shape.
+``eval_shape``d and cross-checked against the page the kernel copies.
 
-The map-evaluation cores take the maps as arguments so the self-test can
-inject seeded mutations (off-by-one, missing clamp) and prove they are
-caught.
+The walk-evaluation core takes the walk's functions as arguments so the
+self-test can inject seeded mutations (off-by-one, missing clamp,
+missing window start, wrong layer) and prove they are caught.
 """
 from __future__ import annotations
 
@@ -63,96 +67,136 @@ def _loc(fn) -> Tuple[str, int]:
     return rel, code.co_firstlineno
 
 
-def _boundary_lens(ps: int, P: int, Sq: int) -> List[int]:
+def _boundary_lens(ps: int, P: int, Sq: int, ppb: int = 1) -> List[int]:
+    """Lens at page and compute-block boundaries, and the last a table
+    of P pages holds."""
     max_len = P * ps - Sq             # caller contract: lens + Sq <= P*ps
-    vals = {0, 1, ps - 1, ps, ps + 1, 2 * ps - 1, max_len}
+    blk = ppb * ps
+    vals = {0, 1, ps - 1, ps, ps + 1, 2 * ps - 1, blk - 1, blk, blk + 1,
+            2 * blk + ps // 2, max_len}
     return sorted(v for v in vals if 0 <= v <= max_len)
 
 
-def check_paged_index_maps(kv_map: Optional[Callable] = None,
-                           scale_map: Optional[Callable] = None,
-                           q_map: Optional[Callable] = None, *,
-                           ps: int, Sq: int, B: int = 3, P: int = 4,
-                           label: str = "",
-                           windowed: bool = True) -> List[Finding]:
-    """Evaluate the paged-attention index maps over grid × boundary lens
-    × windows (``windowed``: the walk a windowed model compiles; else
-    only ``_NO_WINDOW``, the walk of a model with no window layer).
+def check_paged_walk(block_fn: Optional[Callable] = None,
+                     page_fn: Optional[Callable] = None,
+                     scale_fn: Optional[Callable] = None,
+                     q_map: Optional[Callable] = None, *,
+                     ps: int, Sq: int, ppb: int, B: int = 3,
+                     P: Optional[int] = None, label: str = "",
+                     windowed: bool = True) -> List[Finding]:
+    """Evaluate the paged-attention walk over every row × every block
+    the kernel's loop runs (``n_blocks``) × boundary lens × windows
+    (``windowed``: the walk a windowed model compiles; else only
+    ``_NO_WINDOW``).  The contract: the blocks' live slots tile the
+    row's needed slots ``first..last`` exactly, in order, at most
+    ``ppb`` a block, where ``last = (lens[b]+Sq−1)//ps`` and ``first``
+    is the window's first page ``max(0, (lens[b]−window+1)//ps)`` (0
+    unwindowed); each copy reads ``(layer, pages[b, slot])``; a page's
+    scale copy reads an aligned block of ``rows`` rows that holds its
+    row and lies inside the pool's 8-row tiles.
 
-    Defaults to the real kernel maps; pass mutated maps to prove the
-    checker catches them (self-test).  Returns findings (empty = sound).
+    Defaults to the kernel's own functions (``block_pages``,
+    ``pool_page``, ``scale_rows_start``, ``paged_q_block_map``); pass
+    mutated ones to prove the checker catches them (self-test).
+    Returns findings (empty = sound).
     """
     import numpy as np
-    import jax.numpy as jnp
     from repro.kernels import paged_attention as pa
 
-    if kv_map is None:
-        kv_map = functools.partial(pa.paged_kv_block_map, Sq=Sq, ps=ps,
-                                   windowed=windowed)
-    n_pages = B * P + 1
-    rows = min(pa.SCALE_ROWS, n_pages)    # page rows per scale block
-    if scale_map is None:
-        scale_map = functools.partial(pa.paged_scale_block_map, Sq=Sq, ps=ps,
-                                      rows=rows, windowed=windowed)
+    walk = dict(Sq=Sq, ps=ps, ppb=ppb, windowed=windowed)
+    if block_fn is None:
+        block_fn = functools.partial(pa.block_pages, **walk)
+    if page_fn is None:
+        page_fn = pa.pool_page
+    if scale_fn is None:
+        scale_fn = pa.scale_rows_start
     if q_map is None:
         q_map = pa.paged_q_block_map
+    if P is None:
+        P = 2 * ppb + 3               # room for three blocks a row
+    n_pages = B * P + 1               # not a multiple of 8 in general
+    rows = min(pa.SCALE_ROWS, n_pages)
+    padded = -(-n_pages // rows) * rows   # the page axis in whole tiles
 
     layer_id = 2                      # a nonzero layer, so layer 0 is wrong
-    layer = jnp.asarray([layer_id], jnp.int32)
-    # distinct nonzero page ids per (b, p) cell so any mis-indexing is
+    layer = np.asarray([layer_id, layer_id], np.int32)  # values, scales
+    # distinct nonzero page ids per (b, slot) so any mis-indexing is
     # visible as a wrong id, not a coincidental match
-    pages_np = np.arange(1, n_pages).reshape(B, P).astype(np.int32)
-    pages = jnp.asarray(pages_np)
+    pages = np.arange(1, n_pages).reshape(B, P).astype(np.int32)
     out: List[Finding] = []
-    lens_vals = _boundary_lens(ps, P, Sq)
+    lens_vals = _boundary_lens(ps, P, Sq, ppb)
     # uniform sweeps plus one mixed row assignment
     configs = [[v] * B for v in lens_vals]
     configs.append([lens_vals[i % len(lens_vals)] for i in range(B)])
-    windows = (int(pa._NO_WINDOW),) + ((ps, 2 * ps + 1) if windowed else ())
+    windows = (int(pa._NO_WINDOW),) + ((ps, 2 * ps + 1, ppb * ps + 3)
+                                       if windowed else ())
 
-    kv_loc = _loc(kv_map)
-    sc_loc = _loc(scale_map)
-    q_loc = _loc(q_map)
+    bl_loc, pg_loc = _loc(block_fn), _loc(page_fn)
+    sc_loc, q_loc = _loc(scale_fn), _loc(q_map)
     for lens_list, window in itertools.product(configs, windows):
-        lens = jnp.asarray(lens_list, jnp.int32)
-        win = jnp.asarray([window], jnp.int32)
-        for b, p in itertools.product(range(B), range(P)):
+        lens = np.asarray(lens_list, np.int32)
+        win = np.asarray([window], np.int32)
+        for b in range(B):
             last = (lens_list[b] + Sq - 1) // ps
             first = max(0, (lens_list[b] - window + 1) // ps)
-            want = int(pages_np[b, min(max(p, first), last)])
-            ctx = (f"{label} ps={ps} Sq={Sq} lens[b]={lens_list[b]} "
-                   f"window={window} (b={b}, p={p})")
-            r = kv_map(b, p, pages, lens, win, layer)
-            if len(r) != 4 or int(r[0]) != layer_id or \
-                    any(int(x) != 0 for x in r[2:]):
-                out.append(Finding(RULE, kv_loc[0], kv_loc[1],
-                                   f"kv map returned {r} — expected "
-                                   f"({layer_id}, page, 0, 0) [{ctx}]"))
-                continue
-            pid = int(r[1])
-            if not 0 <= pid < n_pages:
+            nblk = int(pa.n_blocks(lens[b], win[0], **walk))
+            ctx = (f"{label} ps={ps} Sq={Sq} ppb={ppb} lens[b]={lens_list[b]}"
+                   f" window={window} b={b}")
+            want = list(range(first, last + 1))
+            seen: List[int] = []
+            for j in range(nblk):
+                slot0, n_live = (int(x) for x in block_fn(b, j, lens, win))
+                if not 1 <= n_live <= ppb:
+                    out.append(Finding(
+                        RULE, bl_loc[0], bl_loc[1],
+                        f"block {j} has {n_live} live slots, not 1..{ppb} "
+                        f"[{ctx}]"))
+                    continue
+                for slot in range(slot0, slot0 + n_live):
+                    seen.append(slot)
+                    if slot > last or slot >= P:
+                        out.append(Finding(
+                            RULE, bl_loc[0], bl_loc[1],
+                            f"block {j} copies slot {slot} past the last "
+                            f"needed slot {last} (past-lens block; table "
+                            f"of {P}) [{ctx}]"))
+                        continue
+                    if slot < first:
+                        out.append(Finding(
+                            RULE, bl_loc[0], bl_loc[1],
+                            f"block {j} copies slot {slot} before the "
+                            f"window's first slot {first} (before-window "
+                            f"block) [{ctx}]"))
+                        continue
+                    lyr, pid = (int(x) for x in page_fn(b, slot, pages,
+                                                        layer))
+                    if lyr != layer_id:
+                        out.append(Finding(
+                            RULE, pg_loc[0], pg_loc[1],
+                            f"copy of slot {slot} reads layer {lyr}, not "
+                            f"{layer_id} (wrong layer) [{ctx}]"))
+                    if pid != pages[b, slot]:
+                        out.append(Finding(
+                            RULE, pg_loc[0], pg_loc[1],
+                            f"copy of slot {slot} reads page {pid}, "
+                            f"contract says pages[b, {slot}] = "
+                            f"{pages[b, slot]} (wrong page) [{ctx}]"))
+                        continue
+                    r0 = int(scale_fn(pid, rows))
+                    if r0 % rows or not r0 <= pid < r0 + rows \
+                            or r0 + rows > padded:
+                        out.append(Finding(
+                            RULE, sc_loc[0], sc_loc[1],
+                            f"scale copy of page {pid} reads rows "
+                            f"[{r0}, {r0 + rows}): not the aligned "
+                            f"{rows}-row block holding it inside the "
+                            f"{padded} tiled rows [{ctx}]"))
+            if seen != want and all(first <= x <= last for x in seen):
                 out.append(Finding(
-                    RULE, kv_loc[0], kv_loc[1],
-                    f"kv map reads page {pid} outside the "
-                    f"[0, {n_pages}) pool [{ctx}]"))
-            elif pid != want:
-                kind = ("past-lens block (clamp violated)" if p > last
-                        else "before-window block" if p < first
-                        else "wrong page")
-                out.append(Finding(
-                    RULE, kv_loc[0], kv_loc[1],
-                    f"kv map reads page {pid}, contract says "
-                    f"pages[b, clip(p, {first}, {last})] = {want} — {kind} "
-                    f"[{ctx}]"))
-            rs = tuple(int(x) for x in scale_map(b, p, pages, lens, win,
-                                                 layer))
-            if rs != (layer_id, want // rows, 0):
-                out.append(Finding(
-                    RULE, sc_loc[0], sc_loc[1],
-                    f"scale map returned {rs}, expected ({layer_id}, "
-                    f"{want // rows}, 0): the {rows}-row block of page "
-                    f"{want} [{ctx}]"))
-            rq = q_map(b, p, pages, lens, win, layer)
+                    RULE, bl_loc[0], bl_loc[1],
+                    f"the blocks copy slots {seen}, not each of "
+                    f"{first}..{last} once in order [{ctx}]"))
+            rq = q_map(b, pages, lens, win, layer)
             if tuple(int(x) for x in rq) != (b, 0, 0, 0):
                 out.append(Finding(
                     RULE, q_loc[0], q_loc[1],
@@ -321,10 +365,23 @@ def _registry_geometries():
             yield arch, dataclasses.replace(cfg, kv_cache_dtype=dt), dt
 
 
+def _pages_per_block(cfg, ps: int) -> int:
+    """The compute block the kernel picks for ``cfg``'s pools at page
+    size ``ps`` (``paged_attn_pallas``: ``pages_per_block`` of a page's
+    VMEM bytes)."""
+    from repro.kernels import paged_attention as pa
+    from repro.quant.kvcache import kv_pool_layout
+    dtype, dp, quant = kv_pool_layout(cfg)
+    n_kv = cfg.n_kv_p
+    return pa.pages_per_block(ps, pa.page_vmem_bytes(
+        ps, pa.whole_lanes(n_kv * dp), dtype,
+        pa.whole_lanes(n_kv * ps) if quant else 0, pa.SCALE_ROWS))
+
+
 def _check_pool_layout(arch: str, cfg, dt: str, ps: int) -> List[Finding]:
-    """eval_shape the geometry's pools and cross-check the BlockSpec block
-    shapes the kernel would carve from them: (1, ps, Hkv·Dp) value pages
-    and head-major (Hkv·ps) scale rows."""
+    """eval_shape the geometry's pools and cross-check what the kernel
+    copies from them: (ps, Hkv·Dp) value pages and head-major (Hkv·ps)
+    scale rows."""
     import jax
     from repro.models import init_paged_cache
     out: List[Finding] = []
@@ -356,19 +413,25 @@ def _check_pool_layout(arch: str, cfg, dt: str, ps: int) -> List[Finding]:
 
 
 def run_kernelcheck() -> Tuple[List[Finding], Dict]:
-    """Full sweep: index maps for every (ps, Sq), pool layout for every
-    registry geometry × kv dtype, the blocked lowering under guarded
-    primitives, and the encoded-matmul maps over registry linear shapes.
-    """
+    """Full sweep: the walk for every (ps, Sq, block size), pool layout
+    for every registry geometry × kv dtype, the blocked lowering under
+    guarded primitives, and the encoded-matmul maps over registry linear
+    shapes."""
     findings: List[Finding] = []
     geoms = list(_registry_geometries())
     archs = sorted({a for a, _, _ in geoms})
-    # the index maps depend only on (ps, Sq) — evaluate once per pair,
-    # then pin every registry geometry to a layout cross-check
+    # the walk depends on (ps, Sq) and the pages a block holds, which
+    # the kernel derives from each geometry's page bytes and kv dtype:
+    # check it once per distinct block size, plus 1 and an odd 3
     for ps, sq in itertools.product(PAGE_SIZES, SQ_VALUES):
-        for windowed in (False, True):
-            findings.extend(check_paged_index_maps(
-                ps=ps, Sq=sq, label="pallas", windowed=windowed))
+        sizes = {1: "one page", 3: "three pages"}
+        for _, cfg, dt in geoms:
+            sizes.setdefault(_pages_per_block(cfg, ps), dt)
+        for ppb, why in sorted(sizes.items()):
+            for windowed in (False, True):
+                findings.extend(check_paged_walk(
+                    ps=ps, Sq=sq, ppb=ppb, label=f"pallas ({why})",
+                    windowed=windowed))
     for arch, cfg, dt in geoms:
         for ps in PAGE_SIZES:
             findings.extend(_check_pool_layout(arch, cfg, dt, ps))

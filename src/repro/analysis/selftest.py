@@ -3,7 +3,7 @@ exists for (DESIGN.md §12).
 
 A static analyzer that silently stops finding anything is worse than no
 analyzer.  Each case here plants one representative defect — an
-off-by-one index map, a missing lens clamp, a deleted sharding rule, a
+off-by-one page copy, a missing lens clamp, a deleted sharding rule, a
 mistabled fold role, a double-free, a use-after-free, an unannotated
 host sync, a blanket suppression, an undocumented metric, a dropped
 ``donate_argnums``, a weight baked into an executable as a constant, a
@@ -21,7 +21,6 @@ synthetic repo in a temp dir, and the ledger cases drive a real (tiny)
 """
 from __future__ import annotations
 
-import functools
 import os
 import tempfile
 import textwrap
@@ -31,29 +30,29 @@ Case = Tuple[str, Callable[[], bool]]      # (name, returns True if caught)
 
 
 def _kernel_off_by_one() -> bool:
-    import jax.numpy as jnp
-    from repro.analysis.kernelcheck import check_paged_index_maps
-    from repro.kernels import paged_attention as pa
+    import numpy as np
+    from repro.analysis.kernelcheck import check_paged_walk
 
-    def bad_map(b, p, pages_s, lens_s, win_s, layer_s, *, Sq, ps):
-        p_eff = jnp.minimum(p + 1, (lens_s[b] + Sq - 1) // ps)  # off by one
-        return (layer_s[0], pages_s[b, p_eff], 0, 0)
+    def bad_page(b, slot, pages_s, layer_s):         # off by one
+        return layer_s[0], pages_s[b, np.minimum(slot + 1,
+                                                 pages_s.shape[1] - 1)]
 
-    f = check_paged_index_maps(
-        kv_map=functools.partial(bad_map, Sq=1, ps=8), ps=8, Sq=1,
-        label="selftest")
+    f = check_paged_walk(page_fn=bad_page, ps=8, Sq=1, ppb=3,
+                         label="selftest")
     return any("wrong page" in x.message for x in f)
 
 
 def _kernel_missing_clamp() -> bool:
-    from repro.analysis.kernelcheck import check_paged_index_maps
+    from repro.analysis.kernelcheck import check_paged_walk
+    from repro.kernels import paged_attention as pa
 
-    def bad_map(b, p, pages_s, lens_s, win_s, layer_s, *, Sq, ps):
-        return (layer_s[0], pages_s[b, p], 0, 0)    # reads past lens
+    def bad_block(b, j, lens_s, win_s):              # whole blocks, past lens
+        first, _ = pa.walk_bounds(lens_s[b], win_s[0], Sq=1, ps=8,
+                                  windowed=True)
+        return first + j * 3, 3
 
-    f = check_paged_index_maps(
-        kv_map=functools.partial(bad_map, Sq=1, ps=8), ps=8, Sq=1,
-        label="selftest")
+    f = check_paged_walk(block_fn=bad_block, ps=8, Sq=1, ppb=3,
+                         label="selftest")
     return any("past-lens" in x.message for x in f)
 
 
@@ -251,7 +250,7 @@ def _compiled_shape_leak() -> bool:
 
 
 CASES: List[Case] = [
-    ("kernel/off-by-one-index-map", _kernel_off_by_one),
+    ("kernel/off-by-one-page", _kernel_off_by_one),
     ("kernel/missing-lens-clamp", _kernel_missing_clamp),
     ("kernel/encoded-grid-overrun", _encoded_overrun),
     ("shard/unruled-large-leaf", _shard_unruled_leaf),

@@ -8,25 +8,34 @@ whole table width every step, regardless of the actual ``lens`` — exactly
 the memory-traffic ceiling TMA/Digital-Neuron identify once multiplication
 is cheap.  This kernel instead walks each sequence's page chain directly:
 
-  * grid ``(B, max_seq_pages)`` with the page axis innermost; the softmax
-    statistics ``(m, l, acc)`` live in VMEM scratch and are revisited
-    across page blocks (same pattern as the flash and encoded kernels);
-  * the page table, ``lens`` and the layer index are scalar-prefetched,
-    so the K/V block index maps resolve ``(layer, pages[b, p])`` *before*
-    the body runs — K/V pages stream HBM→VMEM one page at a time straight
-    out of the stage's stacked pool, and neither the dense gathered view
-    nor a per-layer slice of the pool is ever materialized;
-  * pools are lane-dense, ``(L, n_pages, page_size, n_kv·Dp)``: a page
-    block is a ``(page_size, n_kv·Dp)`` tile in the TPU's default layout,
-    and the body takes each head's ``Dp`` lanes as a static slice in VMEM;
-  * per-row early exit: blocks past ``lens[b] // page_size`` clamp their
-    index map to the last needed page (no new DMA is issued for a
-    repeated block) and skip compute via ``pl.when`` — a slot at 40
-    cached tokens touches 3 pages of a 1024-token-wide table, not 64;
-  * window-aware start: under a sliding window the walk starts at the
-    page of the oldest key the row's earliest query may see
-    (``first_block``); earlier blocks clamp to it the same way, so a
-    window layer reads its window's pages, not the whole context;
+  * grid ``(B,)``, one row a grid step; inside it a ``fori_loop`` walks
+    the row's needed pages only, from the window's first page
+    (``first_block``; slot 0 without a window) to the page of its last
+    query, in compute blocks of ``ppb`` pages (``pages_per_block``: 128
+    keys, so each head's score tile is one full lane row, cut only where
+    the buffers would not fit the scoped VMEM).  No grid step and no
+    copy is spent on a table slot outside that range: a row at 40
+    cached tokens reads 3 pages of a 1024-token-wide table, a window
+    layer its window's pages;
+  * the pools stay in HBM; the page table, ``lens``, the window and the
+    layer index are scalar-prefetched, and the kernel copies each live
+    page ``pool[layer, pages[b, slot]]`` (and, for quantized pools, the
+    aligned block of scale rows holding its own) into a double buffer in
+    VMEM: block j+1's copies, or the next row's first block's, start
+    before block j computes, so the copies hide under the compute.  The
+    dense gathered view is never materialized, nor a per-layer slice of
+    a pool whose rows are whole 128-lane rows (``whole_lane_rows``);
+  * pools are lane-dense, ``(L, n_pages, page_size, n_kv·Dp)``: a page is
+    a ``(page_size, n_kv·Dp)`` tile in the TPU's default layout, and the
+    body takes each head's ``Dp`` lanes of the block as a static slice
+    in VMEM, one ``(R, D)·(D, ppb·page_size)`` score dot and one
+    ``(R, ppb·page_size)·(ppb·page_size, D)`` value dot a kv head a
+    block;
+  * zero by select: a block's slots past the row's last page are never
+    copied and hold whatever the buffer held, a page's unwritten tail
+    whatever the pool held (NaN included), so their scores are replaced
+    by a select, and their values by a select to 0 — never multiplied
+    by a masked weight, since 0·NaN is NaN;
   * grouped GQA layout and f32 accumulation mirror the dense ``mha`` op
     order (q scaled in storage dtype, logits/softcap/mask/softmax in f32)
     so greedy decode stays token-identical to the gather path;
@@ -116,56 +125,113 @@ def _softcap(s, cap):
 
 
 # ---------------------------------------------------------------------------
-# BlockSpec index maps (module level so analysis/kernelcheck.py can evaluate
-# exactly the functions the kernel traces — not a re-derivation of them).
-# Scalar-prefetch signature: (b, p, pages_s, lens_s, win_s, layer_s); Sq/ps
-# are bound by functools.partial at call-site.
+# The walk: which pool pages each compute block reads.  Module level so
+# analysis/kernelcheck.py evaluates exactly the functions the kernel calls,
+# not a re-derivation of them.  The scalars are the kernel's scalar
+# prefetch, pages_s (B, P), lens_s (B,), win_s (1,) and layer_s (2,), the
+# value and the scale pools' layer; arrays index the same way.
 # ---------------------------------------------------------------------------
 
+# VMEM the double-buffered K and V blocks may take: half the 16 MiB of
+# scoped VMEM a v5e gives a kernel by default, the rest left to the q and
+# output blocks, the softmax scratch and the compiler's temporaries
+KV_VMEM_BYTES = 8 << 20
+LANES = 128                          # a TPU vreg row, and its copy unit
+
+
+def pages_per_block(ps: int, page_bytes: int) -> int:
+    """Pages a compute block holds: ``LANES`` keys, so each head's score
+    tile is one full lane row, cut only where two buffers of K and V
+    blocks would pass ``KV_VMEM_BYTES``.  ``page_bytes``: one page of
+    one pool in VMEM, its block of scale rows included."""
+    return max(1, min(LANES // ps, KV_VMEM_BYTES // (4 * page_bytes)))
+
+
+def page_vmem_bytes(ps: int, lanes: int, dtype, scale_lanes: int,
+                    rows: int) -> int:
+    """One page of one pool in VMEM: its ``(ps, lanes)`` values and, for
+    a quantized pool, the block of ``rows`` scale rows of
+    ``scale_lanes`` copied with it (0 for a dense pool)."""
+    return ps * lanes * jnp.dtype(dtype).itemsize + rows * scale_lanes * 4
+
+
+def whole_lanes(width: int) -> int:
+    """``width`` rounded up to whole 128-lane rows."""
+    return -(-width // LANES) * LANES
+
+
+def whole_lane_rows(pool, layer):
+    """``(pool, layer)`` as the kernel copies from them.  A TPU copies a
+    slice of an array only in whole 128-lane rows, so a pool whose rows
+    are narrower (few kv heads, or scale rows of ``n_kv·page_size``
+    lanes) comes as layer ``layer`` alone, its rows padded to whole
+    lanes (a copy of that layer), and is read at layer 0; a pool of
+    whole rows, as every stacked pool of 128·k lanes, is read in
+    place."""
+    width = pool.shape[-1]
+    if width % LANES == 0:
+        return pool, layer
+    one = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=True)
+    pad = [(0, 0)] * (pool.ndim - 1) + [(0, whole_lanes(width) - width)]
+    return jnp.pad(one, pad), jnp.int32(0)
+
+
 def first_block(lens_b, win, ps: int):
-    """The page block of the oldest key that query ``lens_b`` (a row's
+    """The table slot of the oldest key that query ``lens_b`` (a row's
     earliest query) may see under a window of ``win`` keys
-    (``q_pos - k_pos < win``): blocks before it hold no key any query of
+    (``q_pos - k_pos < win``): pages before it hold no key any query of
     the row attends.  0 with ``_NO_WINDOW``."""
     return jnp.maximum(0, (lens_b - win + 1) // ps)
 
 
-def _walk_block(p, b, lens_s, win_s, *, Sq, ps, windowed):
-    """The page block grid cell (b, p) reads: p itself inside the walk,
-    else the nearest block of it — the last holding a query's position
-    or, ``windowed`` only, the first inside the window."""
-    last = (lens_s[b] + Sq - 1) // ps
-    if not windowed:
-        return jnp.minimum(p, last)
-    return jnp.clip(p, first_block(lens_s[b], win_s[0], ps), last)
+def walk_bounds(lens_b, win, *, Sq: int, ps: int, windowed: bool):
+    """(first, last): the table slots of the first and the last page a
+    row with ``lens_b`` cached keys needs.  ``windowed`` is static: a
+    model with no sliding window compiles the walk from slot 0, without
+    the window's arithmetic."""
+    last = (lens_b + Sq - 1) // ps
+    first = first_block(lens_b, win, ps) if windowed else 0
+    return first, last
 
 
-def paged_kv_block_map(b, p, pages_s, lens_s, win_s, layer_s, *, Sq, ps,
-                       windowed=False):
-    """Stacked K/V pool block index for grid cell (b, p): layer
-    ``layer_s[0]``, the page id holding page block p of row b.  Blocks
-    past the last query's position — and, ``windowed``, before the
-    window's first block — clamp to the nearest needed page, so the index
-    map repeats and no new DMA is issued for blocks the kernel body skips
-    via ``pl.when``.  ``windowed`` is static: a model with no sliding
-    window compiles the walk without the window's arithmetic."""
-    p_eff = _walk_block(p, b, lens_s, win_s, Sq=Sq, ps=ps,
-                        windowed=windowed)
-    return (layer_s[0], pages_s[b, p_eff], 0, 0)
+def n_blocks(lens_b, win, *, Sq: int, ps: int, ppb: int, windowed: bool):
+    """Compute blocks of ``ppb`` pages that cover the row's needed slots
+    ``first..last``: at least one."""
+    first, last = walk_bounds(lens_b, win, Sq=Sq, ps=ps, windowed=windowed)
+    return (last - first) // ppb + 1
 
 
-def paged_scale_block_map(b, p, pages_s, lens_s, win_s, layer_s, *, Sq, ps,
-                          rows, windowed=False):
-    """Same page clamp for the (L, n_pages, Hkv·ps) f32 scale side pools of
-    quantized KV modes (DESIGN.md §11): the block of ``rows`` page rows
-    (``SCALE_ROWS``) holding the page's scales, which stream with its
-    value page."""
-    p_eff = _walk_block(p, b, lens_s, win_s, Sq=Sq, ps=ps,
-                        windowed=windowed)
-    return (layer_s[0], pages_s[b, p_eff] // rows, 0)
+def block_pages(b, j, lens_s, win_s, *, Sq: int, ps: int, ppb: int,
+                windowed: bool = False):
+    """Compute block j of row b: ``(slot0, n_live)``, its first table
+    slot ``first + j·ppb`` (the walk starts at the window's first page
+    itself, not at a block boundary of the table) and how many of its
+    ``ppb`` slots are live, at or before the row's last needed page.
+    The kernel copies the live slots ``slot0 .. slot0 + n_live − 1``
+    only; the rest of the block's buffer holds whatever it held."""
+    first, last = walk_bounds(lens_s[b], win_s[0], Sq=Sq, ps=ps,
+                              windowed=windowed)
+    slot0 = first + j * ppb
+    return slot0, jnp.minimum(ppb, last - slot0 + 1)
 
 
-def paged_q_block_map(b, p, *_):
+def pool_page(b, slot, pages_s, layer_s):
+    """The ``(layer, page)`` value-pool index the copy of row b's table
+    slot ``slot`` reads (``layer_s``: the value pools' layer, then the
+    scale pools')."""
+    return layer_s[0], pages_s[b, slot]
+
+
+def scale_rows_start(page, rows: int):
+    """First row of the ``rows`` scale-pool rows copied with ``page``:
+    the aligned block that holds the page's row.  A TPU copies whole
+    8-row tiles at tile-aligned offsets only, and lays the page axis out
+    in whole tiles, so the block of the last pages may read past
+    ``n_pages`` into the tile padding."""
+    return pl.multiple_of(page // rows * rows, rows)
+
+
+def paged_q_block_map(b, *_):
     """Folded q / output block index: row b, whole (Hkv, Sq·G, D) block."""
     return (b, 0, 0, 0)
 
@@ -174,72 +240,127 @@ def paged_q_block_map(b, p, *_):
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(pages_s, lens_s, win_s, layer_s, q_ref, k_ref, v_ref,
-                   *rest, ps, n_pb, cap, G, Sq, Dp, rows, mode="bf16",
+def _decode_kernel(pages_s, lens_s, win_s, layer_s, q_ref, k_hbm, v_hbm,
+                   *rest, ps, ppb, cap, G, Sq, Dp, rows, mode="bf16",
                    windowed=False):
-    if mode == "bf16":
-        sk_ref = sv_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
-    else:                            # quantized pools: scale-row refs ride
-        sk_ref, sv_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    quant = mode != "bf16"
+    if quant:                        # quantized pools: scale rows ride along
+        (sk_hbm, sv_hbm, o_ref, kbuf, vbuf, skbuf, svbuf, sems, slot_ref,
+         m_ref, l_ref, acc_ref) = rest
+        pools = ((k_hbm, kbuf), (v_hbm, vbuf), (sk_hbm, skbuf),
+                 (sv_hbm, svbuf))
+    else:
+        o_ref, kbuf, vbuf, sems, slot_ref, m_ref, l_ref, acc_ref = rest
+        pools = ((k_hbm, kbuf), (v_hbm, vbuf))
     b = pl.program_id(0)
-    p = pl.program_id(1)
+    walk = dict(Sq=Sq, ps=ps, ppb=ppb, windowed=windowed)
 
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def fetch(row, j, slot, op):
+        """Start (``op='start'``) or wait for the copies of block j of
+        ``row`` into buffer ``slot``: per live page its K and V page and,
+        quantized, the block of scale rows that holds its own."""
+        slot0, n_live = block_pages(row, j, lens_s, win_s, **walk)
 
+        def page(i, carry):
+            lyr, pid = pool_page(row, slot0 + i, pages_s, layer_s)
+            at = [(lyr, pid)] * 2
+            if quant:                # the scale pools' own layer index
+                at += [(layer_s[1], pl.ds(scale_rows_start(pid, rows),
+                                          rows))] * 2
+            for (hbm, buf), a in zip(pools, at):
+                cp = pltpu.make_async_copy(hbm.at[a], buf.at[slot, i],
+                                           sems.at[slot])
+                if op == "start":
+                    cp.start()
+                else:
+                    cp.wait()
+            return carry
+        jax.lax.fori_loop(0, n_live, page, 0)
+
+    # the grid runs the rows in order on one core: row 0 fetches its own
+    # first block, every later row finds it fetched by the row before
+    @pl.when(b == 0)
+    def _first_fetch():
+        slot_ref[0] = 0
+        fetch(0, 0, 0, "start")
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
     ln = lens_s[b]                   # tokens already cached for this row
-    nb = (ln + Sq - 1) // ps + 1     # page blocks holding positions <= ln+Sq-1
-    walk = p < nb
-    if windowed:                     # from the window's first block
-        walk = walk & (p >= first_block(ln, win_s[0], ps))
+    nblk = n_blocks(ln, win_s[0], Sq=Sq, ps=ps, ppb=ppb, windowed=windowed)
+    slot_first = slot_ref[0]
+    f32 = jnp.float32
+    hkv, R, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]  # R = Sq·G
+    W = ppb * ps                     # keys a block
 
-    @pl.when(walk)
-    def _block():
-        f32 = jnp.float32
-        hkv, R = q_ref.shape[1], q_ref.shape[2]      # R = Sq·G query rows
+    def block(j, _):
+        slot = (slot_first + j) % 2
+        nxt = 1 - slot
+
+        # block j+1, or the next row's first block, lands while j computes
+        last_blk = j + 1 == nblk
+
+        @pl.when(~last_blk | (b + 1 < pl.num_programs(0)))
+        def _():
+            fetch(jnp.where(last_blk, b + 1, b),
+                  jnp.where(last_blk, 0, j + 1), nxt, "start")
+
+        fetch(b, j, slot, "wait")
+        slot0, n_live = block_pages(b, j, lens_s, win_s, **walk)
         # row r = s·G + g is query s of group g; query s sits at ln + s
-        t = p * ps + jax.lax.broadcasted_iota(jnp.int32, (R, ps), 1)
-        rq = jax.lax.broadcasted_iota(jnp.int32, (R, ps), 0)
+        t = slot0 * ps + jax.lax.broadcasted_iota(jnp.int32, (R, W), 1)
+        rq = jax.lax.broadcasted_iota(jnp.int32, (R, W), 0)
         if G > 1:
             rq = rq // G
         d = (ln + rq) - t                            # q_pos - k_pos
         ok = (d >= 0) & (d < win_s[0])
-        if sk_ref is not None:
-            # the page's head-major scale row out of its block of rows
-            # (an aligned load, a select and a sublane sum: exact)
-            r = pages_s[b, p] % rows
-            hit = jax.lax.broadcasted_iota(jnp.int32, sk_ref.shape, 0) == r
-            sk_row = jnp.where(hit, sk_ref[...], 0.0).sum(0, keepdims=True)
-            sv_row = jnp.where(hit, sv_ref[...], 0.0).sum(0, keepdims=True)
+        # keys up to the last query's position; the rest of the block (a
+        # page's unwritten tail, slots past the last page that no copy
+        # filled) may hold anything, NaN included
+        key_ok = (slot0 * ps + jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0)
+                  < ln + Sq)
+        if quant:
+            # each page's head-major scale row out of its block of rows
+            # (an aligned load, a select and a sublane sum: exact); dead
+            # slots take the last live page's id, their rows unused
+            hit = jax.lax.broadcasted_iota(jnp.int32, skbuf.shape[2:], 0)
+            pids = [pool_page(b, slot0 + jnp.minimum(i, n_live - 1),
+                              pages_s, layer_s)[1] for i in range(ppb)]
+            r = [pid - scale_rows_start(pid, rows) for pid in pids]
+            sk_rows = [jnp.where(hit == r[i], skbuf[slot, i], 0.0)
+                       .sum(0, keepdims=True) for i in range(ppb)]
+            sv_rows = [jnp.where(hit == r[i], svbuf[slot, i], 0.0)
+                       .sum(0, keepdims=True) for i in range(ppb)]
             eye = (jax.lax.broadcasted_iota(jnp.int32, (ps, ps), 0)
                    == jax.lax.broadcasted_iota(jnp.int32, (ps, ps), 1))
 
-        def scale_col(row, h):
-            # head h's ps lanes of a (1, Hkv·ps) scale row as a per-token
-            # (ps, 1) column: a select and a lane sum, exact
-            seg = row[:, h * ps:(h + 1) * ps]
-            return jnp.where(eye, seg, 0.0).sum(-1, keepdims=True)
+        def scale_cols(sc_rows, h):
+            # head h's ps lanes of each page's (1, Hkv·ps) scale row as
+            # per-token (ps, 1) columns: a select and a lane sum, exact
+            if not quant:
+                return None
+            return jnp.stack([
+                jnp.where(eye, row[:, h * ps:(h + 1) * ps], 0.0)
+                .sum(-1, keepdims=True) for row in sc_rows])  # (ppb, ps, 1)
 
-        # one kv head at a time: its (ps, Dp) lanes of the lane-dense
-        # page block, 2-D contractions only — no in-kernel reshape/transpose
+        # one kv head at a time: its (ppb, ps, Dp) lanes of the block's
+        # lane-dense pages, 2-D contractions only
         for h in range(hkv):
             lanes = slice(h * Dp, (h + 1) * Dp)
             # in-loop dequant (DESIGN.md §11): quantized pools stream
             # their narrow bytes HBM→VMEM and widen to f32 here
-            k = _dequant_block(
-                k_ref[0, :, lanes],
-                None if sk_ref is None else scale_col(sk_row, h), mode)
-            v = _dequant_block(
-                v_ref[0, :, lanes],
-                None if sv_ref is None else scale_col(sv_row, h), mode)
+            k = _dequant_block(kbuf[slot, :, :, lanes],
+                               scale_cols(sk_rows if quant else None, h),
+                               mode).reshape(W, D)
+            v = _dequant_block(vbuf[slot, :, :, lanes],
+                               scale_cols(sv_rows if quant else None, h),
+                               mode).reshape(W, D)
+            v = jnp.where(key_ok, v, 0.0)    # a select: 0·NaN is NaN
             q = q_ref[0, h].astype(f32)                       # (R, D)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     precision=ATTN_PRECISION,
-                                    preferred_element_type=f32)  # (R, ps)
+                                    preferred_element_type=f32)  # (R, W)
             s = jnp.where(ok, _softcap(s, cap), NEG_INF)
             m_prev = m_ref[h]                                 # (R, 1)
             m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
@@ -249,11 +370,12 @@ def _decode_kernel(pages_s, lens_s, win_s, layer_s, q_ref, k_ref, v_ref,
             acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
                 pexp, v, precision=ATTN_PRECISION, preferred_element_type=f32)
             m_ref[h] = m_new
+        return 0
 
-    @pl.when(p == n_pb - 1)
-    def _finalize():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = out.astype(o_ref.dtype)
+    jax.lax.fori_loop(0, nblk, block, 0)
+    slot_ref[0] = (slot_first + nblk) % 2
+    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 def fold_q(q, scale: float, G: int):
@@ -291,51 +413,66 @@ def paged_attn_pallas(q, pool_k, pool_v, pages, lens, window, layer, *,
     and scales stream together and widen in-loop (DESIGN.md §11)."""
     B, S, Hq, D = q.shape
     Hkv = Hq // G
-    ps, HDp = pool_k.shape[2], pool_k.shape[3]
+    n_pages, ps, HDp = pool_k.shape[1:]
     Dp = HDp // Hkv
     mode = kv_mode_of(pool_k)        # static: dtype is a trace constant
-    n_pb = pages.shape[1]
+    quant = mode != "bf16"
     R = S * G
     win = jnp.asarray(window, jnp.int32).reshape(1)
-    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
-
-    rows = min(SCALE_ROWS, pool_k.shape[1])  # page rows per scale block
-    page_idx = functools.partial(paged_kv_block_map, Sq=S, ps=ps,
-                                 windowed=windowed)
-    scale_idx = functools.partial(paged_scale_block_map, Sq=S, ps=ps,
-                                  rows=rows, windowed=windowed)
-
-    kern = functools.partial(_decode_kernel, ps=ps, n_pb=n_pb, cap=cap, G=G,
+    layer = jnp.asarray(layer, jnp.int32)
+    rows = min(SCALE_ROWS, n_pages)  # page rows per scale block
+    pool_k, lyr_kv = whole_lane_rows(pool_k, layer)
+    pool_v, _ = whole_lane_rows(pool_v, layer)
+    lyr_sc = layer
+    if quant:
+        scale_k, lyr_sc = whole_lane_rows(scale_k, layer)
+        scale_v, _ = whole_lane_rows(scale_v, layer)
+        if interpret and n_pages % rows:
+            # the TPU's tile padding of the page axis, which the block of
+            # the last pages reads (``scale_rows_start``)
+            pad = ((0, 0), (0, -n_pages % rows), (0, 0))
+            scale_k, scale_v = jnp.pad(scale_k, pad), jnp.pad(scale_v, pad)
+    lanes = pool_k.shape[-1]
+    sc_lanes = scale_k.shape[-1] if quant else 0
+    ppb = pages_per_block(ps, page_vmem_bytes(ps, lanes, pool_k.dtype,
+                                              sc_lanes, rows))
+    kern = functools.partial(_decode_kernel, ps=ps, ppb=ppb, cap=cap, G=G,
                              Sq=S, Dp=Dp, rows=rows, mode=mode,
                              windowed=windowed)
-    in_specs = [
-        pl.BlockSpec((1, Hkv, R, D), paged_q_block_map),
-        pl.BlockSpec((None, 1, ps, HDp), page_idx),
-        pl.BlockSpec((None, 1, ps, HDp), page_idx),
-    ]
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)  # copied by hand
+    in_specs = [pl.BlockSpec((1, Hkv, R, D), paged_q_block_map), hbm, hbm]
     operands = [fold_q(q, scale, G), pool_k, pool_v]
-    if mode != "bf16":
-        in_specs += [pl.BlockSpec((None, rows, Hkv * ps), scale_idx),
-                     pl.BlockSpec((None, rows, Hkv * ps), scale_idx)]
+    # double buffers: block j+1's pages land while block j computes
+    scratch = [pltpu.VMEM((2, ppb, ps, lanes), pool_k.dtype),
+               pltpu.VMEM((2, ppb, ps, lanes), pool_v.dtype)]
+    if quant:
+        in_specs += [hbm, hbm]
         operands += [scale_k, scale_v]
+        scratch += [pltpu.VMEM((2, ppb, rows, sc_lanes), jnp.float32)] * 2
+    scratch += [
+        pltpu.SemaphoreType.DMA((2,)),         # one a buffer slot
+        pltpu.SMEM((1,), jnp.int32),           # slot of the row's block 0
+        pltpu.VMEM((Hkv, R, 1), jnp.float32),
+        pltpu.VMEM((Hkv, R, 1), jnp.float32),
+        pltpu.VMEM((Hkv, R, D), jnp.float32),
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B, n_pb),
+        grid=(B,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Hkv, R, D), paged_q_block_map),
-        scratch_shapes=[
-            pltpu.VMEM((Hkv, R, 1), jnp.float32),
-            pltpu.VMEM((Hkv, R, 1), jnp.float32),
-            pltpu.VMEM((Hkv, R, D), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), q.dtype),
+        # each row prefetches the next row's first block: rows in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attn_pallas",        # the device op's name in a trace
-    )(pages, lens, win, lyr, *operands)
+    )(pages, lens, win, jnp.stack([lyr_kv, lyr_sc]), *operands)
     return unfold_q(out, S, G)
 
 

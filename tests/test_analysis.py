@@ -12,7 +12,7 @@ import pytest
 
 from repro.analysis.kernelcheck import (check_blocked_lowering,
                                         check_encoded_maps,
-                                        check_paged_index_maps)
+                                        check_paged_walk)
 from repro.analysis.ledger import LedgerError, sanitize_enabled
 from repro.analysis.lint import registered_rules, repo_root, run_lint
 from repro.analysis.selftest import CASES, run_selftest
@@ -70,57 +70,65 @@ def test_metric_docs_sync_round_trip():
 # ---------------------------------------------------------------------------
 
 def test_real_index_maps_are_sound():
-    for ps, sq in ((8, 1), (16, 5)):
+    """The kernel's walk at the block sizes it picks for 8- and 16-token
+    pages (16 and 8 pages a block) and an odd one, decode and verify
+    shapes, with and without a window."""
+    for ps, sq, ppb in ((8, 1, 16), (16, 5, 8), (16, 1, 3)):
         for windowed in (False, True):
-            assert check_paged_index_maps(ps=ps, Sq=sq,
-                                          windowed=windowed) == []
+            assert check_paged_walk(ps=ps, Sq=sq, ppb=ppb,
+                                    windowed=windowed) == []
 
 
 def test_off_by_one_index_map_is_caught():
-    import functools
-    import jax.numpy as jnp
+    """A copy that reads the next slot's page."""
+    def bad(b, slot, pages, layer):
+        return layer[0], pages[b, np.minimum(slot + 1, pages.shape[1] - 1)]
 
-    def bad(b, p, pages_s, lens_s, win_s, layer_s, *, Sq, ps):
-        p_eff = jnp.minimum(p + 1, (lens_s[b] + Sq - 1) // ps)
-        return (layer_s[0], pages_s[b, p_eff], 0, 0)
-
-    f = check_paged_index_maps(
-        kv_map=functools.partial(bad, Sq=1, ps=8), ps=8, Sq=1)
+    f = check_paged_walk(page_fn=bad, ps=8, Sq=1, ppb=3)
     assert any("wrong page" in x.message for x in f)
 
 
 def test_missing_lens_clamp_is_caught():
-    f = check_paged_index_maps(
-        kv_map=lambda b, p, pages, lens, win, layer: (layer[0], pages[b, p],
-                                                      0, 0),
-        ps=8, Sq=1)
+    """Blocks copied whole, past the row's last needed page."""
+    from repro.kernels import paged_attention as pa
+
+    def whole(b, j, lens, win):
+        first, _ = pa.walk_bounds(lens[b], win[0], Sq=1, ps=8,
+                                  windowed=True)
+        return first + j * 3, 3
+    f = check_paged_walk(block_fn=whole, ps=8, Sq=1, ppb=3)
     assert any("past-lens" in x.message for x in f)
 
 
 def test_missing_window_start_is_caught():
-    """A map that walks from page 0 under a window reads blocks before
+    """A walk that starts at slot 0 under a window copies pages before
     the window's first page."""
-    import jax.numpy as jnp
+    from repro.kernels import paged_attention as pa
 
-    def from_zero(b, p, pages, lens, win, layer):
-        return (layer[0], pages[b, jnp.minimum(p, lens[b] // 8)], 0, 0)
-    f = check_paged_index_maps(kv_map=from_zero, ps=8, Sq=1)
+    def from_zero(b, j, lens, win):
+        return pa.block_pages(b, j, lens, win, Sq=1, ps=8, ppb=3,
+                              windowed=False)
+    f = check_paged_walk(block_fn=from_zero, ps=8, Sq=1, ppb=3)
     assert any("before-window" in x.message for x in f)
 
 
 def test_wrong_layer_index_map_is_caught():
-    """A map that ignores the layer scalar reads layer 0's pages."""
-    import functools
+    """A copy that ignores the layer scalar reads layer 0's pages."""
     from repro.kernels import paged_attention as pa
 
-    def layer0(b, p, pages_s, lens_s, win_s, layer_s, *, Sq, ps):
-        _, page, _, _ = pa.paged_kv_block_map(b, p, pages_s, lens_s, win_s,
-                                               layer_s, Sq=Sq, ps=ps)
-        return (0, page, 0, 0)
+    def layer0(b, slot, pages, layer):
+        return 0, pa.pool_page(b, slot, pages, layer)[1]
 
-    f = check_paged_index_maps(
-        kv_map=functools.partial(layer0, Sq=1, ps=8), ps=8, Sq=1)
-    assert any("kv map returned" in x.message for x in f)
+    f = check_paged_walk(page_fn=layer0, ps=8, Sq=1, ppb=3)
+    assert any("wrong layer" in x.message for x in f)
+
+
+def test_unaligned_scale_rows_are_caught():
+    """A scale copy that starts at the page's own row: a TPU copies
+    whole 8-row tiles at aligned offsets only."""
+    f = check_paged_walk(scale_fn=lambda page, rows: page, ps=16, Sq=1,
+                         ppb=8)
+    assert any("aligned" in x.message for x in f)
 
 
 def test_blocked_lowering_is_in_bounds():

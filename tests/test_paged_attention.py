@@ -15,9 +15,11 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
-from repro.kernels.paged_attention import paged_attn, gqa_group
+from repro.kernels.paged_attention import (block_pages, gqa_group, n_blocks,
+                                           paged_attn, pages_per_block)
 from repro.models import init_model
-from repro.nn.paged import gather_kv, paged_attn_decode
+from repro.nn.paged import gather_kv, gather_kv_dequant, paged_attn_decode
+from repro.quant.kvcache import quantize_kv, stored_layout
 from repro.serve import Engine, generate
 
 
@@ -200,6 +202,77 @@ def test_op_kquery_sweep(backend, S, ps, Hq, Hkv, window, cap):
                                rtol=3e-5, atol=3e-6)
 
 
+@pytest.mark.parametrize("G", [1, 8])
+@pytest.mark.parametrize("S", [1, 5])
+@pytest.mark.parametrize("kv", ["float32", "bfloat16", "int8", "int4"])
+def test_block_walk_matches_gather_reference(kv, S, G):
+    """The kernel's walk in compute blocks of ``ppb`` 16-token pages
+    (8 here: 128 keys) against the gather reference: rows of different
+    lengths in one batch, at ``ppb·ps − 1``, ``ppb·ps`` and ``ppb·ps + 1``
+    keys, one page, and three blocks; 2 kv heads of 64, so the values
+    are read in place and int4's 64-lane pages and every pool's 32-lane
+    scale rows come padded to whole lanes; G = 8 under a 100-key window,
+    whose first page falls mid-block (slot 1 of a 129-key row, 12 of a
+    296-key one).  Every page no row needs (the scratch page 0, the
+    table's tail, pages before a window) holds NaN: no block may copy
+    them, and slots past a row's last page, which no copy fills, must
+    count for exactly nothing."""
+    rng = np.random.default_rng(hash((kv, S, G)) % 2 ** 32)
+    ps, Hkv, D, P = 16, 2, 64, 20    # values in whole 128-lane rows
+    Hq = Hkv * G
+    window = 100 if G > 1 else None
+    blk = 8 * ps
+    lens_np = np.asarray([blk - 1, blk, blk + 1, 3, 2 * blk + 40], np.int32)
+    B = len(lens_np)
+    _, _, _, pages, kv_map = _pool_case(rng, B, Hq, Hkv, D, ps, P)
+    n_pages = 1 + B * P
+    # the pages some row needs; the rest, scratch page included, is NaN
+    need = np.zeros(n_pages, bool)
+    for b, ln in enumerate(lens_np):
+        first = 0 if window is None else max(0, (ln - window + 1) // ps)
+        need[np.asarray(pages)[b, first:(ln + S - 1) // ps + 1]] = True
+    assert not need[0] and not need.all()
+    shape = (N_LAYERS, n_pages, ps, Hkv, D)
+    dense = [np.where(need[None, :, None, None, None],
+                      rng.normal(size=shape), np.nan).astype(np.float32)
+             for _ in range(2)]
+    if kv in ("int8", "int4"):
+        (pk, sk), (pv, sv) = (stored_layout(*quantize_kv(jnp.asarray(x), kv))
+                              for x in dense)
+        ck, cv = (gather_kv_dequant(p, jnp.nan_to_num(s), LAYER, pages)
+                  for p, s in ((pk, sk), (pv, sv)))
+    else:
+        pk, pv = (stored_layout(jnp.asarray(x).astype(kv)) for x in dense)
+        sk = sv = None
+        ck, cv = (gather_kv(jnp.nan_to_num(p), LAYER, pages, Hkv)
+                  for p in (pk, pv))
+    assert pages_per_block(ps, ps * Hkv * D * 4) == 8
+    lens = jnp.asarray(lens_np)
+    q = jnp.asarray(rng.normal(size=(B, S, Hq, D)), jnp.float32)
+    scale = 1.0 / np.sqrt(D)
+    out = paged_attn(q, pk, pv, pages, lens, layer=LAYER, scale=scale,
+                     window=window, kv_of_q=kv_map,
+                     backend="pallas_interpret", scale_k=sk, scale_v=sv)
+    k_pos = jnp.arange(ck.shape[1])
+    ref = paged_attn_decode(q, ck, cv, kv_map, scale=scale,
+                            q_pos=lens[:, None] + jnp.arange(S)[None, :],
+                            k_pos=k_pos,
+                            k_valid=k_pos[None, :] < (lens + S)[:, None],
+                            window=window)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-6)
+    # the walk the kernel ran: a window starts mid-block; the long row
+    # takes three blocks, one under the window
+    win = np.asarray([window or 2 ** 30], np.int32)
+    walk = dict(Sq=S, ps=ps, ppb=8, windowed=window is not None)
+    starts = [int(block_pages(b, 0, lens_np, win, **walk)[0])
+              for b in range(B)]
+    assert starts == ([0] * B if window is None else [1, 1, 1, 0, 12])
+    assert int(n_blocks(lens_np[4], win[0], **walk)) == (3 if window is None
+                                                         else 1)
+
+
 def test_op_rejects_irregular_maps():
     """The fused kernel requires a uniform GQA grouping; irregular q→kv
     maps must fall back (or raise when forced)."""
@@ -241,6 +314,19 @@ def test_engine_token_identical_across_backends(qwen):
     ref = _serve(params, cfg, prompts, "xla", **kw)
     for backend in ("auto", "pallas_interpret", "blocked"):
         assert _serve(params, cfg, prompts, backend, **kw) == ref, backend
+
+
+def test_engine_token_identical_over_multi_block_walks(qwen):
+    """Rows of 130 and 250 cached keys at 8-token pages take two and
+    three compute blocks of 16 pages; greedy decode at f32 stays
+    token-identical to the gather path."""
+    cfg, params = qwen
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (130, 250, 9)]
+    kw = dict(n_slots=2, page_size=8, n_pages=96)
+    ref = _serve(params, cfg, prompts, "xla", **kw)
+    assert _serve(params, cfg, prompts, "pallas_interpret", **kw) == ref
 
 
 @pytest.mark.skipif(jax.default_backend() == "tpu",

@@ -329,6 +329,51 @@ def test_paged_attention_compiles_at_mellum_widths(one_chip, window):
     _assert_mosaic(compiled)
 
 
+def test_paged_attention_compiles_at_dense_cell_shapes(one_chip):
+    """The kernel at the dense cell's geometry (bench/traffic/
+    decode-batch.json): 48 rows, 72 table pages of 16 tokens, 16 heads
+    of 64 over the f32 stacked pool of 24 layers and 1800 pages, no
+    window; its device op keeps the name the roofline reads."""
+    B, ps, P, n_pages, L = 48, 16, 72, 1800, 24
+    f = jax.jit(lambda q, pk, pv, pg, ln, lyr: paged_attn_pallas(
+        q, pk, pv, pg, ln, jnp.int32(2 ** 30), lyr, scale=HEAD_DIM ** -0.5,
+        G=N_HEADS // N_KV, windowed=False))
+    pool = _sds((L, n_pages, ps, N_KV * HEAD_DIM), jnp.float32, one_chip)
+    with jax.default_matmul_precision("highest"):
+        compiled = f.lower(
+            _sds((B, 1, N_HEADS, HEAD_DIM), jnp.float32, one_chip), pool,
+            pool, _sds((B, P), jnp.int32, one_chip),
+            _sds((B,), jnp.int32, one_chip),
+            _sds((), jnp.int32, one_chip)).compile()
+    _assert_mosaic(compiled)
+    assert "%paged_attn_pallas." in compiled.as_text()
+
+
+@pytest.mark.parametrize("kv,n_kv", [("float32", 1), ("int8", 2),
+                                     ("int8", 4), ("int4", 2)])
+def test_paged_attention_compiles_with_narrow_lane_rows(one_chip, kv, n_kv):
+    """Pools whose rows are not whole 128-lane rows — one kv head of 64,
+    packed int4 at 2 heads, and the scale rows of 2 or 4 kv heads at
+    16-token pages (32 and 64 lanes) — over a page count that is not a
+    multiple of 8: a TPU copies only whole lane rows, so the kernel must
+    still compile."""
+    B, ps, P, n_pages, L, G = 6, 16, 24, 149, 2, 4
+    quant = kv in ("int8", "int4")
+    dtype, width = ((jnp.uint8, HEAD_DIM // 2) if kv == "int4"
+                    else (jnp.dtype(kv), HEAD_DIM))
+    f = jax.jit(lambda q, pk, pv, pg, ln, *sc: paged_attn_pallas(
+        q, pk, pv, pg, ln, jnp.int32(2 ** 30), jnp.int32(1),
+        scale=HEAD_DIM ** -0.5, G=G, windowed=False,
+        scale_k=sc[0] if sc else None, scale_v=sc[1] if sc else None))
+    pool = _sds((L, n_pages, ps, n_kv * width), dtype, one_chip)
+    args = [_sds((B, 1, n_kv * G, HEAD_DIM), jnp.float32, one_chip), pool,
+            pool, _sds((B, P), jnp.int32, one_chip),
+            _sds((B,), jnp.int32, one_chip)]
+    if quant:
+        args += [_sds((L, n_pages, n_kv * ps), jnp.float32, one_chip)] * 2
+    _assert_mosaic(f.lower(*args).compile())
+
+
 def test_mellum_decode_step_compiles_with_pinned_expert_op_name(
         one_chip, monkeypatch):
     """The donated paged decode step of the configuration at its widths
