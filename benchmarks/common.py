@@ -1,7 +1,6 @@
-"""Benchmark helpers: JSON artifact cache + timing."""
+"""Benchmark helpers: JSON artifact cache."""
 import json
 import os
-import time
 
 ART = os.path.join(os.path.dirname(__file__), "artifacts")
 
@@ -16,25 +15,3 @@ def cached(name: str, fn, force: bool = False):
     with open(path, "w") as f:
         json.dump(out, f, indent=1, default=float)
     return out
-
-
-def pct(xs, q: float) -> float:
-    """Percentile with q in [0, 100] — the repo-wide implementation
-    (repro.obs.stats: linear interpolation, matches numpy.percentile).
-    Lazy import so common.py stays usable without PYTHONPATH=src as long
-    as pct() isn't called."""
-    from repro.obs import percentile
-    return percentile(xs, q)
-
-
-def time_call(fn, *args, n: int = 10, warmup: int = 2) -> float:
-    """µs per call (after jit warmup, blocked on result)."""
-    import jax
-    for _ in range(warmup):
-        r = fn(*args)
-    jax.block_until_ready(r)
-    t0 = time.perf_counter()
-    for _ in range(n):
-        r = fn(*args)
-    jax.block_until_ready(r)
-    return (time.perf_counter() - t0) / n * 1e6

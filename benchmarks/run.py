@@ -1,15 +1,13 @@
-"""Benchmark driver — one module per paper table/figure.
+"""Paper-figure driver — one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV.  Heavy results are cached under
 benchmarks/artifacts/ (pass --force to regenerate; --only to filter).
 """
 import argparse
-import sys
 import time
 
 from . import (table1_hw, table2_accuracy, fig5_bitwidth, fig6_rmse,
-               fig7_taskspecific, latency_throughput, kernel_bench,
-               roofline_report, serving_bench)
+               fig7_taskspecific, latency_throughput)
 from .common import cached
 
 SUITES = [
@@ -19,9 +17,6 @@ SUITES = [
     ("fig7_taskspecific", fig7_taskspecific),
     ("table2_accuracy", table2_accuracy),
     ("fig5_bitwidth", fig5_bitwidth),
-    ("kernel_bench", kernel_bench),
-    ("roofline_report", roofline_report),
-    ("serving_bench", serving_bench),
 ]
 
 

@@ -1,6 +1,5 @@
-"""Compiled (post-SPMD) HLO text parsing — the shared layer under both
-``launch/dryrun.py``'s cost reports and the compiled-executable audit
-(``analysis/compiled.py``, DESIGN.md §13).
+"""Compiled (post-SPMD) HLO text parsing — the layer under the
+compiled-executable audit (``analysis/compiled.py``, DESIGN.md §13).
 
 cost_analysis() has no collective traffic — we sum tensor sizes of every
 all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute

@@ -1,6 +1,5 @@
-"""ModelConfig — one dataclass covering all assigned architecture families,
-plus the assigned input-shape sets (train_4k / prefill_32k / decode_32k /
-long_500k)."""
+"""ModelConfig — one dataclass covering all assigned architecture
+families."""
 from __future__ import annotations
 
 import dataclasses
@@ -13,22 +12,6 @@ from repro.core.layers import MacConfig
 
 def _pad_to(x: int, m: int) -> int:
     return x if m <= 1 else ((x + m - 1) // m) * m
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str              # train | prefill | decode
-
-
-SHAPES = {
-    "train_4k":    ShapeSpec("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeSpec("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k":  ShapeSpec("decode_32k", 32768, 128, "decode"),
-    "long_500k":   ShapeSpec("long_500k", 524288, 1, "decode"),
-}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,8 +121,6 @@ class ModelConfig:
     optimizer: str = "adamw"
     learning_rate: float = 3e-4
     fsdp: bool = False
-    # applicability notes (DESIGN.md §4)
-    sub_quadratic: bool = False       # runs long_500k?
 
     def __post_init__(self):
         # a configuration read from JSON gives lists; tuples keep the
@@ -190,17 +171,6 @@ class ModelConfig:
                 out.append(self.sliding_window)
         return out
 
-    def for_mesh(self, tp: int = 16, *, fsdp: Optional[bool] = None,
-                 bf16: bool = True) -> "ModelConfig":
-        """Production-execution variant: head/vocab padding for the TP axis,
-        bf16 compute, FSDP for large models."""
-        big = self.approx_params() > 4e9
-        return dataclasses.replace(
-            self, pad_heads_to=tp, vocab_pad_to=256 * (tp // 16 or 1),
-            param_dtype="bfloat16" if bf16 else self.param_dtype,
-            compute_dtype="bfloat16" if bf16 else self.compute_dtype,
-            fsdp=big if fsdp is None else fsdp)
-
     def reduced(self) -> "ModelConfig":
         """Tiny same-family config for CPU smoke tests."""
         return dataclasses.replace(
@@ -231,40 +201,3 @@ class ModelConfig:
             global_layers=tuple(g for g in self.global_layers if g < 2),
             param_dtype="float32", compute_dtype="float32",
             pad_heads_to=1, vocab_pad_to=1, fsdp=False)
-
-    def approx_params(self) -> float:
-        """Rough parameter count (for FSDP/optimizer policy decisions)."""
-        d, L = self.d_model, (self.n_layers or
-                              self.enc_layers + self.dec_layers)
-        hd = self.head_dim_r
-        attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) \
-            + self.n_heads * hd * d
-        if self.use_mla:
-            attn = d * self.q_lora_rank \
-                + self.q_lora_rank * self.n_heads * (self.qk_nope_dim
-                                                     + self.qk_rope_dim) \
-                + d * (self.kv_lora_rank + self.qk_rope_dim) \
-                + self.kv_lora_rank * self.n_heads * (self.qk_nope_dim
-                                                      + self.v_head_dim) \
-                + self.n_heads * self.v_head_dim * d
-        if self.n_experts:
-            ff_moe = 3 * d * self.d_ff_expert * (self.n_experts
-                                                 + self.n_shared_experts)
-            ff_dense = 3 * d * self.d_ff if self.first_k_dense else 0
-            ff = ff_moe  # per moe layer
-            per_layer = attn + ff
-            total = (L - self.first_k_dense) * per_layer \
-                + self.first_k_dense * (attn + ff_dense)
-        elif self.family == "xlstm":
-            di = int(self.mlstm_proj_factor * d)
-            per_layer = d * 2 * di + 3 * di * (di // max(self.n_heads, 1)) \
-                + di * d
-            total = L * per_layer
-        else:
-            ff = (3 if self.gated_mlp else 2) * d * self.d_ff
-            total = L * (attn + ff)
-            if self.family == "hybrid":
-                di = self.ssm_expand * d
-                total += L * (2 * d * di + di * d)
-        total += self.vocab_size * d * (1 if self.tie_embeddings else 2)
-        return float(total)

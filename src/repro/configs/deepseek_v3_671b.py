@@ -15,5 +15,4 @@ CONFIG = ModelConfig(
     qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
     mtp=True, mtp_weight=0.3,
     optimizer="adafactor",
-    sub_quadratic=False,
 )

@@ -12,5 +12,4 @@ CONFIG = ModelConfig(
     attn_softcap=50.0, final_softcap=30.0,
     attn_scale=0.0625,                    # 1/sqrt(query_pre_attn_scalar=256)
     sliding_window=4096, local_global_period=2,
-    sub_quadratic=False,
 )

@@ -11,5 +11,4 @@ CONFIG = ModelConfig(
     rope=True, rope_theta=10000.0, tie_embeddings=True,
     ssm_state=16, ssm_expand=2, ssm_conv=4,
     meta_tokens=128, sliding_window=2048, global_layers=(0, 15, 31),
-    sub_quadratic=True,          # SWA + SSM state → runs long_500k
 )

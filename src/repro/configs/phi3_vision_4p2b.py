@@ -1,6 +1,6 @@
 """phi-3-vision-4.2b [vlm] — 32L d3072 32H (GQA kv=32) ff8192 vocab32064,
-phi3-mini backbone + CLIP STUB (input_specs provides 256 pre-projected patch
-embeddings). [hf:microsoft/Phi-3-vision-128k-instruct]"""
+phi3-mini backbone + CLIP STUB (the caller passes 256 pre-projected patch
+embeddings as ``apply_model``'s ``img``). [hf:microsoft/Phi-3-vision-128k-instruct]"""
 from .base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -10,5 +10,4 @@ CONFIG = ModelConfig(
     act="silu", gated_mlp=True, norm="rms",
     rope=True, rope_theta=10000.0, tie_embeddings=False,
     n_patches=256,
-    sub_quadratic=False,
 )

@@ -8,5 +8,4 @@ CONFIG = ModelConfig(
     d_ff=6912, vocab_size=151936,
     act="silu", gated_mlp=True, norm="rms", qkv_bias=True,
     rope=True, rope_theta=1_000_000.0, tie_embeddings=False,
-    sub_quadratic=False,
 )

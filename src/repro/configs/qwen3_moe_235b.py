@@ -10,5 +10,4 @@ CONFIG = ModelConfig(
     rope=True, rope_theta=1_000_000.0, tie_embeddings=False,
     n_experts=128, top_k=8, norm_topk=True, router_type="softmax",
     optimizer="adafactor",
-    sub_quadratic=False,
 )

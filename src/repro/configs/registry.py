@@ -1,4 +1,4 @@
-"""--arch registry for launcher/dryrun/tests."""
+"""--arch registry for the launchers and tests."""
 from __future__ import annotations
 
 import importlib

@@ -9,5 +9,4 @@ CONFIG = ModelConfig(
     act="gelu_tanh", gated_mlp=False, norm="layer", norm_eps=1e-5,
     qkv_bias=True, attn_out_bias=True, mlp_bias=True,
     rope=True, rope_theta=999999.4, tie_embeddings=True,
-    sub_quadratic=False,
 )

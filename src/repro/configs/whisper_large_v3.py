@@ -1,5 +1,6 @@
 """whisper-large-v3 [audio] — enc-dec 32L+32L d1280 20H ff5120 vocab51866,
-conv frontend STUB (input_specs provides frame embeddings, enc_len=seq/4).
+conv frontend STUB (the caller passes frame embeddings as ``enc_x``,
+enc_len=seq/4).
 [arXiv:2212.04356; unverified]"""
 from .base import ModelConfig
 
@@ -12,5 +13,4 @@ CONFIG = ModelConfig(
     qkv_bias=True, attn_out_bias=True, mlp_bias=True,
     rope=False, tie_embeddings=True,
     enc_len_ratio=4, max_pos_embed=32768,
-    sub_quadratic=False,
 )

@@ -9,5 +9,4 @@ CONFIG = ModelConfig(
     d_ff=0, vocab_size=50304,
     norm="rms", rope=False, tie_embeddings=False,
     slstm_every=8, mlstm_proj_factor=2.0, chunk_size=256,
-    sub_quadratic=True,          # recurrent state → runs long_500k
 )

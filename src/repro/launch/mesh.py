@@ -1,4 +1,4 @@
-"""Production meshes.  A FUNCTION (not module-level constant) so importing
+"""Device meshes.  A FUNCTION (not module-level constant) so importing
 never touches jax device state.
 
 Every mesh is built with ``AxisType.Auto`` axes: sharding is propagated by
@@ -15,12 +15,6 @@ def make_mesh(shape, axes, devices=None):
     """``jax.make_mesh`` with Auto axis types (see module doc)."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
                          devices=devices)
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2, pods: int = 0,

@@ -1,8 +1,8 @@
 """Encoded-MAC drift monitor: dense-vs-encoded agreement, online.
 
-The paper's accuracy/efficiency tradeoff is measured offline by
-``benchmarks/serving_bench.py --mac encoded`` as top-1 logit agreement
-between the dense fp forward and the calibrated encoded forward.  The
+The paper's accuracy/efficiency tradeoff is measured offline as top-1
+logit agreement between the dense fp forward and the calibrated encoded
+forward (``logit_agreement``).  The
 ``DriftMonitor`` makes the same number continuously observable *while
 serving* (DESIGN.md §9): every N engine steps it replays a sample of the
 currently-resident prompts through both parameter sets and publishes the
@@ -10,10 +10,9 @@ agreement as a gauge — if the encoded path drifts from dense mid-trace
 (activation distribution shift vs the calibration stream), the gauge
 shows it without stopping the engine.
 
-``logit_agreement`` is the shared measurement; the benchmark imports it
-from here, so the online gauge and the offline BENCH number are the same
-computation by construction (parity asserted in
-``tests/test_telemetry.py``).
+``logit_agreement`` is the shared measurement, so the online gauge and
+the offline number are the same computation by construction (parity
+asserted in ``tests/test_telemetry.py``).
 
 Under speculative decoding (DESIGN.md §10) the replay is redundant work:
 every verify step already computes the dense logits at each drafted

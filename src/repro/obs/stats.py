@@ -1,9 +1,8 @@
 """Shared order statistics for serving/bench reporting.
 
 One percentile implementation for the whole repo (DESIGN.md §9):
-``Engine.stats()``, ``benchmarks/serving_bench.py``, and
-``benchmarks/common.py`` all used to carry their own nearest-rank
-variants, which disagree with each other (and with numpy) on small
+``Engine.stats()`` and the benchmark helpers all used to carry their
+own nearest-rank variants, which disagree with each other (and with numpy) on small
 samples — exactly the regime a p99 over a dozen requests lives in.
 This one linearly interpolates between closest ranks, matching
 ``numpy.percentile(..., method='linear')`` bit-for-bit (asserted in

@@ -17,8 +17,8 @@ Two serving modes:
     with repro.serve.encoded.prepare_encoded_serving first; the engine
     itself is MAC-mode agnostic.
 
-serve_step (decode) is THE lowered function for decode_* dry-run shapes:
-one new token against a KV cache of seq_len.  Caches are donated
+``make_decode_step`` is the dense decode of ``generate`` and the static
+engine: one new token against a KV cache of seq_len.  Caches are donated
 (buffer-reuse) and sequence-sharded over the model axis (DESIGN.md §5).
 
 Both engines take ``mesh=`` for tensor-parallel serving (DESIGN.md §6):
@@ -963,8 +963,8 @@ class ServeEngine:
 
     Requests are chunked into fixed batches, left-padded to the chunk's
     longest prompt, and each chunk runs ``generate`` to completion before
-    the next starts — the baseline the continuous-batching ``Engine``
-    is measured against (benchmarks/serving_bench.py).
+    the next starts.  It serves the architectures the continuous-batching
+    ``Engine`` rejects (latent attention, recurrent state).
     """
 
     def __init__(self, params, cfg, batch_slots: int = 8,

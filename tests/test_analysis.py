@@ -282,14 +282,6 @@ def test_hlo_parser_on_real_compiled_module():
     assert any(b >= 64 * 64 * 4 for _, b in consts), text[:2000]
 
 
-def test_hloparse_shim_reexports_shared_parser():
-    from repro.analysis import hlo as H
-    from repro.launch import hloparse
-    assert hloparse.collective_bytes is H.collective_bytes
-    assert hloparse.count_ops is H.count_ops
-    assert hloparse.input_output_aliases is H.input_output_aliases
-
-
 def test_compiled_audit_single_cell_clean_and_reported():
     """Every executable of the primary arch lowers clean on one device:
     donation aliased with exact shapes, zero collectives, no captures;

@@ -252,6 +252,52 @@ def test_engine_quant_tracks_dense_tokens(qwen):
         2 * 2 * cfg.n_kv_p * cfg.head_dim_r * 4)   # << dense f32 bytes
 
 
+def test_int8_pool_top1_agreement_on_trained_model():
+    """Per-position top-1 agreement of a paged prefill over the int8 pool
+    with the same prefill over the dense pool.  The model first learns a
+    deterministic token chain, so its logits have real margins: a random
+    model's argmax flips under any perturbation, and agreement would
+    measure seed luck rather than cache fidelity."""
+    from repro.serve.engine import make_paged_prefill
+    from repro.train.trainer import init_train_state, make_train_step
+    cfg = get_config("qwen1.5-0.5b").reduced()
+    V, steps = cfg.vocab_size, 150
+
+    def chain(x, n):                     # next token = (5x + 17) mod V
+        out = np.empty(n, np.int32)
+        for i in range(n):
+            out[i], x = x, (5 * x + 17) % V
+        return out
+
+    tcfg = dataclasses.replace(cfg, learning_rate=3e-3)
+    state = init_train_state(jax.random.PRNGKey(0), tcfg)
+    tstep = jax.jit(make_train_step(tcfg, total_steps=steps,
+                                    warmup=steps // 5))
+    rng = np.random.default_rng(0)
+    for _ in range(steps):
+        toks = jnp.asarray(np.stack([chain(s, 17)
+                                     for s in rng.integers(0, V, 8)]))
+        state, _ = tstep(state, {"tokens": toks[:, :-1],
+                                 "labels": toks[:, 1:]})
+    params = state["params"]
+    B, S, ps = 8, 16, 4
+    prompts = jnp.asarray(np.stack([chain(s, S)
+                                    for s in rng.integers(0, V, B)]))
+    pages = jnp.arange(1, B * S // ps + 1, dtype=jnp.int32).reshape(B, -1)
+
+    def prefill_argmax(kv_dtype):
+        c = dataclasses.replace(cfg, kv_cache_dtype=kv_dtype)
+        layers = init_paged_cache(c, 1 + B * S // ps, ps)["layers"]
+        toks, _ = jax.jit(make_paged_prefill(c))(
+            params, layers, prompts, pages, jnp.zeros((B,), jnp.int32))
+        return np.asarray(toks)
+
+    dense = prefill_argmax("bf16")
+    # the chain was learned, so the margins are real
+    assert np.mean(dense[:, :-1] == np.asarray(prompts)[:, 1:]) >= 0.9
+    assert np.mean(prefill_argmax("int8") == dense) >= 0.99
+
+
 @pytest.mark.parametrize("kv_dtype", ["int8", "int4"])
 def test_chunked_prefill_prefix_reuse_quant(qwen, kv_dtype):
     """Prefix-cache page reuse + chunked prefill over a quantized pool:

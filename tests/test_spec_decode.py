@@ -2,8 +2,9 @@
 rejection sampler (hypothesis + TV distance), greedy token identity of
 the spec-decode engine vs dense ``generate()`` across prompt lengths /
 EOS / max_new boundaries and drafters, composition with chunked prefill
-+ prefix cache + eviction (rollback leaks no pages), drafter guards, and
-the 2-fake-device mesh subprocess (slow)."""
++ prefix cache + eviction (rollback leaks no pages), drafter guards,
+the exact-circuit encoded drafter getting drafts accepted, and the
+2-fake-device mesh subprocess (slow)."""
 import dataclasses
 import os
 import subprocess
@@ -46,11 +47,14 @@ def _property(arg_sets):
     return deco
 
 from repro.configs import get_config
+from repro.core.circuits import exact_product_circuit
+from repro.core.encoding import EncodingSpec
+from repro.core.mac import EncodedMac
 from repro.core.macexec import check_drafter, count_prepared
 from repro.models import init_model
 from repro.obs import DriftMonitor
 from repro.serve import (Engine, ServeTelemetry, generate, greedy_accept,
-                         rejection_sample, req_tid)
+                         prepare_drafter, rejection_sample, req_tid)
 
 
 # ---------------------------------------------------------------------------
@@ -176,6 +180,30 @@ def test_spec_identity_with_any_drafter(qwen):
     out, eng = _serve(params, cfg, prompts, spec=3, draft_params=drafter)
     assert out == ref
     assert eng.stats()["spec_acceptance_rate"] < 0.5
+
+
+def test_exact_circuit_encoded_drafter_gets_drafts_accepted(qwen, tmp_path):
+    """The encoded drafter folded with the zero-RMSE AND-plane circuit
+    differs from the verifier only by int8 quantization, so its drafts
+    agree with the dense model's argmax far above chance; the emitted
+    tokens stay exactly the non-speculative engine's."""
+    cfg, params = qwen
+    circ, s = exact_product_circuit(cfg.mac.bits, cfg.mac.bits)
+    exact = EncodedMac.from_spec(EncodingSpec(circ, s, 0.0))
+    dp, dc, _ = prepare_drafter(
+        params, cfg, m_bits=cfg.mac.bits * 2,
+        macs_override={n: exact for n in ("wq", "wk", "wv", "wo", "wi",
+                                          "wg", "w")},
+        cache_dir=str(tmp_path), calib_batches=1, calib_batch_size=1,
+        calib_seq=8, verbose=False)
+    prompts = _prompts(cfg, (5, 12))
+    ref, _ = _serve(params, cfg, prompts, max_new=12)
+    out, eng = _serve(params, cfg, prompts, spec=4, max_new=12,
+                      draft_params=dp, draft_cfg=dc)
+    assert out == ref
+    st = eng.stats()
+    assert st["draft_mac_mode"] == "encoded_infer"
+    assert st["spec_acceptance_rate"] > 0.2
 
 
 def test_spec_max_new_and_eos_boundaries(qwen):

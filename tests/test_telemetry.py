@@ -355,6 +355,35 @@ def test_engine_trace_lifecycle_and_reconciliation(qwen):
     assert reg.counter("decode_tokens").value(mac=cfg.mac.mode) == 27
 
 
+def test_engine_trace_and_metrics_read_back_from_disk(qwen, tmp_path):
+    """What ``launch/serve.py --trace-out/--metrics-out`` writes is what a
+    reader gets: the Chrome trace re-read from disk holds the lifecycle
+    spans with phases telescoping to each request span, and the metrics
+    snapshot holds the evictions counter and step-time histogram."""
+    cfg, params = qwen
+    tel, eng, rids = _pressure_run(params, cfg)
+    trace, metrics = tmp_path / "trace.json", tmp_path / "metrics.json"
+    tel.write(trace_out=str(trace), metrics_out=str(metrics))
+    ev = json.loads(trace.read_text())["traceEvents"]
+    assert {"serve.step", "serve.prefill_chunk", "serve.decode", "evict",
+            "request", "queued", "prefill", "decode"} <= \
+        {e["name"] for e in ev}
+    spans = [e for e in ev if e.get("ph") == "X"]
+    assert all(e["dur"] >= 0 and e["ts"] >= 0 for e in spans)
+    phases, reqs = {}, {}
+    for e in spans:
+        if e["name"] in ("queued", "prefill", "decode"):
+            phases[e["tid"]] = phases.get(e["tid"], 0) + e["dur"]
+        elif e["name"] == "request":
+            reqs[e["tid"]] = e["dur"]
+    assert sorted(reqs) == sorted(req_tid(r) for r in rids)
+    for tid, dur in reqs.items():
+        assert phases[tid] == pytest.approx(dur, abs=2.0)
+    m = json.loads(metrics.read_text())
+    assert m["counters"]["evictions"]["series"][""] >= 1
+    assert m["histograms"]["step_ms"]["series"]
+
+
 def test_tracing_does_not_change_tokens(qwen):
     cfg, params = qwen
     _, eng_on, rids_on = _pressure_run(params, cfg)
