@@ -26,11 +26,23 @@ is cheap.  This kernel instead walks each sequence's page chain directly:
     dense gathered view is never materialized, nor a per-layer slice of
     a pool whose rows are whole 128-lane rows (``whole_lane_rows``);
   * pools are lane-dense, ``(L, n_pages, page_size, n_kv·Dp)``: a page is
-    a ``(page_size, n_kv·Dp)`` tile in the TPU's default layout, and the
-    body takes each head's ``Dp`` lanes of the block as a static slice
-    in VMEM, one ``(R, D)·(D, ppb·page_size)`` score dot and one
-    ``(R, ppb·page_size)·(ppb·page_size, D)`` value dot a kv head a
-    block;
+    a ``(page_size, n_kv·Dp)`` tile in the TPU's default layout.  Where
+    each kv head has one query row (``R = Sq·G == 1``: MHA decode) over
+    f32 or bf16 pools, the body takes every kv head of a block in one
+    lane-dense pass (``head_batched``, chosen from shapes and dtype at
+    trace time): q is one ``(1, n_kv·Dp)`` row; its f32 products with
+    the whole ``(ppb·page_size, n_kv·Dp)`` K block are summed per head
+    (the block turned on the XLU, each head's ``Dp`` sublanes added on
+    the VPU); the online softmax runs per head in ``(n_kv,
+    ppb·page_size)``; the weights, repeated over their heads' lanes,
+    multiply V and are summed over the keys into a lane-dense f32 row.
+    A one-row dot would load each head's tile into the MXU, over six
+    bf16 passes at ``HIGHEST``, to push one row through it.  Otherwise
+    (GQA groups, the verify step's Sq > 1, quantized pools) the body
+    takes each head's ``Dp`` lanes as a static slice, one ``(R,
+    D)·(D, ppb·page_size)`` score dot and one ``(R,
+    ppb·page_size)·(ppb·page_size, D)`` value dot a kv head a block,
+    where R rows share each tile's passes;
   * zero by select: a block's slots past the row's last page are never
     copied and hold whatever the buffer held, a page's unwritten tail
     whatever the pool held (NaN included), so their scores are replaced
@@ -232,8 +244,17 @@ def scale_rows_start(page, rows: int):
 
 
 def paged_q_block_map(b, *_):
-    """Folded q / output block index: row b, whole (Hkv, Sq·G, D) block."""
+    """Folded q / output block index: row b, whole (Hkv, Sq·G, D) block
+    (or, head-batched, its whole lane-dense ``(1, 1, lanes)`` row)."""
     return (b, 0, 0, 0)
+
+
+def head_batched(R: int, pool_dtype) -> bool:
+    """The kernel's body, chosen statically: every kv head of a block in
+    one lane-dense pass where each head has one query row (``R = Sq·G ==
+    1``) over dense pools; the per-head MXU loop for ``R > 1`` and for
+    quantized pools."""
+    return R == 1 and kv_mode_of(pool_dtype) == "bf16"
 
 
 # ---------------------------------------------------------------------------
@@ -242,7 +263,7 @@ def paged_q_block_map(b, *_):
 
 def _decode_kernel(pages_s, lens_s, win_s, layer_s, q_ref, k_hbm, v_hbm,
                    *rest, ps, ppb, cap, G, Sq, Dp, rows, mode="bf16",
-                   windowed=False):
+                   windowed=False, Hkv=0):
     quant = mode != "bf16"
     if quant:                        # quantized pools: scale rows ride along
         (sk_hbm, sv_hbm, o_ref, kbuf, vbuf, skbuf, svbuf, sems, slot_ref,
@@ -293,6 +314,37 @@ def _decode_kernel(pages_s, lens_s, win_s, layer_s, q_ref, k_hbm, v_hbm,
     f32 = jnp.float32
     hkv, R, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]  # R = Sq·G
     W = ppb * ps                     # keys a block
+    batched = Hkv > 0                # head-batched: one query row a head
+    if batched:
+        # q and the accumulator are lane-dense rows: head h on lanes
+        # h·Dp .. h·Dp + Dp − 1 of the page rows' ``width`` lanes
+        width = kbuf.shape[-1]
+        q_row = q_ref[0, 0].astype(f32)                  # (1, width)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (Hkv, width), 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, (Hkv, width), 0) * Dp
+        on_head = (lane >= head) & (lane < head + Dp)
+
+        def head_sums(x):
+            # (W, width) → (Hkv, W), each head's Dp lanes added: the
+            # block turned on the XLU, each head's Dp sublanes added in
+            # f32 on the VPU
+            t = x.T
+            return jnp.concatenate([t[h * Dp:(h + 1) * Dp].sum(
+                0, keepdims=True) for h in range(Hkv)], 0)
+
+        def head_lanes(x):
+            # (Hkv, W) → (W, width), each head's weights on its Dp lanes
+            # (0 on padding lanes): repeated over sublanes, turned back
+            full = jnp.broadcast_to(x[:, None, :], (Hkv, Dp, W))
+            full = full.reshape(Hkv * Dp, W)
+            if width > Hkv * Dp:
+                full = jnp.concatenate(
+                    [full, jnp.zeros((width - Hkv * Dp, W), f32)], 0)
+            return full.T
+
+        def spread(col):
+            # (Hkv, 1) → (1, width): a select and a sublane sum, exact
+            return jnp.where(on_head, col, 0.0).sum(0, keepdims=True)
 
     def block(j, _):
         slot = (slot_first + j) % 2
@@ -335,6 +387,25 @@ def _decode_kernel(pages_s, lens_s, win_s, layer_s, q_ref, k_hbm, v_hbm,
             eye = (jax.lax.broadcasted_iota(jnp.int32, (ps, ps), 0)
                    == jax.lax.broadcasted_iota(jnp.int32, (ps, ps), 1))
 
+        if batched:
+            # every kv head at once: f32 products of q and the block's
+            # lane-dense K on the VPU, summed per head; the online
+            # softmax per head in (Hkv, W); the weights spread back over
+            # their heads' lanes, times V, summed over the keys
+            k = kbuf[slot].reshape(W, width).astype(f32)
+            v = jnp.where(key_ok, vbuf[slot].reshape(W, width).astype(f32),
+                          0.0)               # a select: 0·NaN is NaN
+            s = jnp.where(ok, _softcap(head_sums(k * q_row), cap), NEG_INF)
+            m_prev = m_ref[...]                               # (Hkv, 1)
+            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            pexp = jnp.exp(s - m_new)                         # (Hkv, W)
+            l_ref[...] = l_ref[...] * alpha + pexp.sum(-1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * spread(alpha) + (
+                v * head_lanes(pexp)).sum(0, keepdims=True)
+            m_ref[...] = m_new
+            return 0
+
         def scale_cols(sc_rows, h):
             # head h's ps lanes of each page's (1, Hkv·ps) scale row as
             # per-token (ps, 1) columns: a select and a lane sum, exact
@@ -374,22 +445,35 @@ def _decode_kernel(pages_s, lens_s, win_s, layer_s, q_ref, k_hbm, v_hbm,
 
     jax.lax.fori_loop(0, nblk, block, 0)
     slot_ref[0] = (slot_first + nblk) % 2
+    if batched:
+        out = acc_ref[...] / jnp.maximum(spread(l_ref[...]), 1e-30)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
+        return
     out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-def fold_q(q, scale: float, G: int):
+def fold_q(q, scale: float, G: int, lanes: int = 0):
     """(B, Sq, Hq, D) → (B, Hkv, Sq·G, D), scaled in the storage dtype
     (dense-op-order numerics): row r = s·G + g of kv head h is query s of
-    q head h·G + g.  Done outside the kernel — Mosaic does not lower the
-    reshapes this needs."""
+    q head h·G + g.  With ``lanes`` (head-batched, Sq·G == 1): the
+    lane-dense row (B, 1, 1, lanes) instead, head h on lanes h·D..,
+    padding lanes 0.  Done outside the kernel — Mosaic does not lower
+    the reshapes this needs."""
     B, S, Hq, D = q.shape
     qg = (q * jnp.asarray(scale, q.dtype)).reshape(B, S, Hq // G, G, D)
+    if lanes:
+        row = qg.reshape(B, 1, 1, Hq * D)
+        return jnp.pad(row, ((0, 0),) * 3 + ((0, lanes - Hq * D),))
     return qg.transpose(0, 2, 1, 3, 4).reshape(B, Hq // G, S * G, D)
 
 
-def unfold_q(out, S: int, G: int):
-    """Inverse of ``fold_q``'s layout: (B, Hkv, Sq·G, D) → (B, Sq, Hq, D)."""
+def unfold_q(out, S: int, G: int, heads=None):
+    """Inverse of ``fold_q``'s layout: (B, Hkv, Sq·G, D) → (B, Sq, Hq, D);
+    with ``heads = (Hq, D)``, of its lane-dense row (B, 1, 1, lanes)."""
+    if heads is not None:
+        Hq, D = heads
+        return out[..., :Hq * D].reshape(out.shape[0], 1, Hq, D)
     B, hkv, _, D = out.shape
     out = out.reshape(B, hkv, S, G, D).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, S, hkv * G, D)
@@ -436,12 +520,16 @@ def paged_attn_pallas(q, pool_k, pool_v, pages, lens, window, layer, *,
     sc_lanes = scale_k.shape[-1] if quant else 0
     ppb = pages_per_block(ps, page_vmem_bytes(ps, lanes, pool_k.dtype,
                                               sc_lanes, rows))
+    batched = head_batched(R, pool_k.dtype)
     kern = functools.partial(_decode_kernel, ps=ps, ppb=ppb, cap=cap, G=G,
                              Sq=S, Dp=Dp, rows=rows, mode=mode,
-                             windowed=windowed)
+                             windowed=windowed, Hkv=Hkv if batched else 0)
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)  # copied by hand
-    in_specs = [pl.BlockSpec((1, Hkv, R, D), paged_q_block_map), hbm, hbm]
-    operands = [fold_q(q, scale, G), pool_k, pool_v]
+    # q and output: row b's (Hkv, R, D) block, or head-batched its
+    # lane-dense (1, lanes) row
+    qo = (1, 1, 1, lanes) if batched else (1, Hkv, R, D)
+    in_specs = [pl.BlockSpec(qo, paged_q_block_map), hbm, hbm]
+    operands = [fold_q(q, scale, G, lanes if batched else 0), pool_k, pool_v]
     # double buffers: block j+1's pages land while block j computes
     scratch = [pltpu.VMEM((2, ppb, ps, lanes), pool_k.dtype),
                pltpu.VMEM((2, ppb, ps, lanes), pool_v.dtype)]
@@ -449,31 +537,35 @@ def paged_attn_pallas(q, pool_k, pool_v, pages, lens, window, layer, *,
         in_specs += [hbm, hbm]
         operands += [scale_k, scale_v]
         scratch += [pltpu.VMEM((2, ppb, rows, sc_lanes), jnp.float32)] * 2
+    # the online softmax's m and l per (head, row), and its accumulator
+    ml, acc = ((Hkv, 1), (1, lanes)) if batched else ((Hkv, R, 1),
+                                                      (Hkv, R, D))
     scratch += [
         pltpu.SemaphoreType.DMA((2,)),         # one a buffer slot
         pltpu.SMEM((1,), jnp.int32),           # slot of the row's block 0
-        pltpu.VMEM((Hkv, R, 1), jnp.float32),
-        pltpu.VMEM((Hkv, R, 1), jnp.float32),
-        pltpu.VMEM((Hkv, R, D), jnp.float32),
+        pltpu.VMEM(ml, jnp.float32),
+        pltpu.VMEM(ml, jnp.float32),
+        pltpu.VMEM(acc, jnp.float32),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hkv, R, D), paged_q_block_map),
+        out_specs=pl.BlockSpec(qo, paged_q_block_map),
         scratch_shapes=scratch,
     )
+    out_shape = (B, 1, 1, lanes) if batched else (B, Hkv, R, D)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         # each row prefetches the next row's first block: rows in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attn_pallas",        # the device op's name in a trace
     )(pages, lens, win, jnp.stack([lyr_kv, lyr_sc]), *operands)
-    return unfold_q(out, S, G)
+    return unfold_q(out, S, G, (Hq, D) if batched else None)
 
 
 # ---------------------------------------------------------------------------
@@ -561,6 +653,13 @@ def _paged_attn_blocked(q, pool_k, pool_v, pages, lens, window, layer, *,
 # public entry: backend + shard-local dispatch
 # ---------------------------------------------------------------------------
 
+def resolve_backend(backend: str) -> str:
+    """``auto`` → ``pallas`` on a TPU, ``blocked`` elsewhere."""
+    if backend != "auto":
+        return backend
+    return "pallas" if jax.default_backend() == "tpu" else "blocked"
+
+
 def _local(q, pool_k, pool_v, pages, lens, win, layer, *, scale, cap, G,
            backend, windowed, scale_k=None, scale_v=None):
     if backend == "blocked":
@@ -628,8 +727,7 @@ def paged_attn(q, pool_k, pool_v, pages, lens, *, layer, scale: float,
     if (kv_mode_of(pool_k) != "bf16") != (scale_k is not None):
         raise ValueError("quantized pools need scale_k/scale_v rows "
                          "(and dense pools must not pass them)")
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "blocked"
+    backend = resolve_backend(backend)
     win = _NO_WINDOW if window is None else window
     win = jnp.asarray(win, jnp.int32)
     layer = jnp.asarray(layer, jnp.int32)

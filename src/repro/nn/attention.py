@@ -31,7 +31,10 @@ from .common import linear, linear_init, apply_rope, layer_rope, softcap, \
 from .attention_mha import mha, NEG_INF, _mask  # grouped-layout core op
 from .paged import (scatter_kv, scatter_kv_quant, gather_kv,
                     gather_kv_dequant, paged_attn_decode)
-from repro.kernels.paged_attention import paged_attn, gqa_group, _NO_WINDOW
+from repro.kernels.paged_attention import (paged_attn, gqa_group,
+                                           head_batched, resolve_backend,
+                                           _NO_WINDOW)
+from repro.quant.kvcache import kv_pool_layout
 
 
 def kv_of_q_map(n_heads: int, n_kv: int, n_heads_p: int, n_kv_p: int
@@ -47,6 +50,28 @@ def kv_of_q_map(n_heads: int, n_kv: int, n_heads_p: int, n_kv_p: int
     idx = np.minimum(np.arange(n_heads_p) // group, n_kv_p - 1)
     idx[n_heads:] = n_kv_p - 1          # padded q heads → last (padded) kv
     return idx.astype(np.int32)
+
+
+def paged_fused(cfg, S: int) -> bool:
+    """Whether paged attention of ``S`` query tokens a slot goes through
+    the fused kernel (``paged_attn``): up to ``paged_fused_max_sq``
+    tokens, a backend other than ``xla`` and a regular GQA map."""
+    kv_map = kv_of_q_map(cfg.n_heads, cfg.n_kv_heads, cfg.n_heads_p,
+                         cfg.n_kv_p)
+    return (S <= max(1, cfg.paged_fused_max_sq)
+            and cfg.attention_backend != "xla"
+            and gqa_group(kv_map, cfg.n_heads_p, cfg.n_kv_p) is not None)
+
+
+def paged_head_batched(cfg) -> bool:
+    """Whether each layer of a decode step (one query token a slot) runs
+    the Pallas kernel's head-batched body: decided from the shapes and
+    the pool dtype alone, as the kernel decides it while it is traced
+    (``kernels.paged_attention.head_batched``)."""
+    return (paged_fused(cfg, 1)
+            and resolve_backend(cfg.attention_backend) != "blocked"
+            and head_batched(cfg.n_heads_p // cfg.n_kv_p,
+                             kv_pool_layout(cfg)[0]))
 
 
 def attn_init(key, cfg, d_model: Optional[int] = None) -> dict:
@@ -168,11 +193,7 @@ def attn_apply(p: dict, x: jnp.ndarray, cfg, *, layer_window=None,
         # speculative-decoding verify step: the kernel scores Sq query
         # rows at positions lens..lens+Sq-1, which is exactly this
         # branch's contract (positions = lens[:, None] + arange(S))
-        fused = (S <= max(1, cfg.paged_fused_max_sq)
-                 and cfg.attention_backend != "xla"
-                 and gqa_group(kv_map, cfg.n_heads_p, cfg.n_kv_p)
-                 is not None)
-        if fused:
+        if paged_fused(cfg, S):
             # a model with no window layer walks without the window's
             # arithmetic (its scanned window is traced all the same)
             windowed = any(w is not None for w in cfg.layer_windows)

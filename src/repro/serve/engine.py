@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from repro.analysis.ledger import sanitize_enabled
 from repro.core.macexec import check_drafter
+from repro.nn.attention import paged_head_batched
 from repro.models import (apply_model, init_cache, init_paged_cache,
                           supports_paged_cache)
 from repro.obs import CompileTracker, percentile, profiler_trace
@@ -386,6 +387,14 @@ class Engine:
             "moe_experts_active",
             "experts that got at least one token in a decode step, summed "
             "over steps and expert layers")
+        # decode steps' layer calls that ran the paged kernel's
+        # head-batched body: a static choice, the same every step
+        self._c_head_batched = reg.counter(
+            "paged_attn_head_batched_layers",
+            "layer calls of decode steps through the paged-attention "
+            "kernel's head-batched body (one query row a kv head)")
+        self._head_batched_layers = (self.cfg.n_layers
+                                     if paged_head_batched(self.cfg) else 0)
         self._c_jit = reg.counter(
             "jit_compiles",
             "XLA compilations of the jitted serving steps since this "
@@ -440,6 +449,8 @@ class Engine:
             "occupancy_sum": self._c_occ.total(),
             "moe_assignments": int(self._c_moe_assign.total()),
             "moe_experts_active": int(self._c_moe_active.total()),
+            "paged_attn_head_batched_layers":
+                int(self._c_head_batched.total()),
         }
 
     @property
@@ -622,6 +633,7 @@ class Engine:
                 n = self.kv.n_slots
                 self._c_moe_assign.inc(int(toks[n]))
                 self._c_moe_active.inc(int(toks[n + 1]))
+            self._c_head_batched.inc(self._head_batched_layers)
         with tr.span("serve.commit"):
             now = time.perf_counter()
             self._c_decode.inc(len(active), mac=self._mac)

@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
-from repro.kernels.paged_attention import (block_pages, gqa_group, n_blocks,
+from repro.kernels.paged_attention import (block_pages, gqa_group,
+                                           head_batched, n_blocks,
                                            paged_attn, pages_per_block)
 from repro.models import init_model
 from repro.nn.paged import gather_kv, gather_kv_dequant, paged_attn_decode
@@ -202,27 +203,14 @@ def test_op_kquery_sweep(backend, S, ps, Hq, Hkv, window, cap):
                                rtol=3e-5, atol=3e-6)
 
 
-@pytest.mark.parametrize("G", [1, 8])
-@pytest.mark.parametrize("S", [1, 5])
-@pytest.mark.parametrize("kv", ["float32", "bfloat16", "int8", "int4"])
-def test_block_walk_matches_gather_reference(kv, S, G):
-    """The kernel's walk in compute blocks of ``ppb`` 16-token pages
-    (8 here: 128 keys) against the gather reference: rows of different
-    lengths in one batch, at ``ppb·ps − 1``, ``ppb·ps`` and ``ppb·ps + 1``
-    keys, one page, and three blocks; 2 kv heads of 64, so the values
-    are read in place and int4's 64-lane pages and every pool's 32-lane
-    scale rows come padded to whole lanes; G = 8 under a 100-key window,
-    whose first page falls mid-block (slot 1 of a 129-key row, 12 of a
-    296-key one).  Every page no row needs (the scratch page 0, the
-    table's tail, pages before a window) holds NaN: no block may copy
+def _nan_walk_case(rng, kv, lens_np, S, G, Hkv, window, *, ps=16, D=64,
+                   P=20, backend="pallas_interpret"):
+    """The kernel (interpret mode) and the gather reference over stacked
+    pools of ``kv`` whose every page no row needs (the scratch page 0,
+    the table's tail, pages before a window) holds NaN: no block may copy
     them, and slots past a row's last page, which no copy fills, must
-    count for exactly nothing."""
-    rng = np.random.default_rng(hash((kv, S, G)) % 2 ** 32)
-    ps, Hkv, D, P = 16, 2, 64, 20    # values in whole 128-lane rows
+    count for exactly nothing.  Returns (kernel out, reference out)."""
     Hq = Hkv * G
-    window = 100 if G > 1 else None
-    blk = 8 * ps
-    lens_np = np.asarray([blk - 1, blk, blk + 1, 3, 2 * blk + 40], np.int32)
     B = len(lens_np)
     _, _, _, pages, kv_map = _pool_case(rng, B, Hq, Hkv, D, ps, P)
     n_pages = 1 + B * P
@@ -252,18 +240,40 @@ def test_block_walk_matches_gather_reference(kv, S, G):
     scale = 1.0 / np.sqrt(D)
     out = paged_attn(q, pk, pv, pages, lens, layer=LAYER, scale=scale,
                      window=window, kv_of_q=kv_map,
-                     backend="pallas_interpret", scale_k=sk, scale_v=sv)
+                     backend=backend, scale_k=sk, scale_v=sv)
     k_pos = jnp.arange(ck.shape[1])
     ref = paged_attn_decode(q, ck, cv, kv_map, scale=scale,
                             q_pos=lens[:, None] + jnp.arange(S)[None, :],
                             k_pos=k_pos,
                             k_valid=k_pos[None, :] < (lens + S)[:, None],
                             window=window)
+    return out, ref
+
+
+@pytest.mark.parametrize("G", [1, 8])
+@pytest.mark.parametrize("S", [1, 5])
+@pytest.mark.parametrize("kv", ["float32", "bfloat16", "int8", "int4"])
+def test_block_walk_matches_gather_reference(kv, S, G):
+    """The kernel's walk in compute blocks of ``ppb`` 16-token pages
+    (8 here: 128 keys) against the gather reference: rows of different
+    lengths in one batch, at ``ppb·ps − 1``, ``ppb·ps`` and ``ppb·ps + 1``
+    keys, one page, and three blocks; 2 kv heads of 64, so the values
+    are read in place and int4's 64-lane pages and every pool's 32-lane
+    scale rows come padded to whole lanes; G = 8 under a 100-key window,
+    whose first page falls mid-block (slot 1 of a 129-key row, 12 of a
+    296-key one).  Every page no row needs holds NaN."""
+    rng = np.random.default_rng(hash((kv, S, G)) % 2 ** 32)
+    ps = 16
+    window = 100 if G > 1 else None
+    blk = 8 * ps
+    lens_np = np.asarray([blk - 1, blk, blk + 1, 3, 2 * blk + 40], np.int32)
+    out, ref = _nan_walk_case(rng, kv, lens_np, S, G, 2, window)
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
     # the walk the kernel ran: a window starts mid-block; the long row
     # takes three blocks, one under the window
+    B = len(lens_np)
     win = np.asarray([window or 2 ** 30], np.int32)
     walk = dict(Sq=S, ps=ps, ppb=8, windowed=window is not None)
     starts = [int(block_pages(b, 0, lens_np, win, **walk)[0])
@@ -271,6 +281,76 @@ def test_block_walk_matches_gather_reference(kv, S, G):
     assert starts == ([0] * B if window is None else [1, 1, 1, 0, 12])
     assert int(n_blocks(lens_np[4], win[0], **walk)) == (3 if window is None
                                                          else 1)
+
+
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("kv", ["float32", "bfloat16"])
+def test_head_batched_walk_matches_gather_reference(kv, window):
+    """The head-batched body (one query row a kv head, dense pools) at
+    the dense cell's geometry: 16 kv heads of 64 in 1024-lane pages of
+    16 tokens, 8 pages a block; rows of one block (a partial last page,
+    and one key short of a block), two blocks, and four (a partial last
+    page, and a full one); a 200-key window starts the long rows' walks
+    mid-table.  Every page no row needs holds NaN."""
+    rng = np.random.default_rng([len(kv), window or 0])
+    lens_np = np.asarray([100, 127, 200, 500, 511], np.int32)
+    out, ref = _nan_walk_case(rng, kv, lens_np, 1, 1, 16, window, P=40)
+    assert head_batched(1, jnp.dtype(kv))
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-6)
+    win = np.asarray([window or 2 ** 30], np.int32)
+    walk = dict(Sq=1, ps=16, ppb=8, windowed=window is not None)
+    assert [int(n_blocks(ln, win[0], **walk)) for ln in lens_np] == (
+        [1, 1, 2, 4, 4] if window is None else [1, 1, 2, 2, 2])
+
+
+def _pallas_out_shape(fn, *args):
+    """Output shape of the one ``pallas_call`` that ``fn`` traces."""
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn.outvars[0].aval.shape
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", None)
+                if inner is not None:
+                    got = find(getattr(inner, "jaxpr", inner))
+                    if got:
+                        return got
+        return None
+    return find(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("G,S,kv,batched", [
+    (1, 1, "float32", True), (1, 1, "bfloat16", True),
+    (8, 1, "float32", False), (1, 5, "float32", False),
+    (1, 1, "int8", False), (1, 1, "int4", False)])
+def test_head_batched_path_choice(G, S, kv, batched):
+    """The body is chosen statically from what the kernel sees: the
+    head-batched pass for one query row a kv head (Sq·G == 1) over f32 or
+    bf16 pools, the per-head MXU loop for G = 8, for Sq = 5 and for
+    quantized pools; the traced kernel's output is the lane-dense row
+    exactly when the predicate says so."""
+    from repro.kernels.paged_attention import paged_attn_pallas
+    B, ps, Hkv, D, n_pages = 3, 16, 2, 64, 9
+    dtype, width = ((jnp.uint8, D // 2) if kv == "int4"
+                    else (jnp.dtype(kv), D))
+    assert head_batched(S * G, dtype) == batched
+    pool = jax.ShapeDtypeStruct((N_LAYERS, n_pages, ps, Hkv * width), dtype)
+    args = [jax.ShapeDtypeStruct((B, S, Hkv * G, D), jnp.float32), pool,
+            pool, jax.ShapeDtypeStruct((B, 4), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32)]
+    if kv in ("int8", "int4"):
+        args += [jax.ShapeDtypeStruct((N_LAYERS, n_pages, Hkv * ps),
+                                      jnp.float32)] * 2
+
+    def call(q, pk, pv, pg, ln, *sc):
+        return paged_attn_pallas(q, pk, pv, pg, ln, jnp.int32(2 ** 30),
+                                 jnp.int32(LAYER), scale=D ** -0.5, G=G,
+                                 interpret=True, scale_k=sc[0] if sc else
+                                 None, scale_v=sc[1] if sc else None)
+    shape = _pallas_out_shape(call, *args)
+    assert shape == ((B, 1, 1, Hkv * D) if batched else (B, Hkv, S * G, D))
 
 
 def test_op_rejects_irregular_maps():
@@ -327,6 +407,33 @@ def test_engine_token_identical_over_multi_block_walks(qwen):
     kw = dict(n_slots=2, page_size=8, n_pages=96)
     ref = _serve(params, cfg, prompts, "xla", **kw)
     assert _serve(params, cfg, prompts, "pallas_interpret", **kw) == ref
+
+
+def test_engine_counts_head_batched_layers(qwen):
+    """``paged_attn_head_batched_layers`` advances by the layer count a
+    decode step where each kv head has one query row (MHA), whose tokens
+    stay those of the gather path; the reduced GQA config (G = 2) keeps
+    the per-head body and counts nothing."""
+    cfg, params = qwen
+    mha = dataclasses.replace(cfg, n_kv_heads=cfg.n_heads)
+    mha_params = init_model(jax.random.PRNGKey(5), mha)
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (5, 12)]
+    kw = dict(n_slots=2, page_size=4, n_pages=64)
+    for c, p, per_step in ((mha, mha_params, mha.n_layers), (cfg, params, 0)):
+        eng = Engine(p, dataclasses.replace(
+            c, attention_backend="pallas_interpret"), **kw)
+        calls = []
+        step = eng._step
+        eng._step = lambda *a: calls.append(1) or step(*a)
+        rids = [eng.submit(x, max_new=6) for x in prompts]
+        res = eng.run()
+        assert calls
+        assert eng.metrics["paged_attn_head_batched_layers"] == \
+            per_step * len(calls)
+        assert [res[r].tolist() for r in rids] == \
+            _serve(p, c, prompts, "xla", **kw)
 
 
 @pytest.mark.skipif(jax.default_backend() == "tpu",

@@ -18,7 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.analysis.hlo import entry_param_shapes, input_output_aliases
 from repro.kernels.encoded_matmul import encoded_matmul_pallas
-from repro.kernels.paged_attention import paged_attn_pallas
+from repro.kernels.paged_attention import head_batched, paged_attn_pallas
 
 # qwen1.5-0.5b (configs/qwen15_05b.py)
 D_MODEL, D_FF, N_HEADS, N_KV, HEAD_DIM = 1024, 2816, 16, 16, 64
@@ -81,7 +81,9 @@ def test_paged_attention_compiles(one_chip, kv, sq):
     """The fused paged-attention kernel over stacked lane-dense pools in
     the compute dtype (f32 as configured), bf16, int8 and packed int4,
     for decode (Sq=1) and a speculative verify step at k=4 (Sq=5): 8
-    slots, 16-token pages, 16 table pages, 3 layers."""
+    slots, 16-token pages, 16 table pages, 3 layers.  Decode over f32 or
+    bf16 pools compiles the head-batched body (its output the lane-dense
+    row), every other case the per-head one; the op keeps its name."""
     B, ps, P, n_pages, L = 8, 16, 16, 129, 3
     quant = kv in ("int8", "int4")
     f = jax.jit(lambda q, pk, pv, pg, ln, lyr, *sc: paged_attn_pallas(
@@ -96,7 +98,14 @@ def test_paged_attention_compiles(one_chip, kv, sq):
             _sds((B,), jnp.int32, one_chip), _sds((), jnp.int32, one_chip)]
     if quant:
         args += [_sds((L, n_pages, N_KV * ps), jnp.float32, one_chip)] * 2
-    _assert_mosaic(f.lower(*args).compile())
+    compiled = f.lower(*args).compile()
+    _assert_mosaic(compiled)
+    batched = sq == 1 and not quant
+    assert head_batched(sq * N_HEADS // N_KV, dtype) == batched
+    out = re.search(r"%paged_attn_pallas\.\d+ = \w+\[([\d,]+)\]",
+                    compiled.as_text())
+    assert out, "the kernel's op lost its name"
+    assert (out.group(1) == f"{B},1,1,{N_KV * HEAD_DIM}") == batched
 
 
 def test_encoded_matmul_op_name_is_pinned(one_chip):
